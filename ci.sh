@@ -8,7 +8,7 @@ python -m pytest tests/ -q
 
 echo "== driver entry points =="
 JAX_PLATFORMS=cpu python -c "
-import jax; jax.config.update('jax_platforms', 'cpu')
+import jax
 import __graft_entry__ as g
 fn, args = g.entry()
 out = jax.jit(fn)(*args)
@@ -16,10 +16,11 @@ assert out is not None
 print('entry() ok')"
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')"
 
-echo "== on-chip tool dry-runs (CPU platform; round-4 postmortem gate) =="
-# The one TPU window round 4 got was burned by an untested child process
-# (ModuleNotFoundError). Run the EXACT subprocess invocations the watcher
-# uses, end-to-end, on the CPU platform, so they can never regress unseen.
+echo "== on-chip tool dry-runs (CPU platform) =="
+# The commands that run on the chip (chip_smoke.py is the first of them),
+# end-to-end on the CPU platform at a tiny scale, so that their control flow
+# cannot regress unseen. None of these lines is a device measurement.
+JAX_PLATFORMS=cpu python chip_smoke.py --rehearse | tail -1
 python tools/tpu_correctness.py --dryrun-cpu --out /tmp/ci_tpu_correctness.json
 python - <<'PYEOF'
 import json
@@ -27,9 +28,9 @@ d = json.load(open("/tmp/ci_tpu_correctness.json"))
 assert d["ok"] and d["platform"] == "cpu", d
 print("correctness dry-run ok:", len(d["checks"]), "checks")
 PYEOF
-# bench measuring child, exact _spawn() invocation at tiny scale
-bench_line=$(_SRT_BENCH_CHILD=1 JAX_PLATFORMS=cpu TPCH_SF=0.01 \
-  TPCH_DIR=/tmp/tpch_ci_sf0.01 TPCDS_SECONDARY=0 python bench.py | tail -1)
+# the bench's measured run at tiny scale
+bench_line=$(JAX_PLATFORMS=cpu TPCH_SF=0.01 TPCH_DIR=/tmp/tpch_ci_sf0.01 \
+  TPCDS_SECONDARY=0 python bench.py --dryrun-cpu | tail -1)
 python -c '
 import json, sys
 d = json.loads(sys.argv[1])
@@ -68,8 +69,8 @@ echo "$bench_line" > /tmp/ci_bench_line.json
 python tools/bench_compare.py /tmp/ci_bench_line.json --baseline BENCH_r08.json
 
 echo "== radix spine: kernel interpret tests + join microbench smoke =="
-# the exact kernel set the next chip window's probe latch will exercise,
-# plus the join-spine microbench in smoke mode — parity of the Pallas
+# the Pallas kernels' arithmetic in interpret mode, plus the join-spine
+# microbench in smoke mode — parity of the Pallas
 # probe against the lax.sort rank path is a gate, not a hope
 JAX_PLATFORMS=cpu python -m pytest tests/test_pallas.py \
   tests/test_readahead.py -q
@@ -101,7 +102,7 @@ if cores < 2:
     print(f"pipeline A/B gate SKIPPED: {cores} core(s) — "
           "decode/compute/exchange overlap needs >=2 cores")
     raise SystemExit(0)
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import statistics, time
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
@@ -146,7 +147,7 @@ echo "== whole-stage chain fusion: >=3x per-batch dispatch drop, bit-identical =
 # on q18's own plan shape with the threshold lowered; canonical q18 asserts
 # chain formation + bit-identity.
 JAX_PLATFORMS=cpu python - <<'PYEOF'
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 import spark_rapids_tpu.functions as F
 from spark_rapids_tpu.benchmarks import tpch
@@ -240,7 +241,7 @@ for phase in populate replay; do
 SRT_CI_PHASE="$phase" SRT_CI_CACHE_DIR="$stage_cache_dir" \
 JAX_PLATFORMS=cpu python - <<'PYEOF'
 import os
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
 from spark_rapids_tpu.session import TpuSession
@@ -267,7 +268,7 @@ done
 rm -rf "$stage_cache_dir"
 
 echo "== scan-side chain: bit-identity + warm-start replay of fused scan stages =="
-# the scan-floor gate (perf_notes r9): q1 and q18 with the full scan-side
+# the scan-floor gate: q1 and q18 with the full scan-side
 # chain on (device decode + encoded upload + fused decode→filter→partial-agg
 # + chained group-by) must be bit-identical to the arrow path, and a FRESH
 # process pointed at the populated stage cache must replay every fused scan
@@ -277,7 +278,7 @@ for phase in populate replay; do
 SRT_CI_PHASE="$phase" SRT_CI_CACHE_DIR="$scan_cache_dir" \
 JAX_PLATFORMS=cpu python - <<'PYEOF'
 import os
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
 from spark_rapids_tpu.session import TpuSession
@@ -331,7 +332,7 @@ for mode in enc den; do
 if [ "$mode" = enc ]; then obs="$scan_mv_enc"; else obs="$scan_mv_den"; fi
 SRT_CI_MODE="$mode" SRT_OBS_DIR="$obs" JAX_PLATFORMS=cpu python - <<'PYEOF'
 import os
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
 from spark_rapids_tpu.session import TpuSession
@@ -685,7 +686,7 @@ echo "== observability: event log + tracing overhead + profiler gate =="
 # and a non-empty operator breakdown (join build named)
 obs_dir=$(mktemp -d)
 JAX_PLATFORMS=cpu SRT_OBS_DIR="$obs_dir" python - <<'PYEOF'
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import os, statistics, time
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
@@ -853,7 +854,7 @@ echo "== statistics plane: plan-history estimate-error gate =="
 # both runs' estimates and mask the history path entirely.
 stats_dir=$(mktemp -d)
 JAX_PLATFORMS=cpu SRT_STATS_DIR="$stats_dir" python - <<'PYEOF'
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import os
 import spark_rapids_tpu  # noqa: F401  (enables x64)
 from spark_rapids_tpu.benchmarks import tpch
@@ -926,7 +927,7 @@ instdir=$(mktemp -d)
 # --no-build-isolation: the CI box has no egress; setuptools is preinstalled
 pip install --quiet --no-build-isolation --target "$instdir" --no-deps .
 (cd /tmp && PYTHONPATH="$instdir" JAX_PLATFORMS=cpu python - <<'PYEOF'
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import spark_rapids_tpu, pyarrow as pa
 assert "/repo/" not in spark_rapids_tpu.__file__, spark_rapids_tpu.__file__
 from spark_rapids_tpu.session import TpuSession

@@ -415,8 +415,8 @@ OPTIMIZER_TPU_ROW_COST = conf("spark.rapids.tpu.sql.optimizer.tpu.rowCost").doc(
 
 OPTIMIZER_TPU_DISPATCH_COST = conf(
     "spark.rapids.tpu.sql.optimizer.tpu.dispatchCost").doc(
-    "Dual cost model: fixed seconds per device operator dispatch (jit call "
-    "over the runtime tunnel)").double_conf(2e-3)
+    "Dual cost model: fixed seconds per device operator dispatch (one jit "
+    "call)").double_conf(2e-3)
 
 OPTIMIZER_TRANSFER_ROW_COST = conf(
     "spark.rapids.tpu.sql.optimizer.transferRowCost").doc(
@@ -512,7 +512,7 @@ CLUSTER_TASK_MAX_FAILURES = conf("spark.rapids.tpu.cluster.task.maxFailures").do
 
 CLUSTER_TASK_TIMEOUT = conf("spark.rapids.tpu.cluster.task.timeoutSeconds").doc(
     "Deadline for one MiniCluster task; a task running past it has its "
-    "executor killed (the pipe protocol cannot cancel a wedged task) and is "
+    "executor killed (the pipe protocol cannot cancel a hung task) and is "
     "retried on another executor, counting as a task failure against the "
     "slow executor. <=0 disables the deadline").double_conf(0.0)
 

@@ -190,10 +190,7 @@ class MeshExchangeExec(TpuExec):
                     + (m_rows[None],))
 
         spec = P("data", None)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # older jax
-            from jax.experimental.shard_map import shard_map
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             shard_step, mesh=self.mesh,
             in_specs=tuple([spec] * (2 * n_cols) + [P("data")]),
             out_specs=tuple([spec] * (2 * n_cols) + [P("data")])))

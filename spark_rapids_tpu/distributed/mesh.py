@@ -213,10 +213,7 @@ class LocalMesh:
             return pids[None], jax.lax.psum(counts, "data")
 
         spec = P("data", None)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # older jax
-            from jax.experimental.shard_map import shard_map
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             shard_step, mesh=self.mesh,
             in_specs=tuple([spec] * (2 * nk) + [P("data")]),
             out_specs=(spec, P())))
@@ -344,10 +341,7 @@ class LocalMesh:
                     + (rp[None], rn[None]))
 
         spec = P("data", None)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # older jax
-            from jax.experimental.shard_map import shard_map
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             shard_step, mesh=self.mesh,
             in_specs=tuple([spec] * (2 * nc) + [spec, P()]),
             out_specs=tuple([P("data", None, None)] * (2 * nc + 1)
@@ -558,11 +552,8 @@ class MeshExecutor:
 
         spec2 = P("data", None)
         n_out = len(group_b) + len(aggs)
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
         n_in = len(schema.fields)
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             shard_step, mesh=self.mesh,
             in_specs=tuple([spec2] * (2 * n_in) + [P("data")]),
             out_specs=tuple([spec2] * (2 * n_out) + [P("data")])))

@@ -316,7 +316,7 @@ class HashAggregateExec(TpuExec):
         whether the key range fits the packed single-operand sort (the
         join-build strategy-pick pattern, exec/joins._prep_fast_build) — a
         statically 64-bit key (LONG/TIMESTAMP) otherwise forces the 2-operand
-        wide sort, ~3x the packed cost at 1M rows (docs/perf_notes.md). The
+        wide sort, ~3x the packed cost at 1M rows (measured on XLA:CPU). The
         same probe now also checks whether the live rows already ARRIVE
         key-sorted with no nulls (clustered fact tables — TPC-H lineitem is
         physically ordered by l_orderkey): then the sort vanishes entirely
@@ -473,8 +473,8 @@ class HashAggregateExec(TpuExec):
         """Sort-free small-domain aggregation: keys with statically-known
         compact domains (dict strings / bools) and sum-shaped aggregates
         (Sum/Count/Average) reduce straight into D per-group buckets —
-        scatter-add on CPU, one-hot MATMUL on TPU (the MXU-shaped group-by;
-        cudf's hash groupby plays this role in the reference,
+        scatter-add on CPU, D masked reductions in one pass on TPU (cudf's
+        hash groupby plays this role in the reference,
         aggregate.scala:706). The sorted segment path (q1: ~18 ms sort +
         ~12 ms/column tree per batch) drops to ~1 ms/column.
 
@@ -487,11 +487,11 @@ class HashAggregateExec(TpuExec):
         fns = [_agg_fn(e) for e in self.agg_exprs]
         if not all(isinstance(f, (Sum, Count, Average)) for f in fns):
             return None
-        # TPU domain bound: the f64 one-hot matmul materializes (cap, D) so
+        # TPU domain bound: the masked reduction does cap x D work, so
         # D stays small; count-only aggregations (incl. DISTINCT dedup,
         # which has no aggregates) ride the blocked Pallas one-hot kernel
         # in the non-merge phase and stretch to medium domains — only when
-        # that kernel actually dispatches (probe latch), else the jnp
+        # that kernel is routed (pallas_kernels.KERNELS), else the jnp
         # fallback would materialize the very (cap, D) blowup the 128
         # bound exists to prevent
         count_only = all(isinstance(f, Count) for f in fns)

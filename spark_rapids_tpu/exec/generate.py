@@ -26,6 +26,7 @@ from spark_rapids_tpu.columnar.vector import (ListVector, TpuColumnVector,
 from spark_rapids_tpu.exec.base import TpuExec, acquire_semaphore
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime.tracing import trace_range
+from spark_rapids_tpu.ops.windowing import cumsum
 
 
 class GenerateExec(TpuExec):
@@ -75,7 +76,7 @@ class GenerateExec(TpuExec):
         # device mapping: out position -> (source row, element index)
         eff_d = jnp.zeros((batch.capacity,), jnp.int32).at[:n].set(
             jnp.asarray(eff.astype(np.int32)))
-        cum = jnp.cumsum(eff_d)
+        cum = cumsum(eff_d)
         pos = jnp.arange(out_cap, dtype=jnp.int32)
         src = jnp.searchsorted(cum, pos, side="right").astype(jnp.int32)
         src_c = jnp.clip(src, 0, batch.capacity - 1)

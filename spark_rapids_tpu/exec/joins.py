@@ -177,7 +177,7 @@ class _JoinCore:
 
         - range fits the packed budget → ONE-operand int64 sort of
           ((val - vmin) << idx_bits | row_idx); ~8x cheaper than the
-          3-operand comparator sort (docs/perf_notes.md fix-3 measurement).
+          3-operand comparator sort (an XLA:CPU measurement).
         - afterwards, uniqueness + compact domain decide the probe mode:
           dense direct-address rank table (O(1) gather per stream row),
           unique single-searchsorted, or the general two-searchsorted."""
@@ -221,7 +221,7 @@ class _JoinCore:
         if direct_ok:
             # CPU-only sort-free build: scatter row indices straight into the
             # direct-address table (XLA:CPU scatters are cheap; the sort they
-            # replace was the dominant build cost — docs/perf_notes.md). A
+            # replace was the dominant build cost on XLA:CPU). A
             # duplicate-key build falls through to the sorted paths below;
             # on TPU large scatters serialize, so this path never engages.
             def rel_of(k, n_build, vmin):
@@ -311,7 +311,8 @@ class _JoinCore:
                 # ineligible rows above every real key (rng+1 relative)
                 rel = jnp.where(eligible, rel, jnp.asarray(rng + 1, jnp.int64))
                 packed = (rel << idx_bits) | jnp.arange(cap, dtype=jnp.int64)
-                s = jax.lax.sort(packed)
+                # one operand of distinct values: stability buys nothing
+                s = jax.lax.sort(packed, is_stable=False)
                 perm = (s & ((1 << idx_bits) - 1)).astype(jnp.int32)
                 # int64 ON PURPOSE: casting back to the key dtype would wrap
                 # the vmax+1 sentinel tail to INT_MIN when vmax == dtype max,
@@ -340,9 +341,12 @@ class _JoinCore:
                     jnp.asarray(jnp.iinfo(vals.dtype).max, vals.dtype))
                 # two sort keys: eligibility first so a LEGITIMATE max-valued
                 # key still lands inside [0, n_valid) against the sentinel
+                # the row index as last key = the stable order, without the
+                # index operand a stable sort would add beside it
                 _, sorted_vals, perm = jax.lax.sort(
                     [(~eligible).astype(jnp.int8), masked,
-                     jnp.arange(cap, dtype=jnp.int32)], num_keys=2)
+                     jnp.arange(cap, dtype=jnp.int32)], num_keys=3,
+                    is_stable=False)
                 nv = jnp.sum(eligible, dtype=jnp.int32)
                 same = sorted_vals[1:] == sorted_vals[:-1]
                 in_valid = (jnp.arange(cap - 1, dtype=jnp.int32) + 1) < nv
@@ -532,7 +536,8 @@ class _JoinCore:
                     s_eligible, svals,
                     jnp.asarray(jnp.iinfo(svals.dtype).max, svals.dtype))
                 _, s_sorted = jax.lax.sort(
-                    [(~s_eligible).astype(jnp.int8), s_masked], num_keys=2)
+                    [(~s_eligible).astype(jnp.int8), s_masked], num_keys=2,
+                    is_stable=False)   # every operand is a key
                 ns = jnp.sum(s_eligible, dtype=jnp.int32)
                 blo = jnp.minimum(
                     jnp.searchsorted(s_sorted, bvals, side="left"), ns)

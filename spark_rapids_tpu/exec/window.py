@@ -115,7 +115,7 @@ class WindowExec(TpuExec):
         part_boundary = self._boundaries(sorted_part, cap)
         order_boundary = part_boundary | self._boundaries(sorted_order, cap) \
             if sorted_order else part_boundary
-        seg_ids = jnp.cumsum(part_boundary.astype(jnp.int32)) - 1
+        seg_ids = W.cumsum(part_boundary.astype(jnp.int32)) - 1
 
         sctx = EvalContext(sorted_in, batch.lazy_num_rows, cap)
         bounds_memo = {}  # per-batch: partitions run concurrently in threads

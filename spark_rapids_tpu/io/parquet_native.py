@@ -270,17 +270,17 @@ def read_chunk_pages(path: str, row_group: int, column: int,
 
     # fast path: one native C call scans the whole chunk (thrift headers,
     # def-level RLE decode, hybrid segmentation — native/parquet_host.cpp);
-    # the Python loop below is the fallback, the executable spec, and the
-    # compressed-chunk path (bodies must decompress before scanning)
+    # the Python loop below is the executable spec, the path for chunks the
+    # native scanner declines, and the compressed-chunk path (bodies must
+    # decompress before scanning). A scanner that cannot be built or loaded
+    # (NativeBuildError, OSError) is an error: the toolchain is part of
+    # this installation.
     raw_pages = None
     if dec is None:  # compressed bodies must decompress before scanning
+        from spark_rapids_tpu.native import scan_chunk_native
         try:
-            from spark_rapids_tpu.native import (NativeBuildError,
-                                                 scan_chunk_native)
             raw_pages, dict_info = scan_chunk_native(buf, col.num_values,
                                                      max_def)
-        except (NativeBuildError, OSError):
-            pass  # no native toolchain: parse in Python below
         except NotImplementedError:
             pass  # e.g. v2 data pages: the Python parser below handles them
     if raw_pages is not None:
@@ -367,6 +367,41 @@ def read_chunk_pages(path: str, row_group: int, column: int,
 
 # -- chunk → engine vector ----------------------------------------------------
 
+def _merge_packed_pages(pages: ChunkPages) -> ChunkPages:
+    """Fold a chunk's data pages into ONE page when their packed index bytes
+    can simply be concatenated: every page all bit-packed, one bit width, and
+    every page but the last filling its packed bytes exactly (a bit-packed
+    run holds whole 8-value groups, so it then ends on a value boundary).
+    Writers cut a row group's chunk into many pages (pyarrow: 20,000 rows
+    each, ~38 per TPC-H SF 1 chunk); folded, the chunk rides the single
+    fused decode program instead of one eager pipeline per page — on the
+    chip that per-page pipeline re-lowered the Pallas unpack kernel for every
+    page of every run (~27 s per scan batch, hot or cold)."""
+    if len(pages.index_segments) < 2:
+        return pages
+    bw0 = pages.index_segments[0][2]
+    last = len(pages.index_segments) - 1
+    parts, levels, n_values, n_present = [], [], 0, 0
+    for i, (nv, dl, bw, page_bytes, _off, segs) in enumerate(
+            pages.index_segments):
+        if bw != bw0 or not segs or any(s.kind != "packed" for s in segs):
+            return pages
+        packed = b"".join(page_bytes[s.byte_off:s.byte_off + s.byte_len]
+                          for s in segs)
+        present = int(dl.sum())
+        if i < last and len(packed) * 8 != present * bw:
+            return pages
+        parts.append(packed)
+        levels.append(dl)
+        n_values += nv
+        n_present += present
+    packed = b"".join(parts)
+    seg = RleSegment("packed", n_present, 0, 0, len(packed))
+    page = (n_values, np.concatenate(levels), bw0, packed, 0, [seg])
+    return ChunkPages(pages.physical_type, pages.dict_values, [page],
+                      pages.num_values)
+
+
 def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
                     encoded: bool = False):
     """Decode a parsed chunk into a TpuColumnVector. The common fast path
@@ -390,10 +425,11 @@ def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
         dict_dev = jnp.asarray(np.asarray(pages.dict_values))
     from spark_rapids_tpu.columnar.vector import bucket_capacity
 
+    pages = _merge_packed_pages(pages)
     # fast path: ONE data page, all-packed index segments → a single fused
     # program (unpack + dict gather + null spread + canonicalize). The eager
     # per-page pipeline below cost ~25 XLA dispatches per chunk — at TPC-H
-    # scan width that dominated hot-query wall time (docs/perf_notes.md r4).
+    # scan width that dominated hot-query wall time on XLA:CPU.
     if len(pages.index_segments) == 1:
         (num_values, def_levels, bw, page_bytes, values_off, segs) = \
             pages.index_segments[0]

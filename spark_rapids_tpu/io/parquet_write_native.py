@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.ops.windowing import cumsum
 
 MAGIC = b"PAR1"
 
@@ -196,7 +197,7 @@ def _prep_kernel(cap: int, dt_name: str):
     def k(vals, valid, n):
         live = jnp.arange(cap) < n
         vl = valid & live
-        running = jnp.cumsum(vl.astype(jnp.int32))
+        running = cumsum(vl.astype(jnp.int32))
         cnt = running[-1]
         j = jnp.arange(cap, dtype=jnp.int32)
         perm = jnp.clip(jnp.searchsorted(running, j + 1, side="left"),

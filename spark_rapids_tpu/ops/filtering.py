@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from spark_rapids_tpu.expr.core import Col
+from spark_rapids_tpu.ops.windowing import cumsum
 
 
 def selection_mask(pred: Col, num_rows, capacity: int):
@@ -35,9 +36,9 @@ def compact_cols(cols, keep_mask):
       per array at 1M rows while a gather is ~8 ms, so paying the scatter
       once instead of twice per column is ~3x at two columns and grows with
       width; searchsorted lowers to ~log2(cap) gather sweeps and measured
-      ~8x slower still (docs/perf_notes.md round-4)."""
+      ~8x slower still."""
     capacity = keep_mask.shape[0]
-    running = jnp.cumsum(keep_mask.astype(jnp.int32))
+    running = cumsum(keep_mask.astype(jnp.int32))
     count = running[-1]
     j = jnp.arange(capacity, dtype=jnp.int32)
     live = j < count
@@ -90,7 +91,7 @@ def host_compact_cols(cols, keep_mask, min_shrink: int = 4):
     yields the survivor indices, and a tiny gather program lands the output
     at bucket_capacity(count): the 3-row result of a 1M-capacity stage flows
     on at capacity 8 (measured ~50x on the compaction itself, and every
-    downstream per-batch program shrinks with it — docs/perf_notes.md r7).
+    downstream per-batch program shrinks with it).
 
     Returns (new_cols, count) or None when the output would not shrink by at
     least `min_shrink` (caller falls back to the in-program compact — for
@@ -132,8 +133,8 @@ def maybe_host_resize(cols, count, min_shrink: int = 4):
     HOST int count, or None when the input capacity is small or the shrink is
     under `min_shrink` (the sync would serialize the pipeline for nothing).
 
-    This is the stage-boundary half of the host-compaction design
-    (docs/perf_notes.md r7): a high-reduction operator output stops dragging
+    This is the stage-boundary half of the host-compaction design: a
+    high-reduction operator output stops dragging
     its stale input capacity through every downstream per-batch program."""
     from spark_rapids_tpu.columnar.vector import bucket_capacity
     from spark_rapids_tpu.runtime import fuse

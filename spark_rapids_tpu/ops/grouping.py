@@ -29,6 +29,7 @@ import typing
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.expr.core import Col
@@ -91,35 +92,33 @@ def combine_compact_keys(key_cols):
 def dense_group_sum(vals, mask, codes, n_domain: int, use_matmul: bool,
                     count_like: bool = False):
     """(n_domain,) per-group totals of `vals` over UNSORTED small-domain
-    codes — no sort, no segment structure. CPU: D-bucket scatter-add. TPU:
-    one-hot matmul (MXU-shaped; a cap-length scatter would serialize there,
-    the round-2 wedge lesson).
+    codes — no sort, no segment structure. CPU: D-bucket scatter-add. TPU
+    (`use_matmul`): a cap-length scatter would serialize there, so the
+    totals are D masked reductions in one fused pass over the rows.
 
     `count_like` marks 0/1-valued inputs (histograms, per-batch count
     updates): those are EXACT in f32 below 2^24 rows, so on TPU they ride
     the blocked Pallas one-hot kernel (pallas_kernels.onehot_sum_f32) which
     never materializes the (cap, D) one-hot in HBM — the medium-domain
-    MXU-shaped path. Everything else keeps the jnp one-hot (f64 for exact
-    integer sums), which bounds the practical domain."""
+    MXU-shaped path. Everything else sums in its own dtype: int64 stays
+    exact, f64 keeps the chip's emulated-f64 adds. (The f64 one-hot MATMUL
+    this replaces was measured on a v5e: XLA splits an f64 dot into f32
+    pieces, which was 1e-15 for f64 values but NOT exact for int64 sums
+    routed through it, 10-160x slower at 1 Mi rows, and several seconds
+    more to compile per aggregate.)"""
     v = jnp.where(mask, vals, jnp.zeros_like(vals))
     if use_matmul:
-        want = v.dtype
         if count_like and v.shape[0] < (1 << 24):
             # the f32 2^24 exactness bound: a batch cap at/above it could
-            # put >2^24 ones in one bucket — exact f64 path instead
+            # put >2^24 ones in one bucket — exact path below instead
             from spark_rapids_tpu.ops import pallas_kernels as PK
             if PK.should_use("onehot"):
                 out = PK.onehot_sum_f32(v.astype(jnp.float32), codes,
                                         n_domain)
-                return out.astype(want)
-        if jnp.issubdtype(want, jnp.integer):
-            # integer matmul is not an MXU op; f64 (emulated ~49-bit
-            # mantissa on TPU) sums counts exactly to ~5e14
-            v = v.astype(jnp.float64)
-        onehot = (codes[:, None] == jnp.arange(n_domain, dtype=jnp.int32)
-                  [None, :]).astype(v.dtype)
-        out = v @ onehot
-        return out.astype(want) if out.dtype != want else out
+                return out.astype(v.dtype)
+        hit = codes[:, None] == jnp.arange(n_domain, dtype=jnp.int32)[None, :]
+        return jnp.sum(jnp.where(hit, v[:, None], jnp.zeros((), v.dtype)),
+                       axis=0)
     out = jnp.zeros((n_domain + 1,), v.dtype)
     return out.at[jnp.clip(codes, 0, n_domain)].add(v,
                                                     mode="drop")[:n_domain]
@@ -207,7 +206,7 @@ def group_segments(key_cols, num_rows, capacity: int, range_hint=None,
         neq = neq | differs | (c.validity != prev_valid)
     first_live = jnp.arange(capacity) == 0
     boundary = (first_live | neq) & live
-    seg_ids = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+    seg_ids = W.cumsum(boundary.astype(jnp.int32)) - 1
     seg_ids = jnp.where(live, seg_ids, capacity - 1)
     seg_ids = jnp.clip(seg_ids, 0, capacity - 1)
     return perm, seg_ids, boundary, live
@@ -228,7 +227,7 @@ def segment_structure(seg_ids, capacity: int) -> SegCtx:
 def _edge_sum(data, ctx: SegCtx):
     """Per-row segment total of `data` via one global cumsum differenced at the
     row's segment edges. Exact for ints (wrap cancels); f64 error ~ulp(prefix)."""
-    cs = jnp.cumsum(data, axis=0)
+    cs = W.cumsum(data)
     csz = jnp.concatenate([jnp.zeros((1,), cs.dtype), cs])
     return csz[ctx.seg_end + 1] - csz[ctx.seg_start]
 
@@ -238,6 +237,42 @@ def _seg_scan(data, ctx: SegCtx, combine):
     generic windowing.segmented_scan would re-derive it per call)."""
     from spark_rapids_tpu.ops.windowing import _doubling_scan
     return _doubling_scan(data, lambda i, s: (i - s) >= ctx.seg_start, combine)
+
+
+_TREE_SMALL = 1 << 12   # levels this short are walked as a second group
+
+
+def _tree_walk(levels, k0: int, lo, hi, out):
+    """Consume levels k0.. of the range-sum tree against the open ranges
+    [lo, hi): one loop over the levels laid end to end, so the chip's
+    compiler sees ONE gather body instead of two per level (unrolled, the
+    40 gathers of a 1 Mi-row tree took it 19 s; this form 1 s). The adds
+    happen in the same order either way."""
+    sizes = np.array([lv.shape[0] for lv in levels], np.int32)
+    offs_d = jnp.asarray(np.concatenate([[0], np.cumsum(sizes)[:-1]])
+                         .astype(np.int32))
+    last_d = jnp.asarray(sizes - 1)
+    flat = jnp.concatenate(levels)
+
+    def body(i, st):
+        lo, hi, out = st
+        i = i.astype(jnp.int32)
+        k = i + jnp.int32(k0)
+        blk = jnp.int32(1) << k
+        off, last = offs_d[i], last_d[i]
+        # consume a 2^k block at the front if lo is 2^k-aligned-odd
+        take_lo = ((lo & blk) != 0) & (lo + blk <= hi)
+        contrib = flat[off + jnp.clip(lo >> k, 0, last)]
+        out = out + jnp.where(take_lo, contrib, jnp.zeros_like(out))
+        lo = jnp.where(take_lo, lo + blk, lo)
+        # and one at the back if hi has bit k set
+        take_hi = ((hi & blk) != 0) & (hi - blk >= lo)
+        contrib = flat[off + jnp.clip((hi - blk) >> k, 0, last)]
+        out = out + jnp.where(take_hi, contrib, jnp.zeros_like(out))
+        hi = jnp.where(take_hi, hi - blk, hi)
+        return lo, hi, out
+
+    return jax.lax.fori_loop(0, len(levels), body, (lo, hi, out))
 
 
 def _seg_sum_tree(data, ctx: SegCtx):
@@ -250,8 +285,9 @@ def _seg_sum_tree(data, ctx: SegCtx):
     segment prefixes (the flaw of cumsum edge-differencing), and the pairwise
     build gives better-than-sequential float error. Cost: log2(cap) masked
     gathers from geometrically shrinking levels vs log2(cap) full-width
-    combine passes for the doubling scan (~20x cheaper at 256k rows)."""
-    cap = ctx.capacity
+    combine passes for the doubling scan (~20x cheaper at 256k rows). The
+    long levels and the short ones are walked as two groups, so the gathers
+    from short levels stay gathers from a small table."""
     levels = [data]
     while levels[-1].shape[0] > 1:
         x = levels[-1]
@@ -259,22 +295,13 @@ def _seg_sum_tree(data, ctx: SegCtx):
             x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
         levels.append(x.reshape(-1, 2).sum(axis=1))
 
-    lo = ctx.seg_start
-    hi = ctx.seg_end + 1
-    out = jnp.zeros_like(data)
-    for k in range(len(levels)):
-        blk = jnp.int32(1 << k)
-        # consume a 2^k block at the front if lo is 2^k-aligned-odd
-        take_lo = ((lo & blk) != 0) & (lo + blk <= hi)
-        contrib = levels[k][jnp.clip(lo >> k, 0, levels[k].shape[0] - 1)]
-        out = out + jnp.where(take_lo, contrib, jnp.zeros_like(out))
-        lo = jnp.where(take_lo, lo + blk, lo)
-        # and one at the back if hi has bit k set
-        take_hi = ((hi & blk) != 0) & (hi - blk >= lo)
-        contrib = levels[k][jnp.clip((hi - blk) >> k, 0, levels[k].shape[0] - 1)]
-        out = out + jnp.where(take_hi, contrib, jnp.zeros_like(out))
-        hi = jnp.where(take_hi, hi - blk, hi)
-    return out
+    split = next(k for k, lv in enumerate(levels)
+                 if lv.shape[0] <= _TREE_SMALL)
+    st = (ctx.seg_start, ctx.seg_end + 1, jnp.zeros_like(data))
+    for k0, group in ((0, levels[:split]), (split, levels[split:])):
+        if group:
+            st = _tree_walk(group, k0, *st)
+    return st[2]
 
 
 def _seg_extreme(data, ctx: SegCtx, largest: bool):
@@ -283,7 +310,10 @@ def _seg_extreme(data, ctx: SegCtx, largest: bool):
     the extreme lands on the segment's first/last row. One native sort
     (~log n comparator passes fused by XLA) instead of a log-step doubling
     scan over full-width data."""
-    _, sorted_vals = jax.lax.sort([ctx.seg_ids, data], num_keys=2)
+    # every operand is a key, so stability buys nothing (and its extra
+    # index operand costs the chip's compiler tens of seconds)
+    _, sorted_vals = jax.lax.sort([ctx.seg_ids, data], num_keys=2,
+                                  is_stable=False)
     pos = ctx.seg_end if largest else ctx.seg_start
     return sorted_vals[pos]
 

@@ -35,6 +35,7 @@ ops/parquet_decode.py remain the oracle and the fallback.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -48,13 +49,52 @@ _C2 = np.int32(np.uint32(0x1B873593))
 _M5 = np.int32(np.uint32(0xE6546B64))
 _FX1 = np.int32(np.uint32(0x85EBCA6B))
 _FX2 = np.int32(np.uint32(0xC2B2AE35))
+# the package runs with jax_enable_x64: a bare Python int in an index map or
+# a kernel body is a 64-bit scalar, which Mosaic refuses. Block indices and
+# in-kernel constants are spelled as 32-bit values.
+_I0 = np.int32(0)
 
 
-# dispatch switch: None = auto (compiled kernels on TPU, jnp reference
-# elsewhere); True forces the kernels (interpret-mode off-TPU — tests);
-# False forces the jnp paths (spark.rapids.tpu.sql.pallas.enabled=false)
+# dispatch switch: None = auto (the table below, on the TPU backend); True
+# forces the kernels (interpret-mode off-TPU — tests); False forces the jnp
+# paths (spark.rapids.tpu.sql.pallas.enabled=false)
 _FORCE: bool | None = None
-_TPU_PROBE: dict | None = None  # per-kernel latched compile-probe results
+
+# The one switch table: kernel -> None (on) or the reason it is off. "On"
+# means the chip's compiler accepted the kernel at the shapes the TPC-H SF 1
+# main path uses (tests/test_tpu_compile.py compiles each for a described
+# v5e). A kernel that is on and fails to compile on the chip raises the
+# compiler's error; nothing latches it off at run time. A kernel that is off
+# carries its reason: the compiler's message (its compile test is then a
+# strict xfail quoting it), or, after "chip:", what a run on the chip showed
+# of a kernel that does compile.
+KERNELS: dict[str, str | None] = {
+    # compiles, and is wrong on the chip: a smoke run on a TPU v5 lite
+    # (PR 24) unpacked 1 Mi random values per width and compared with NumPy.
+    # Widths 1-16 came back exact; every width 17-31 had wrong values (135 of
+    # 1 Mi at width 17, 1971 at width 24), always a value that straddles two
+    # words with its low part 16 bits or longer, always with bits >= 16
+    # missing (got 11459, want 76995). Interpret mode is exact at every
+    # width, and so is ops/parquet_decode.unpack_bits_device on the chip. It
+    # made TPC-H q5 wrong (c/s_nationkey and l_suppkey pages).
+    "bitunpack": (
+        "chip: Mosaic accepts the kernel but on a TPU v5 lite it drops bits "
+        ">= 16 of values that straddle two words, for every bit width "
+        "17-31 (135 wrong of 1 Mi at width 17; interpret mode is exact)"),
+    "radix": None,
+    # build compiles (it rides radix_ranks); the probe does not
+    "hashjoin": (
+        "hash_join_probe: RecursionError: maximum recursion depth exceeded "
+        "in Mosaic lowering (the kernel is 64-bit: int64 key refs and a "
+        "64-bit multiply hash; the integer convert rule has no 64-bit "
+        "case). With keys split into int32 lanes and the hash moved out, "
+        "the per-row table gather tk[base + s] is refused: "
+        "NotImplementedError: Only 2D gather is supported"),
+    "onehot": None,
+    "murmur3": None,
+}
+
+_traced: collections.Counter = collections.Counter()
 
 
 def set_mode(force: bool | None) -> None:
@@ -62,51 +102,18 @@ def set_mode(force: bool | None) -> None:
     _FORCE = force
 
 
-def _probe_tpu(kernel: str) -> bool:
-    """Compile a tiny instance of `kernel` once on the TPU backend. A
-    Mosaic lowering failure inside an enclosing jit would surface as an
-    opaque engine error at compile time; probing here instead latches the
-    dispatch off so the jnp formulations keep the engine correct. Latches
-    are PER KERNEL: a lowering failure in one (e.g. a newly added kernel
-    that has never met real hardware) must not disable the proven ones."""
-    global _TPU_PROBE
-    if _TPU_PROBE is None:
-        _TPU_PROBE = {}
-    if kernel not in _TPU_PROBE:
-        try:
-            if kernel == "murmur3":
-                w = jnp.zeros((8, 2), jnp.int32)
-                l = jnp.full((8,), 5, jnp.int32)
-                jax.block_until_ready(murmur3_words(w, l, 42))
-            elif kernel == "bitunpack":
-                jax.block_until_ready(
-                    bitunpack128(jnp.zeros((32,), jnp.int32), 8, 100, 128))
-            elif kernel == "onehot":
-                jax.block_until_ready(
-                    onehot_sum_f32(jnp.ones((256,), jnp.float32),
-                                   jnp.zeros((256,), jnp.int32), 140))
-            elif kernel == "radix":
-                ids = jnp.asarray([1, 0, 2, 1, 0, 3, 3, 0], jnp.int32)
-                jax.block_until_ready(radix_partition_permutation(ids, 4))
-            elif kernel == "hashjoin":
-                keys = jnp.arange(16, dtype=jnp.int64)
-                elig = jnp.ones((16,), jnp.bool_)
-                tk, tr, ok = hash_join_build(keys, elig, 128)
-                jax.block_until_ready(
-                    hash_join_probe(tk, tr, keys[:8], 128))
-            else:
-                raise ValueError(f"unknown pallas kernel {kernel!r}")
-            _TPU_PROBE[kernel] = True
-        except Exception:  # noqa: BLE001 — any lowering failure latches off
-            _TPU_PROBE[kernel] = False
-    return _TPU_PROBE[kernel]
-
-
 def should_use(kernel: str = "murmur3") -> bool:
     """Does the engine route `kernel`'s op here on this backend?"""
+    off = KERNELS[kernel]          # unknown kernel name: KeyError
     if _FORCE is not None:
         return _FORCE
-    return jax.default_backend() == "tpu" and _probe_tpu(kernel)
+    return jax.default_backend() == "tpu" and off is None
+
+
+def traced() -> dict:
+    """{kernel: times its pallas_call was traced into a program} in this
+    process — which kernels a run really reached (chip_smoke.py prints it)."""
+    return dict(_traced)
 
 
 def _interpret() -> bool:
@@ -139,31 +146,31 @@ def _fmix(h1, length):
 # murmur3 string hash
 # ---------------------------------------------------------------------------
 
-_HASH_TILE = 256
+_HASH_ROWS = 64  # sublane rows of 128 strings per grid step (8192 strings)
 
 
 def _murmur3_kernel(words_ref, len_ref, seed_ref, out_ref, *, W: int):
-    words = words_ref[:]                      # (T, W) int32
-    lens = len_ref[:]                         # (T, 1) int32
-    h1 = seed_ref[:]                          # (T, 1) int32 running hash
-    n_words = lens // 4
-    n_tail = lens % 4
+    # strings lie along (sublane, lane): every operand is a dense (R, 128)
+    # slab, word column i is the slab words_ref[i]
+    lens = len_ref[...]                       # (R, 128) int32
+    h1 = seed_ref[...]                        # (R, 128) int32 running hash
+    n_words = lax.shift_right_logical(lens, jnp.int32(2))   # lens >= 0
+    n_tail = lens & jnp.int32(3)
     # whole-word rounds, statically unrolled; rows shorter than column i
     # keep their running hash through a vector select
     for i in range(W):
-        k = words[:, i:i + 1]
-        h1 = jnp.where(i < n_words, _mix_h1(h1, _mix_k1(k)), h1)
+        h1 = jnp.where(i < n_words, _mix_h1(h1, _mix_k1(words_ref[i])), h1)
     # the tail word (index n_words, per row) via static-column selects —
     # a dynamic per-row gather would not vectorize on the VPU
     tail_word = jnp.zeros_like(lens)
     for i in range(W):
-        tail_word = jnp.where(n_words == i, words[:, i:i + 1], tail_word)
+        tail_word = jnp.where(n_words == i, words_ref[i], tail_word)
     for t in range(3):
         byte = lax.shift_right_logical(tail_word,
                                        jnp.int32(8 * t)) & jnp.int32(0xFF)
         sbyte = jnp.where(byte >= 128, byte - 256, byte)
         h1 = jnp.where(t < n_tail, _mix_h1(h1, _mix_k1(sbyte)), h1)
-    out_ref[:] = _fmix(h1, lens)
+    out_ref[...] = _fmix(h1, lens)
 
 
 def murmur3_words(words, lengths, seed) -> jnp.ndarray:
@@ -173,28 +180,33 @@ def murmur3_words(words, lengths, seed) -> jnp.ndarray:
     `seed` may be a scalar or a per-row (n,) running hash (the partitioner
     chains column hashes, so the seed is usually row-varying).
     """
+    _traced["murmur3"] += 1
     n, W = words.shape
-    tile = min(_HASH_TILE, max(8, n))
-    n_pad = -(-n // tile) * tile
-    words_p = jnp.zeros((n_pad, W), jnp.int32).at[:n].set(
-        words.astype(jnp.int32))
-    lens_p = jnp.zeros((n_pad, 1), jnp.int32).at[:n, 0].set(
-        lengths.astype(jnp.int32))
+    rows = -(-max(n, 1) // 128)
+    tile = min(_HASH_ROWS, -(-rows // 8) * 8)
+    rows_p = -(-rows // tile) * tile
+    n_pad = rows_p * 128
+
+    def slab(x):                              # (n,) -> (rows_p, 128)
+        return jnp.zeros((n_pad,), jnp.int32).at[:n].set(
+            x.astype(jnp.int32)).reshape(rows_p, 128)
+
+    words_p = jnp.zeros((W, n_pad), jnp.int32).at[:, :n].set(
+        words.astype(jnp.int32).T).reshape(W, rows_p, 128)
     seed_rows = jnp.broadcast_to(jnp.asarray(seed, jnp.int32), (n,))
-    seed_p = jnp.zeros((n_pad, 1), jnp.int32).at[:n, 0].set(seed_rows)
     out = pl.pallas_call(
         functools.partial(_murmur3_kernel, W=W),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-        grid=(n_pad // tile,),
+        out_shape=jax.ShapeDtypeStruct((rows_p, 128), jnp.int32),
+        grid=(rows_p // tile,),
         in_specs=[
-            pl.BlockSpec((tile, W), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((W, tile, 128), lambda i: (_I0, i, _I0)),
+            pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
+            pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
         ],
-        out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
         interpret=_interpret(),
-    )(words_p, lens_p, seed_p)
-    return out[:n, 0]
+    )(words_p, slab(lengths), slab(seed_rows))
+    return out.reshape(n_pad)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -218,15 +230,21 @@ def _bitunpack_kernel(w_ref, out_ref, *, bw: int):
     out_ref[:] = jnp.concatenate(cols, axis=1)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def bitunpack128(words_u32, bit_width: int, n: int, capacity: int):
     """Unpack `n` bit-packed values of `bit_width` bits from 32-bit words
     into (capacity,) int32. 128 values of width bw span exactly 4*bw words,
     so the kernel reads only statically-indexed columns.
 
     words_u32: (ceil(n/128)*4*bw,) int32 — packed little-endian words.
+
+    Jitted on its static arguments: an eager caller (the per-page decode
+    loop) then compiles once per shape, where a bare eager pallas_call is
+    re-lowered by Mosaic on every call.
     """
     if not 1 <= bit_width <= 32:
         raise ValueError(f"bit width {bit_width} out of range")
+    _traced["bitunpack"] += 1
     bw = bit_width
     n128 = max(1, -(-n // 128))
     tile = min(_UNPACK_TILE, n128)
@@ -242,8 +260,8 @@ def bitunpack128(words_u32, bit_width: int, n: int, capacity: int):
         functools.partial(_bitunpack_kernel, bw=bw),
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int32),
         grid=(rows // tile,),
-        in_specs=[pl.BlockSpec((tile, 4 * bw), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tile, 128), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((tile, 4 * bw), lambda i: (i, _I0))],
+        out_specs=pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
         interpret=_interpret(),
     )(w)
     flat = out.reshape(-1)
@@ -278,30 +296,33 @@ def _onehot_kernel(codes_ref, vals_ref, out_ref, *, bk: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     d0 = pl.program_id(0) * _OH_BD
-    codes = codes_ref[0, :]                    # (bk,) int32
-    vals = vals_ref[0, :]                      # (bk,) f32
-    lanes = d0 + lax.broadcasted_iota(jnp.int32, (bk, _OH_BD), 1)
-    onehot = (codes[:, None] == lanes).astype(jnp.float32)
-    out_ref[0, :] += jnp.dot(vals, onehot,
-                             preferred_element_type=jnp.float32)
+    # the one-hot is built transposed, (domain lane-block, rows): the codes
+    # row broadcasts along sublanes, so no lane->sublane relayout is needed,
+    # and the contraction is the MXU's native A @ B^T form
+    lanes = d0 + lax.broadcasted_iota(jnp.int32, (_OH_BD, bk), 0)
+    onehot_t = (codes_ref[...] == lanes).astype(jnp.float32)   # (128, bk)
+    out_ref[...] += lax.dot_general(
+        vals_ref[...], onehot_t, (((1,), (1,)), ((), ())),
+        precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                    # (1, 128)
 
 
 def onehot_sum_f32(vals, codes, n_domain: int):
     """(n_domain,) f32 bucket sums of `vals` over int32 `codes` — the
-    generalized one-hot-matmul group-by (VERDICT r4 next #7; reference
-    analog: cudf's hash groupby behind aggregate.scala:706).
+    generalized one-hot-matmul group-by (reference analog: cudf's hash groupby behind aggregate.scala:706).
 
     The jnp formulation in ops/grouping.dense_group_sum materializes the
     (cap, D) one-hot in HBM — fine at D<=128, ruinous at medium domains.
     This kernel generates each (BK, 128) one-hot tile on the fly in VMEM
     and feeds the MXU, cutting HBM traffic from O(cap*D) one-hot elements
     to O(cap * D/128) input re-streams (rows stream once per 128-lane
-    domain block) + O(D) output; nothing is scattered (the round-2 wedge
-    lesson), and every shape is static.
+    domain block) + O(D) output; nothing is scattered (large scatters
+    serialize on the TPU), and every shape is static.
 
     Exactness: f32 accumulation — callers use it for 0/1 histograms and
     per-batch counts (exact below 2^24) and f32 sums; f64 sums stay on the
     jnp path."""
+    _traced["onehot"] += 1
     cap = vals.shape[0]
     # lane-aligned row block: Mosaic wants multiples of 128 (the probe's
     # aligned instance would not catch a misaligned caller)
@@ -316,9 +337,9 @@ def onehot_sum_f32(vals, codes, n_domain: int):
         functools.partial(_onehot_kernel, bk=bk),
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         grid=(dp // _OH_BD, capp // bk),
-        in_specs=[pl.BlockSpec((1, bk), lambda i, k: (0, k)),
-                  pl.BlockSpec((1, bk), lambda i, k: (0, k))],
-        out_specs=pl.BlockSpec((1, _OH_BD), lambda i, k: (0, i)),
+        in_specs=[pl.BlockSpec((1, bk), lambda i, k: (_I0, k)),
+                  pl.BlockSpec((1, bk), lambda i, k: (_I0, k))],
+        out_specs=pl.BlockSpec((1, _OH_BD), lambda i, k: (_I0, i)),
         interpret=_interpret(),
     )(codes2, vals2)
     return out[0, :n_domain]
@@ -338,22 +359,30 @@ def _radix_kernel(ids_ref, rank_ref, counts_ref, *, bk: int, dp: int):
     (`counts_ref`, one block revisited every step — the sequential TPU grid
     is the carry chain) turns per-tile exclusive one-hot cumsums into global
     stable ranks: rank(row) = rows with the same id in earlier tiles +
-    same-id rows above it in this tile. All dense (BK, DP) VPU work — the
-    scatter that cudf's radix partition would do stays outside the kernel."""
+    same-id rows above it in this tile. The one-hot is held transposed,
+    (DP, BK): the ids row broadcasts along sublanes and the ranks come out
+    as a row. Mosaic lowers no cumsum, so the running count along the tile
+    is one MXU product with an upper-triangular 0/1 matrix (0/1 is exact in
+    bf16, the f32 accumulator is exact to 2^24 > BK). The scatter that
+    cudf's radix partition would do stays outside the kernel."""
     k = pl.program_id(0)
 
     @pl.when(k == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    ids = ids_ref[0, :]                                   # (bk,) int32
-    lanes = lax.broadcasted_iota(jnp.int32, (bk, dp), 1)
-    onehot = (ids[:, None] == lanes).astype(jnp.int32)    # out-of-range → 0s
-    carry = counts_ref[0, :]                              # (dp,) prior tiles
-    incl = jnp.cumsum(onehot, axis=0)
-    rank_ref[0, :] = jnp.sum(onehot * (incl - onehot + carry[None, :]),
-                             axis=1, dtype=jnp.int32)
-    counts_ref[0, :] = carry + incl[-1, :]
+    lanes = lax.broadcasted_iota(jnp.int32, (dp, bk), 0)
+    hit = ids_ref[...] == lanes                       # (dp, bk); id>=dp → 0s
+    onehot = hit.astype(jnp.int32)
+    upper = (lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+             <= lax.broadcasted_iota(jnp.int32, (bk, bk), 1))
+    incl = jnp.dot(hit.astype(jnp.bfloat16), upper.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    carry = counts_ref[...]                           # (dp, 1) prior tiles
+    rank_ref[...] = jnp.sum(onehot * (incl - onehot + carry),
+                            axis=0, keepdims=True, dtype=jnp.int32)
+    counts_ref[...] = carry + jnp.sum(onehot, axis=1, keepdims=True,
+                                      dtype=jnp.int32)
 
 
 def radix_ranks(ids, num_lanes: int):
@@ -361,6 +390,7 @@ def radix_ranks(ids, num_lanes: int):
     (ranks, counts) where ranks[i] = #{j < i : ids[j] == ids[i]} and
     counts[l] = #{ids == l}. Ids outside [0, num_lanes) (padding sentinel)
     get rank 0 and are not counted."""
+    _traced["radix"] += 1
     cap = ids.shape[0]
     dp = -(-max(num_lanes, 1) // 128) * 128
     if dp > RADIX_MAX_PARTS:
@@ -376,14 +406,14 @@ def radix_ranks(ids, num_lanes: int):
     ranks, counts = pl.pallas_call(
         functools.partial(_radix_kernel, bk=bk, dp=dp),
         out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, dp), jnp.int32)],
+                   jax.ShapeDtypeStruct((dp, 1), jnp.int32)],
         grid=(n_pad // bk,),
-        in_specs=[pl.BlockSpec((1, bk), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, bk), lambda i: (0, i)),
-                   pl.BlockSpec((1, dp), lambda i: (0, 0))],
+        in_specs=[pl.BlockSpec((1, bk), lambda i: (_I0, i))],
+        out_specs=[pl.BlockSpec((1, bk), lambda i: (_I0, i)),
+                   pl.BlockSpec((dp, 1), lambda i: (_I0, _I0))],
         interpret=_interpret(),
     )(ids_p)
-    return ranks[0, :cap], counts[0, :num_lanes]
+    return ranks[0, :cap], counts[:num_lanes, 0]
 
 
 def radix_partition_permutation(ids, num_lanes: int):
@@ -481,6 +511,7 @@ def hash_join_probe(table_keys, table_rows, stream_i64, num_buckets: int):
     Unique-keys contract: at most one slot matches. Validity/liveness
     masking is the caller's job (hash of an invalid row's value is
     harmless; its hit is masked off outside)."""
+    _traced["hashjoin"] += 1
     h_bits = num_buckets.bit_length() - 1
     n = stream_i64.shape[0]
     tile = min(_HJ_TILE, max(8, n))
@@ -493,12 +524,12 @@ def hash_join_probe(table_keys, table_rows, stream_i64, num_buckets: int):
                    jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
         grid=(n_pad // tile,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec((1, hs), lambda i: (0, 0)),
-            pl.BlockSpec((1, hs), lambda i: (0, 0)),
+            pl.BlockSpec((1, tile), lambda i: (_I0, i)),
+            pl.BlockSpec((1, hs), lambda i: (_I0, _I0)),
+            pl.BlockSpec((1, hs), lambda i: (_I0, _I0)),
         ],
-        out_specs=[pl.BlockSpec((1, tile), lambda i: (0, i)),
-                   pl.BlockSpec((1, tile), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((1, tile), lambda i: (_I0, i)),
+                   pl.BlockSpec((1, tile), lambda i: (_I0, i))],
         interpret=_interpret(),
     )(sp, table_keys.reshape(1, hs), table_rows.reshape(1, hs))
     return pos[0, :n], found[0, :n].astype(jnp.bool_)

@@ -14,6 +14,7 @@ import typing
 
 import numpy as np
 import jax.numpy as jnp
+from spark_rapids_tpu.ops.windowing import cumsum
 
 
 class EncodedPageSpec(typing.NamedTuple):
@@ -61,7 +62,7 @@ def expand_present_to_rows(present_vals: jnp.ndarray,
     """Parquet stores values only for non-null slots; spread them over the
     full row layout: row j takes present value rank(j) where rank is the
     prefix count of set definition levels (a gather, not a scatter)."""
-    ranks = jnp.cumsum(def_levels.astype(jnp.int32)) - 1
+    ranks = cumsum(def_levels.astype(jnp.int32)) - 1
     safe = jnp.clip(ranks, 0, capacity - 1)
     vals = present_vals[safe]
     valid = def_levels.astype(jnp.bool_)

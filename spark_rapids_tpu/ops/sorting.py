@@ -199,7 +199,10 @@ def sort_permutation(key_cols, orders, num_rows, capacity: int,
     for c, o in zip(key_cols, orders):
         operands.extend(_key_arrays(c, o))
     iota = jnp.arange(capacity, dtype=jnp.int32)
-    res = lax.sort(tuple(operands) + (iota,), num_keys=len(operands), is_stable=True)
+    # the row index as last key = the stable order, without the second
+    # index operand a stable sort would add
+    res = lax.sort(tuple(operands) + (iota,), num_keys=len(operands) + 1,
+                   is_stable=False)
     return res[-1]
 
 
@@ -214,7 +217,7 @@ def partition_permutation(part_ids, num_partitions: int, num_rows,
                           capacity: int):
     """Stable permutation grouping live rows by partition id with padding
     sunk to the end — the exchange partition step. Ids are a tiny dense
-    domain, so a comparator sort is overkill: when the radix latch is up
+    domain, so a comparator sort is overkill: when the radix kernel is routed
     the Pallas counting-rank kernel (pallas_kernels.radix_partition_permutation)
     produces the permutation from one-hot cumsums; otherwise the stable
     argsort stands in."""

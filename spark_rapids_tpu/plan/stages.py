@@ -201,8 +201,7 @@ def explain_fused(root, collector=None) -> str:
     """`explain(fused=True)` body: the stage-annotated tree plus one summary
     block per stage naming its members, the logical operators fused into
     them, and (when a finished query's collector is supplied) each member's
-    dispatch and batch counts — dispatches/batch is the fusion win metric
-    (docs/perf_notes.md round 7)."""
+    dispatch and batch counts — dispatches/batch is the fusion win metric."""
     out = [render_tree(root)]
     per_node: dict = {}
     if collector is not None:

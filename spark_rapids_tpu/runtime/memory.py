@@ -950,7 +950,7 @@ class DeviceManager:
     catalog (reference GpuDeviceManager.scala:36 + RapidsBufferCatalog.init:177).
 
     One executor owns one TPU chip in the reference's model (GpuDeviceManager.scala:103);
-    here the local runtime owns jax.devices()[0] and multi-chip execution goes through
+    here the local runtime owns the device at spark.rapids.tpu.device.ordinal and multi-chip execution goes through
     the Mesh path (distributed/), matching SURVEY.md §7's executor-per-chip decision.
     """
 
@@ -959,17 +959,20 @@ class DeviceManager:
 
     def __init__(self, conf: C.RapidsConf):
         self.conf = conf
-        self.device = jax.devices()[0]
+        devices = jax.devices()
+        self.device = devices[conf.get(C.DEVICE_ORDINAL)]
+        if self.device != devices[0]:
+            # uploads and uncommitted programs follow the chosen device
+            jax.config.update("jax_default_device", self.device)
         limit = conf.get(C.DEVICE_MEMORY_LIMIT)
         if not limit:
-            stats = None
-            try:
-                stats = self.device.memory_stats()
-            except Exception:
-                pass
-            hbm = (stats or {}).get("bytes_limit", 0)
+            hbm = (self.device.memory_stats() or {}).get("bytes_limit", 0)
             if not hbm:
-                hbm = 16 << 30  # CPU backend exposes no limit; assume one v5e chip's HBM
+                if self.device.platform != "cpu":
+                    raise RuntimeError(
+                        f"{self.device} reports no memory bytes_limit; set "
+                        f"{C.DEVICE_MEMORY_LIMIT.key} to budget it by hand")
+                hbm = 16 << 30  # the CPU backend exposes no limit; assume one v5e chip's HBM
             limit = int(hbm * conf.get(C.DEVICE_MEMORY_FRACTION))
         spill_dirs = conf.get(C.SPILL_DIRS)
         self.catalog = BufferCatalog(

@@ -7,7 +7,7 @@ so its pull-based iterator chain still pipelines at the hardware level. Here
 XLA dispatch is synchronous per program and host arrow decode shares the
 query thread, so BENCH_r06 found the engine overhead-bound — parquet decode,
 device compute and exchange serialization run strictly sequentially
-(docs/perf_notes.md round-6). This module supplies the missing concurrency
+(an XLA:CPU profile). This module supplies the missing concurrency
 EXPLICITLY: physical plans are cut into segments at the existing pipeline
 breakers (scan, exchange map/reduce, join build, sort, final collect) and
 each segment's batch loop runs on its own worker thread, connected by

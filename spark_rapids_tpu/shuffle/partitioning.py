@@ -94,7 +94,7 @@ def slice_into_partitions(batch: ColumnarBatch, part_ids, num_partitions: int):
     n = batch.num_rows
     live = jnp.arange(cap, dtype=jnp.int32) < n
     ids = jnp.where(live, part_ids.astype(jnp.int32), jnp.int32(num_partitions))
-    # radix-rank kernel when latched, stable argsort otherwise; padding rows
+    # radix-rank kernel when routed, stable argsort otherwise; padding rows
     # sink to the end via the sentinel id either way
     from spark_rapids_tpu.ops.sorting import partition_permutation
     perm = partition_permutation(part_ids, num_partitions, n, cap)

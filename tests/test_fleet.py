@@ -384,7 +384,7 @@ def _spawn_victim(fleet_dir, faults_spec):
 
 @pytest.mark.slow
 def test_sigkill_midstream_failover_bit_identical(tmp_path):
-    """The headline failover contract: a victim replica PROCESS (wedged by a
+    """The headline failover contract: a victim replica PROCESS (hung by a
     hang fault at its first result frame, so the kill lands mid-stream) is
     SIGKILLed while serving; the client's submit_with_retry rotates to the
     in-process survivor and the result is bit-identical — with zero leaked
@@ -424,7 +424,7 @@ def test_sigkill_midstream_failover_bit_identical(tmp_path):
 
         t = threading.Thread(target=run, daemon=True)
         t.start()
-        time.sleep(1.5)     # the victim is wedged at its first result frame
+        time.sleep(1.5)     # the victim is hung at its first result frame
         os.kill(victim.pid, signal.SIGKILL)
         t.join(timeout=240)
         assert not t.is_alive(), "failover client never finished"

@@ -127,7 +127,7 @@ def test_journey_served_then_cached_records(tmp_path):
 
 
 def test_journey_spans_failover_and_trace_rides_along(tmp_path):
-    """The tentpole timeline: attempt 1 dies by replica timeout on a wedged
+    """The tentpole timeline: attempt 1 dies by replica timeout on a hung
     replica, attempt 2 serves on the next one — ONE journey id, and (the
     retry-trace regression) ONE trace id equal to it across both attempts."""
     spark = _session({
@@ -472,7 +472,7 @@ def _spawn_victim(fleet_dir, log_dir):
 @pytest.mark.slow
 def test_sigkill_blackbox_dump_and_merged_journey(tmp_path):
     """The post-mortem contract end to end with a real victim PROCESS: the
-    wedged victim's heartbeat watchdog dumps the flight recorder (naming
+    hung victim's heartbeat watchdog dumps the flight recorder (naming
     the in-flight journey) and closes the journey as replica_timeout
     BEFORE the SIGKILL; the in-process survivor serves attempt 2, adopts
     the lease with the blackbox path on fleet.adopt, and profiler.py
@@ -509,7 +509,7 @@ def test_sigkill_blackbox_dump_and_merged_journey(tmp_path):
 
         t = threading.Thread(target=run, daemon=True)
         t.start()
-        # let the query wedge at its first result frame, age past the 1s
+        # let the query hang at its first result frame, age past the 1s
         # request timeout, and a 0.5s heartbeat run the watchdog + dump
         assert _wait(bb_path.exists, timeout_s=30), \
             "victim never dumped its flight recorder"

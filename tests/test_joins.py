@@ -346,3 +346,47 @@ def test_dtype_max_key_fast_path(how):
     got2 = j2.execute_collect()
     want2 = host_join(lt64, rt64, "lk", "rk", how)
     assert got2.num_rows == want2.num_rows
+
+
+@pytest.mark.parametrize("dtypes", [("int64", "int64"), ("int64", "int32"),
+                                    ("bool", "int64", "int32"),
+                                    ("int64", "float64")])
+def test_join_ranks_are_tuple_equality(dtypes):
+    """ops/joining.join_ranks: rank equality == key-tuple equality across
+    both sides and rank order == tuple order (integer-backed keys are ranked
+    by one-operand sorts, a fractional key keeps the comparator sort);
+    null-keyed and padding rows get sentinels that never match."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.expr.core import Col
+    from spark_rapids_tpu.ops import joining as J
+    r = np.random.default_rng(len(dtypes))
+    bcap, scap, nb, ns = 64, 128, 50, 100
+    sp = {"int64": T.LONG, "int32": T.INT, "bool": T.BOOLEAN,
+          "float64": T.DOUBLE}
+
+    def side(cap):
+        cols, tuples = [], []
+        for dt in dtypes:
+            if dt == "bool":
+                v = r.random(cap) < 0.5
+            elif dt == "int64":   # extremes: not packable beside a row index
+                v = r.choice(np.array([-2**63, -7, 0, 3, 2**63 - 1]), cap)
+            else:
+                v = r.integers(-2, 3, cap).astype(dt)
+            valid = r.random(cap) > 0.1
+            cols.append(Col(jnp.asarray(v), jnp.asarray(valid), sp[dt]))
+            tuples.append([x if ok else None
+                           for x, ok in zip(v.tolist(), valid.tolist())])
+        return cols, list(zip(*tuples))
+
+    (b, bt), (s, st) = side(bcap), side(scap)
+    br, sr = (np.asarray(x) for x in J.join_ranks(b, nb, bcap, s, ns, scap))
+    rows = ([(t, int(k), i < nb) for i, (t, k) in enumerate(zip(bt, br))]
+            + [(t, int(k), i < ns) for i, (t, k) in enumerate(zip(st, sr))])
+    assert all(k == J._PAD_RANK for _, k, live in rows if not live)
+    live = [(t, k) for t, k, ok in rows if ok]
+    assert all((k < 0) == (None in t) for t, k in live)
+    keyed = [(t, k) for t, k in live if None not in t]
+    for t1, k1 in keyed:
+        for t2, k2 in keyed:
+            assert (k1 == k2) == (t1 == t2) and (k1 < k2) == (t1 < t2)

@@ -241,3 +241,51 @@ def test_sort_spills_accumulated_inputs(monkeypatch, tmp_path):
         n = t.num_rows
         assert out[at:at + n] == sorted(t["v"].to_pylist())
         at += n
+
+
+# -- device choice and HBM budget (DeviceManager) ------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+    def __repr__(self):
+        return f"FakeDevice({self.platform})"
+
+
+@pytest.mark.parametrize("stats", [None, {}, {"bytes_limit": 0}])
+def test_device_manager_missing_limit_is_an_error_off_cpu(monkeypatch, stats):
+    """A 16 GiB guess is for the CPU platform only: an accelerator that
+    reports no bytes_limit must not be budgeted by assumption."""
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.runtime import memory
+    monkeypatch.setattr(memory.jax, "devices",
+                        lambda: [_FakeDevice("tpu", stats)])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        memory.DeviceManager(C.RapidsConf())
+    # an explicit budget needs no report from the device
+    dm = memory.DeviceManager(C.RapidsConf(
+        {"spark.rapids.tpu.memory.hbm.limitBytes": 1 << 30}))
+    assert dm.catalog.device_budget == 1 << 30
+
+
+def test_device_manager_cpu_assumes_16gib_and_follows_ordinal():
+    import jax
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.runtime import memory
+    frac = C.RapidsConf().get(C.DEVICE_MEMORY_FRACTION)
+    dm = memory.DeviceManager(C.RapidsConf())
+    assert dm.device == jax.devices()[0]
+    assert dm.catalog.device_budget == int((16 << 30) * frac)
+    try:
+        dm = memory.DeviceManager(C.RapidsConf(
+            {"spark.rapids.tpu.device.ordinal": 3}))
+        assert dm.device == jax.devices()[3]
+        # uploads follow the chosen device
+        assert jax.numpy.ones((8,)).devices() == {jax.devices()[3]}
+    finally:
+        jax.config.update("jax_default_device", None)

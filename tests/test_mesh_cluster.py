@@ -8,7 +8,7 @@ over the TCP transport. Robustness is the contract under test:
 
 - combined-plane results are BIT-IDENTICAL to the TCP-only plane (and to
   a single-process run for the q18 ladder query);
-- a mesh participant killed or wedged inside the collective is surfaced
+- a mesh participant killed or hung inside the collective is surfaced
   by the PR-5 heartbeat/deadline machinery and the task transparently
   re-plans onto the per-split TCP path under a bumped epoch — degraded
   mode, counted in meshDegradedFallbacks, never a hang and never a
@@ -157,7 +157,7 @@ def test_mesh_failure_degrades_without_executor_loss(df, tcp_table):
 
 
 def test_mesh_hang_surfaced_by_deadline_not_a_hang(df, tcp_table):
-    """A task wedged INSIDE the mesh collective (mesh_hang site) is
+    """A task hung INSIDE the mesh collective (mesh_hang site) is
     detected by the PR-5 task-deadline machinery — the executor is killed
     and replaced, the lanes degrade to TCP, and the query completes
     bit-identically instead of hanging."""

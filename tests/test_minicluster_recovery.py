@@ -164,9 +164,9 @@ def test_task_attempts_exhaust_to_query_failure(df):
 
 
 @pytest.mark.slow
-def test_task_timeout_kills_wedge_and_retries(df, clean_table):
+def test_task_timeout_kills_hang_and_retries(df, clean_table):
     """A hung task past cluster.task.timeoutSeconds: the driver kills the
-    wedged executor, charges a timeout attempt, and retries elsewhere.
+    hung executor, charges a timeout attempt, and retries elsewhere.
     Warm-up query first — a COLD first task's XLA compile would trip any
     honest deadline (the @SKIP arms the hang for query 2)."""
     got, delta, stats = _run_chaos(
@@ -182,7 +182,7 @@ def test_task_timeout_kills_wedge_and_retries(df, clean_table):
 
 @pytest.mark.slow
 def test_speculation_dedup_bit_identical(df, clean_table):
-    """A wedged straggler with speculation on: the duplicate wins the race,
+    """A hung straggler with speculation on: the duplicate wins the race,
     the loser's map output is discarded (dedup keyed by (shuffle, split)),
     and the result is still the clean run's exact bytes — no duplicated or
     lost blocks."""
@@ -237,7 +237,7 @@ def test_shutdown_reaps_all_executor_processes(df):
     c = MiniCluster(n_executors=N_EXEC, platform="cpu")
     try:
         c.collect(df)
-        c._procs[2].kill()      # an already-dead slot must not wedge reaping
+        c._procs[2].kill()      # an already-dead slot must not hang reaping
     finally:
         c.shutdown()
     assert all(p is not None and not p.is_alive() for p in c._procs)

@@ -280,19 +280,23 @@ def test_hash_join_build_refuses_bucket_overflow():
     assert not bool(ok)
 
 
-def test_probe_latch_smoke():
-    """The per-kernel compile probes the next chip window will take: every
-    kernel's tiny instance must run clean in interpret mode so a Mosaic
-    failure (not a code bug) is the only thing that can latch it off."""
+def test_switch_table_dispatch(monkeypatch):
+    """should_use() is "TPU backend and the table says on": nothing probes
+    and nothing latches. Off the TPU no kernel is routed; on it exactly the
+    kernels whose table entry is None; set_mode() overrides both ways."""
     import spark_rapids_tpu.ops.pallas_kernels as mod
-    saved = mod._TPU_PROBE
-    mod._TPU_PROBE = None
+    assert not any(mod.should_use(k) for k in mod.KERNELS)
+    monkeypatch.setattr(mod.jax, "default_backend", lambda: "tpu")
+    for kernel, why_off in mod.KERNELS.items():
+        assert mod.should_use(kernel) is (why_off is None), kernel
+        assert why_off is None or len(why_off) > 20   # the compiler's words
+    mod.set_mode(False)
     try:
-        for kernel in ("murmur3", "bitunpack", "onehot", "radix",
-                       "hashjoin"):
-            assert mod._probe_tpu(kernel) is True, kernel
+        assert not any(mod.should_use(k) for k in mod.KERNELS)
+        mod.set_mode(True)
+        assert all(mod.should_use(k) for k in mod.KERNELS)
     finally:
-        mod._TPU_PROBE = saved
+        mod.set_mode(None)
 
 
 def test_join_core_pallas_hash_equivalence():

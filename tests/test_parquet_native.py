@@ -156,10 +156,7 @@ def test_native_scanner_matches_python_parser(tmp_path, monkeypatch):
     produce identical ChunkPages structures — same pages, def levels, run
     segmentation, and dictionary."""
     from spark_rapids_tpu import native as N
-    try:
-        N.parquet_lib()  # the comparison is vacuous without the C library
-    except N.NativeBuildError:
-        pytest.skip("no native toolchain")
+    N.parquet_lib()  # a missing toolchain is an error on this installation
     t = mixed_table(3000, seed=7)
     f = str(tmp_path / "m.parquet")
     pq.write_table(t, f, compression="NONE", use_dictionary=True,
@@ -179,9 +176,16 @@ def test_native_scanner_matches_python_parser(tmp_path, monkeypatch):
     native = parse_all()
 
     def boom(*a, **k):
-        raise N.NativeBuildError("forced python fallback")
+        raise NotImplementedError("forced python parser")
     monkeypatch.setattr(N, "scan_chunk_native", boom)
     python = parse_all()
+
+    # a scanner that cannot be built is an error, not "parse in Python"
+    def no_toolchain(*a, **k):
+        raise N.NativeBuildError("no toolchain")
+    monkeypatch.setattr(N, "scan_chunk_native", no_toolchain)
+    with pytest.raises(N.NativeBuildError):
+        parse_all()
 
     assert len(native) == len(python)
     for cn, cp in zip(native, python):
@@ -220,3 +224,38 @@ def test_v2_data_pages_device_path(tmp_path, codec):
     got = pa.concat_tables(outs)
     for name in t.column_names:
         assert got.column(name).to_pylist() == t.column(name).to_pylist(), name
+
+
+def test_multi_page_chunks_fold_into_one_page(tmp_path):
+    """A chunk cut into many all-packed pages of one bit width folds into
+    ONE page (so it rides the single fused decode program, compiled once),
+    and decodes to the same rows; a page whose present count is not a whole
+    number of 8-value groups keeps the chunk on the per-page path."""
+    n = 40000
+    r = np.random.default_rng(3)
+    t = pa.table({
+        "q": pa.array(r.integers(1, 51, n).astype(np.float64)),
+        "s": pa.array(np.array([f"cat{i}" for i in range(40)])[
+            r.integers(0, 40, n)]),
+        "nul": pa.array([None if v % 13 == 0 else int(v)
+                         for v in r.integers(0, 300, n)], pa.int32()),
+    })
+    f = str(tmp_path / "pages.parquet")
+    pq.write_table(t, f, compression="NONE", use_dictionary=True,
+                   data_page_size=2048)
+    md = pq.ParquetFile(f).metadata
+    folded = {}
+    for c, name in enumerate(t.column_names):
+        pages = PN.read_chunk_pages(f, 0, c, md=md)
+        assert len(pages.index_segments) > 4, name
+        merged = PN._merge_packed_pages(pages)
+        folded[name] = len(merged.index_segments) == 1
+        if folded[name]:
+            nv, dl, _bw, _packed, _off, segs = merged.index_segments[0]
+            assert nv == n and len(dl) == n and segs[0].count == int(dl.sum())
+    assert folded["q"] and folded["s"]
+    assert not folded["nul"]        # present counts not multiples of 8
+    schema = T.StructType.from_arrow(t.schema)
+    out = PN.read_row_group_device(f, 0, schema).to_arrow()
+    for name in t.column_names:
+        assert out.column(name).to_pylist() == t.column(name).to_pylist(), name

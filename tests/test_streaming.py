@@ -595,7 +595,7 @@ def test_exec_kill_mid_commit_replays_bit_identical(tmp_path):
 @pytest.mark.slow
 def test_sigkill_between_begin_and_commit_replays(tmp_path):
     """The other crash point: the coordinator process dies AFTER journaling
-    epoch.begin but BEFORE the state snapshot exists at all (wedged at the
+    epoch.begin but BEFORE the state snapshot exists at all (hung at the
     streaming.state site, then SIGKILLed). Recovery replays from the
     begin record's pinned batch ids, bit-identical with the oracle."""
     res_before = M.resilience_snapshot()["streamEpochReplays"]
@@ -610,7 +610,7 @@ def test_sigkill_between_begin_and_commit_replays(tmp_path):
         assert _wait(lambda: (child.poll() is None
                               and (p := journal.pending()) is not None
                               and p["epoch"] == 2), timeout_s=300)
-        time.sleep(0.3)     # let the child reach the wedge point
+        time.sleep(0.3)     # let the child reach the hang point
         os.kill(child.pid, signal.SIGKILL)
         child.communicate(timeout=60)
         assert child.returncode == -signal.SIGKILL

@@ -1,0 +1,231 @@
+"""What the chip's compiler says, asked without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is described,
+not attached. Each case lowers one Pallas kernel, or one jitted XLA program
+that only exists on a TPU, at the shapes the TPC-H SF 1 main path uses
+(1 Mi-row scan batches, join tables from `hash_join_buckets`) and compiles it
+for one v5e chip. Nothing runs: a pass says the compiler accepts the program
+and that it fits the device, not that its results are right (the interpret
+mode tests in test_pallas.py cover the arithmetic, chip_smoke.py the chip).
+
+The topology is described inside the module-scoped fixture below and nowhere
+else: only one process may hold the TPU library, so this must not happen
+while any module is imported, and every compile runs in this process.
+
+Left out to keep the file cheap, compiled by hand for this chip instead: a
+packed-int64 `lax.sort` at 1 Mi rows (37 s here; the join build and sort
+spine), and the whole fused stage programs of q3/q5/q18, which chip_smoke.py
+compiles on the chip itself.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import spark_rapids_tpu  # noqa: F401  (x64)
+from spark_rapids_tpu import types as T
+from spark_rapids_tpu.expr.core import Col
+from spark_rapids_tpu.ops import pallas_kernels as PK
+
+N = 1 << 20                 # io/filescan.py batch_rows
+HBM_BYTES = 16 * 10**9      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Steer the backend questions the code asks (`jax.default_backend()` is
+    'cpu' here) to their TPU answers, in the test and not in the program."""
+    from spark_rapids_tpu.runtime import hw
+    monkeypatch.setattr(PK, "_interpret", lambda: False)
+    monkeypatch.setattr(hw, "scatters_cheap", lambda: False)
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _fits(compiled) -> bool:
+    ma = compiled.memory_analysis()
+    return (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes) < HBM_BYTES
+
+
+def _kernel_cases():
+    """(kernel, fn, [(shape, dtype)...]) at SF 1 shapes, one per table row."""
+    buckets = PK.hash_join_buckets(8192)       # 4096: the VMEM table cap
+
+    def hashjoin(keys, elig, stream):
+        tk, tr, ok = PK.hash_join_build(keys, elig, buckets)
+        return PK.hash_join_probe(tk, tr, stream, buckets), ok
+
+    return {
+        # dictionary index widths of the SF 1 lineitem columns
+        "bitunpack": [
+            (lambda w, bw=bw: PK.bitunpack128(w, bw, N, N),
+             [((N // 128 * 4 * bw,), jnp.int32)]) for bw in (1, 6, 12, 20)],
+        # exchange partition step (<= 200 partitions) and the hash-table
+        # build's bucket ranks (4096 lanes)
+        "radix": [
+            (lambda ids: PK.radix_partition_permutation(ids, 200),
+             [((N,), jnp.int32)]),
+            (lambda ids: PK.radix_ranks(ids, buckets), [((N,), jnp.int32)])],
+        "hashjoin": [
+            (hashjoin, [((8192,), jnp.int64), ((8192,), jnp.bool_),
+                        ((N,), jnp.int64)])],
+        "onehot": [
+            (lambda v, c: PK.onehot_sum_f32(v, c, 1000),
+             [((N,), jnp.float32), ((N,), jnp.int32)])],
+        "murmur3": [
+            (lambda w, l, s: PK.murmur3_words(w, l, s),
+             [((N, 4), jnp.int32), ((N,), jnp.int32), ((N,), jnp.int32)])],
+    }
+
+
+def _kernel_params():
+    out = []
+    for name, why_off in PK.KERNELS.items():
+        # off for what a chip run showed ("chip: ..."): it still compiles
+        refused = why_off and not why_off.startswith("chip:")
+        marks = ([pytest.mark.xfail(strict=True, reason=why_off)]
+                 if refused else [])
+        out.append(pytest.param(name, id=name, marks=marks))
+    return out
+
+
+@pytest.mark.parametrize("kernel", _kernel_params())
+def test_pallas_kernel_compiles_for_v5e(kernel, one_chip, as_on_tpu):
+    """Every kernel in the switch table: on = Mosaic accepts it at SF 1
+    shapes and the kernel is in the program; off by the compiler = strict
+    xfail quoting it (a later PR that repairs it must flip the table); off
+    for what the chip showed = it must still compile."""
+    for fn, shapes in _kernel_cases()[kernel]:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        compiled = _compile(fn, *args)
+        assert "tpu_custom_call" in compiled.as_text()
+        assert _fits(compiled)
+
+
+def test_switch_table_names_every_routed_kernel():
+    """should_use() is the table: an unknown name is an error, and off the
+    TPU backend nothing is routed unless a test forces it."""
+    assert set(PK.KERNELS) == {"bitunpack", "radix", "hashjoin", "onehot",
+                               "murmur3"}
+    with pytest.raises(KeyError):
+        PK.should_use("no_such_kernel")
+    assert not any(PK.should_use(k) for k in PK.KERNELS)   # cpu backend
+
+
+def test_dense_f64_group_sum_compiles(one_chip, as_on_tpu):
+    """q1's small-domain group-by on a TPU (`use_matmul=True`, the
+    scatter-free form): D masked f64 reductions in one pass."""
+    from spark_rapids_tpu.ops.grouping import dense_group_sum
+    compiled = _compile(
+        lambda v, m, c: dense_group_sum(v, m, c, 16, True),
+        jax.ShapeDtypeStruct((N,), jnp.float64, sharding=one_chip),
+        jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip))
+    assert _fits(compiled)
+
+
+def test_gather_form_compaction_compiles(one_chip, as_on_tpu):
+    """Filter compaction on a TPU: cumsum + searchsorted + gathers."""
+    from spark_rapids_tpu.ops.filtering import compact_cols
+
+    def fn(k, kv, x, xv, keep):
+        cols, n = compact_cols([Col(k, kv, T.LONG), Col(x, xv, T.DOUBLE)],
+                               keep)
+        return [c.values for c in cols], [c.validity for c in cols], n
+
+    b = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=one_chip)
+    compiled = _compile(
+        fn, jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip), b,
+        jax.ShapeDtypeStruct((N,), jnp.float64, sharding=one_chip), b, b)
+    assert _fits(compiled)
+
+
+def test_parquet_dictionary_decode_compiles(one_chip, as_on_tpu):
+    """One encoded lineitem page → rows: bit-unpack (the Pallas kernel when
+    the table routes it, else the jnp form), dictionary gather,
+    definition-level spread (ops/parquet_decode.decode_page_cols)."""
+    from spark_rapids_tpu.ops import parquet_decode as PD
+    bw = 6
+    pallas = PK.KERNELS["bitunpack"] is None
+    packed = (jax.ShapeDtypeStruct((N // 128 * 4 * bw,), jnp.int32,
+                                   sharding=one_chip) if pallas else
+              jax.ShapeDtypeStruct((N * bw // 8,), jnp.uint8,
+                                   sharding=one_chip))
+    spec = PD.EncodedPageSpec(bw, N, 0 if pallas else N * bw // 8, N,
+                              "float64", False, 0.0, pallas, N)
+    s32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = _compile(
+        lambda w, d, dl, npres, n: PD.decode_page_cols(spec, w, d, dl,
+                                                       npres, n),
+        packed,
+        jax.ShapeDtypeStruct((50,), jnp.float64, sharding=one_chip),
+        jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=one_chip), s32, s32)
+    assert ("tpu_custom_call" in compiled.as_text()) == pallas
+    assert _fits(compiled)
+
+
+def test_mesh_all_to_all_exchange_compiles_for_four_chips(topo, as_on_tpu):
+    """The mesh data plane's exchange step (distributed/exchange.row_exchange
+    under shard_map) on a 4-device mesh built from the described chips: the
+    compiler puts an all-to-all in, and each device's share fits."""
+    from spark_rapids_tpu.distributed.exchange import row_exchange
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    cap = 1 << 18
+
+    def shard_step(k, kv, x, xv, nrows):
+        cols = [Col(k[0], kv[0], T.LONG), Col(x[0], xv[0], T.DOUBLE)]
+        pids = (k[0] & jnp.int64(3)).astype(jnp.int32)
+        merged, m_rows = row_exchange(cols, nrows[0], pids, 4, cap)
+        return (tuple(c.values[None] for c in merged)
+                + tuple(c.validity[None] for c in merged) + (m_rows[None],))
+
+    spec = P("data", None)
+    step = jax.shard_map(shard_step, mesh=mesh,
+                         in_specs=(spec,) * 4 + (P("data"),),
+                         out_specs=(spec,) * 4 + (P("data"),))
+    sh = NamedSharding(mesh, spec)
+
+    def arg(dt):
+        return jax.ShapeDtypeStruct((4, cap), dt, sharding=sh)
+
+    compiled = _compile(
+        step, arg(jnp.int64), arg(jnp.bool_), arg(jnp.float64),
+        arg(jnp.bool_),
+        jax.ShapeDtypeStruct((4,), jnp.int32,
+                             sharding=NamedSharding(mesh, P("data"))))
+    assert "all-to-all" in compiled.as_text()
+    assert _fits(compiled)

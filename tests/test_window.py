@@ -343,3 +343,42 @@ def test_lead_string_default_falls_back():
     assert "non-null default" in txt
     out = execute_hybrid(TpuOverrides(RapidsConf()).apply(node))  # host path
     assert out.num_rows == 30
+
+
+@pytest.mark.parametrize("n", [1000, 4096, 5000, 1 << 14])
+@pytest.mark.parametrize("dtype", ["int32", "int64", "bool", "float64"])
+def test_two_level_scans_match_the_native_ones(n, dtype):
+    """ops/windowing.cumsum/cummax/cummin_reverse take a two-level blocked
+    form on long arrays (the chip's compiler needs it); the result is the
+    native scan's, in dtype and in every element."""
+    import jax
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import windowing as W
+    rng = np.random.default_rng(n)
+    x = {"bool": lambda: rng.random(n) < 0.3,
+         "float64": lambda: rng.uniform(-5, 5, n)}.get(
+        dtype, lambda: rng.integers(-50, 1000, n).astype(dtype))()
+    d = jnp.asarray(x)
+    got, want = W.cumsum(d), jnp.cumsum(d, axis=0)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    if dtype != "bool":
+        assert np.array_equal(W.cummax(d), jax.lax.cummax(d))
+        assert np.array_equal(W.cummin_reverse(d),
+                              jax.lax.cummin(d, reverse=True))
+
+
+@pytest.mark.parametrize("cap", [256, 5000, 1 << 14])
+def test_range_sum_tree_matches_segment_totals(cap):
+    """grouping._seg_sum_tree walks the long and the short levels as two
+    loops; every row gets its own segment's total."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import grouping as G
+    rng = np.random.default_rng(cap)
+    data = rng.uniform(0, 1e5, cap)
+    boundary = rng.random(cap) < 0.01
+    boundary[0] = True
+    seg_ids = np.cumsum(boundary).astype(np.int32) - 1
+    ctx = G.segment_structure(jnp.asarray(seg_ids), cap)
+    got = np.asarray(G._seg_sum_tree(jnp.asarray(data), ctx))
+    want = np.bincount(seg_ids, weights=data)[seg_ids]
+    np.testing.assert_allclose(got, want, rtol=1e-12)

@@ -12,7 +12,7 @@ real replica PROCESSES (tools/fleet_replica.py) over shared on-disk state:
     get bit-identical results with every query-scoped resilience counter
     zero AND every process-wide resilience counter zero on both replicas —
     replication with no faults is invisible to every recovery ladder.
-  - **Mid-stream SIGKILL failover**: a victim replica (wedged by an armed
+  - **Mid-stream SIGKILL failover**: a victim replica (hung by an armed
     hang fault at its first result-frame send, so the kill
     deterministically lands mid-stream) is SIGKILLed while serving; the
     client's ``submit_with_retry`` sees a retryable TransportError,
@@ -30,7 +30,7 @@ real replica PROCESSES (tools/fleet_replica.py) over shared on-disk state:
     each replica's raw Prometheus text — the rollup invents and loses
     nothing.
   - **Black-box flight recorder**: the victim gets a request timeout, so
-    its heartbeat watchdog detects the wedged query and dumps
+    its heartbeat watchdog detects the hung query and dumps
     ``blackbox-<pid>.json`` BEFORE the SIGKILL lands; the dump names the
     in-flight query (journey id + SQL), and the survivor's ``fleet.adopt``
     event carries the dump's path.
@@ -247,12 +247,12 @@ def main(argv=None) -> int:
     report["fleet_counter_series"] = len(resum)
 
     # -- phase 3: SIGKILL a victim mid-stream; client fails over --------------
-    # the victim's armed hang fault wedges q5 forever at its first result
+    # the victim's armed hang fault hangs q5 forever at its first result
     # frame (endpoint.send is a maybe_inject_any site, so "hang" fires
     # there), so the kill deterministically lands while the client is
     # mid-stream (a timed slow fault loses the race when the shared stage
     # cache makes the query finish in under the kill delay). The victim
-    # also gets a request timeout: its connection thread is the wedged one,
+    # also gets a request timeout: its connection thread is the hung one,
     # so the HEARTBEAT watchdog must detect the stuck query, close its
     # journey (replica_timeout) and dump the flight recorder — all before
     # the SIGKILL, which is exactly the post-mortem the dump exists for.
@@ -274,7 +274,7 @@ def main(argv=None) -> int:
 
     ft = threading.Thread(target=failover_client, daemon=True)
     ft.start()
-    # long enough for the query to wedge, age past the 1s request timeout,
+    # long enough for the query to hang, age past the 1s request timeout,
     # and a heartbeat (1s) to run the watchdog sweep + blackbox dump
     time.sleep(4.0)
     os.kill(proc_v.pid, signal.SIGKILL)
@@ -330,7 +330,7 @@ def main(argv=None) -> int:
         named = [i for i in bb.get("inflight", [])
                  if i.get("journey") == jny]
         check(named, f"blackbox in-flight registry does not name the "
-                     f"wedged journey {jny}: {bb.get('inflight')}")
+                     f"hung journey {jny}: {bb.get('inflight')}")
         check(named and named[0].get("sql"),
               "blackbox in-flight entry carries no SQL")
         check(bb.get("events"), "blackbox event ring is empty")
