@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.vector import TpuColumnVector, bucket_capacity
+from spark_rapids_tpu.runtime import tracing
 
 
 class ColumnarBatch:
@@ -35,7 +36,11 @@ class ColumnarBatch:
     def num_rows(self) -> int:
         """Host row count; forces a device sync if the count is still a device scalar."""
         if not isinstance(self._num_rows, int):
-            self._num_rows = int(self._num_rows)
+            with tracing.span("sync.count") as sp:
+                self._num_rows = int(self._num_rows)
+                if sp and self.columns:
+                    sp.set(rows=self._num_rows,
+                           capacity=self.columns[0].capacity)
         return self._num_rows
 
     @property

@@ -71,6 +71,7 @@ class EncodedCol:
         return Col(v, m, self.dtype, self.dictionary)
 
 
+@jax.named_scope("ParquetScan.decode")
 def densify_cols(cols):
     """Traceable prologue for fused kernels that accept mixed dense/encoded
     inputs: expand every EncodedCol to a dense expr Col in-trace (the page

@@ -274,8 +274,14 @@ METRICS_LEVEL = conf("spark.rapids.tpu.sql.metrics.level").doc(
     "RapidsConf.scala:465)").string_conf("MODERATE")
 
 TRACE_ENABLED = conf("spark.rapids.tpu.sql.trace.enabled").doc(
-    "Wrap hot regions in jax.profiler trace annotations (reference NVTX ranges, "
-    "NvtxWithMetrics.scala)").boolean_conf(False)
+    "Record spans (runtime/tracing.py; reference NVTX ranges, "
+    "NvtxWithMetrics.scala): every trace_range/span region opens a "
+    "jax.profiler trace annotation of its name, so a profiler capture shows "
+    "it on the device trace's clock, and appends one record (name, id, "
+    "parent, trace id, thread, perf_counter_ns start and end, counts) to a "
+    "bounded in-process buffer read afterwards with tracing.recorded() / "
+    "drain() / summarize(); docs/observability.md lists the spans. Off, a "
+    "span site costs one check").boolean_conf(False)
 
 CPU_FALLBACK_ENABLED = conf("spark.rapids.tpu.sql.cpuFallback.enabled").doc(
     "Allow untagged operators to run via the host (pyarrow) fallback engine rather "
@@ -774,7 +780,8 @@ STATS_HISTORY_ENABLED = conf("spark.rapids.tpu.stats.history.enabled").doc(
 TRACE_DIR = conf("spark.rapids.tpu.trace.dir").doc(
     "Directory for per-process JSONL span files (runtime/tracing.py): every "
     "trace_range/span region and span_event instant is appended with its "
-    "wall-clock start, duration, pid/thread and the ambient query's trace "
+    "wall-clock start, duration, pid/thread, span id and parent, and the "
+    "ambient query's trace "
     "id, which propagates across MiniCluster tasks, shuffle fetches and "
     "endpoint submissions. tools/profiler.py trace merges the files into "
     "Chrome-trace JSON (Perfetto) with a critical-path table. Empty "

@@ -13,6 +13,7 @@ a device scalar until a downstream sync."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
@@ -25,6 +26,7 @@ from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.filtering import compact_cols, gather_cols
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import retry as R
+from spark_rapids_tpu.runtime import tracing
 from spark_rapids_tpu.runtime.tracing import trace_range
 
 PARTIAL = "partial"
@@ -276,7 +278,8 @@ class HashAggregateExec(TpuExec):
                 upd_cols, upd_n = self._agg_kernel(uctx, merge=False)
                 counts_v = jnp.stack([acc_n, upd_n.astype(jnp.int32)])
                 per_col = [[a, u] for a, u in zip(a_cols, upd_cols)]
-                cat = concat_cols(per_col, counts_v, Cc, (acc_cap, bcap))
+                with jax.named_scope("concat"):
+                    cat = concat_cols(per_col, counts_v, Cc, (acc_cap, bcap))
                 mctx = EvalContext(cat, acc_n + upd_n, Cc)
                 mg_cols, mg_n = self._agg_kernel(mctx, merge=True)
                 status = jnp.stack([jnp.asarray(mg_n, jnp.int32),
@@ -292,8 +295,10 @@ class HashAggregateExec(TpuExec):
         if out is None:
             return None   # uncacheable key or trace fallback → go unchained
         mg_cols, status = out
-        st = np.asarray(status)   # the ONE host sync of the chained step
-        mg_n, upd_n = int(st[0]), int(st[1])
+        with tracing.span("sync.status") as sp:
+            st = np.asarray(status)   # the ONE host sync of the chained step
+            mg_n, upd_n = int(st[0]), int(st[1])
+            sp.set(rows=mg_n, capacity=Cc)
         # accept only when the concat ran at the bucket the unchained loop's
         # concat_batches would have picked (bucket of the TRUE total): the
         # merge's f64 reduction order is capacity-sensitive, so an equal
@@ -389,14 +394,28 @@ class HashAggregateExec(TpuExec):
             from spark_rapids_tpu.ops.filtering import selection_mask
             return selection_mask(self.prefilter.eval(c), c.num_rows, cap)
 
+        # the operators the planner fused into this program keep their own
+        # names in its op metadata (srt_HashAggregateExec/FilterExec/...)
         if not merge:
             if self.prefilter is not None and not self.prefilter_on_projected:
-                keep = eval_keep(ctx)
+                with jax.named_scope("FilterExec"):
+                    keep = eval_keep(ctx)
             if self.preproject is not None:
-                cols = [e.eval(ctx) for e in self.preproject]
+                with jax.named_scope("ProjectExec"):
+                    cols = [e.eval(ctx) for e in self.preproject]
                 ctx = EvalContext(cols, ctx.num_rows, cap)
             if self.prefilter is not None and self.prefilter_on_projected:
-                keep = eval_keep(ctx)
+                with jax.named_scope("FilterExec"):
+                    keep = eval_keep(ctx)
+        with jax.named_scope("HashAggregate.merge" if merge
+                             else "HashAggregate.update"):
+            return self._agg_groups(ctx, keep, merge, range_hint, presorted)
+
+    def _agg_groups(self, ctx: EvalContext, keep, merge: bool, range_hint,
+                    presorted: bool):
+        """_agg_kernel after its fused-in filter and projection: group the
+        rows (dense codes, or sort + segments) and reduce each aggregate."""
+        cap = ctx.capacity
         nkeys = len(self.group_exprs)
         if nkeys:
             if merge:

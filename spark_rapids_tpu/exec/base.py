@@ -18,7 +18,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.config import RapidsConf
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime.semaphore import TpuSemaphore
-from spark_rapids_tpu.runtime.tracing import trace_range
+from spark_rapids_tpu.runtime import tracing
 
 _task_counter = itertools.count(1)
 _task_local = threading.local()
@@ -96,12 +96,14 @@ class TpuExec:
         from spark_rapids_tpu.runtime import pipeline as P
         nthreads = max(1, min(self.conf.get(NUM_LOCAL_TASKS), self.num_partitions))
         collector = M.current_collector()
+        parent_span = tracing.current_span()
         pipe_on = P.enabled(self.conf)
 
         def run(split):
             # re-enter the driving action's query scope on the pool thread so
             # metrics/events fired by operators attribute to this query
-            with M.collector_context(collector), TaskContext():
+            with M.collector_context(collector), TaskContext(), \
+                    tracing.child_of(parent_span):
                 it = self.execute_partition(split)
                 if pipe_on:
                     # final-collect pipeline segment: upstream compute runs

@@ -71,8 +71,8 @@ class ProjectExec(TpuExec):
                             key, "ProjectExec", build, (in_cols, nr),
                             lambda: [e.eval(ctx) for e in exprs])
                     cols = [c.to_vector() for c in out]
-                    yield ColumnarBatch(cols, batch.lazy_num_rows, self.output,
-                                        metadata=batch.metadata)
+                yield ColumnarBatch(cols, batch.lazy_num_rows, self.output,
+                                    metadata=batch.metadata)
                 if positional:  # host sync only when an expr needs positions
                     offset += int(batch.num_rows)
         return self.wrap_output(it())
@@ -138,8 +138,8 @@ class FilterExec(TpuExec):
                             resized = maybe_host_resize(new_cols, count)
                             if resized is not None:
                                 new_cols, count = resized
-                    yield ColumnarBatch([c.to_vector() for c in new_cols], count,
-                                        self.output, metadata=batch.metadata)
+                yield ColumnarBatch([c.to_vector() for c in new_cols], count,
+                                    self.output, metadata=batch.metadata)
         return self.wrap_output(it())
 
     def args_string(self):

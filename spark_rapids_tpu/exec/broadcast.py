@@ -24,6 +24,7 @@ from spark_rapids_tpu.runtime import faults as F
 from spark_rapids_tpu.runtime import memory as mem
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import retry as R
+from spark_rapids_tpu.runtime import tracing
 from spark_rapids_tpu.runtime.tracing import trace_range
 
 class BroadcastTimeout(RuntimeError):
@@ -106,12 +107,15 @@ class BroadcastExchangeExec(TpuExec):
                 # future, so the build is otherwise invisible to the
                 # per-thread attribution frames
                 collector = M.current_collector()
+                parent_span = tracing.current_span()
 
                 def build():
                     with M.collector_context(collector), \
                             M.node_frame(self._node_id,
                                          self.metrics.metric(
-                                             M.BUILD_SELF_TIME, M.ESSENTIAL)):
+                                             M.BUILD_SELF_TIME,
+                                             M.ESSENTIAL)), \
+                            tracing.child_of(parent_span):
                         return self._materialize()
 
                 self._future = _spawn_build(build)
@@ -124,7 +128,8 @@ class BroadcastExchangeExec(TpuExec):
         # consumer's blocked wait must not double-count in its own frame.
         # The wait polls so a cancelled/deadlined query drains instead of
         # camping on a peer-started build for broadcastTimeout seconds
-        with M.node_frame(self._node_id, None):
+        with M.node_frame(self._node_id, None), \
+                tracing.span("broadcast.wait"):
             while True:
                 check_cancel()
                 try:

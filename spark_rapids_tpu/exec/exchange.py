@@ -60,6 +60,7 @@ class ShuffleExchangeExec(TpuExec):
         sid = store.register_shuffle(serialized=serialized)
         self._pending_shuffle_id = sid
         collector = M.current_collector()
+        parent_span = tracing.current_span()
         EL.emit("stage.map.start", node=self._node_id,
                 shuffle=sid,
                 map_partitions=self.child.num_partitions,
@@ -87,7 +88,7 @@ class ShuffleExchangeExec(TpuExec):
             # this node's selfTime (child operator frames subtract their own)
             with M.collector_context(collector), \
                     M.node_frame(self._node_id, self._self_time), \
-                    TaskContext():
+                    TaskContext(), tracing.child_of(parent_span):
                 child_it = self.child.execute_partition(split)
                 if pipe_on:
                     # map-segment boundary: upstream compute produces on the

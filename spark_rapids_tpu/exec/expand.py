@@ -41,7 +41,8 @@ class ExpandExec(TpuExec):
             for batch in self.child.execute_partition(split):
                 acquire_semaphore(self.metrics)
                 with trace_range("ExpandExec", self._op_time):
-                    yield self._expand(batch, k)
+                    out = self._expand(batch, k)
+                yield out
         return self.wrap_output(it())
 
     def _expand(self, batch: ColumnarBatch, k: int) -> ColumnarBatch:

@@ -39,6 +39,7 @@ from spark_rapids_tpu.runtime import faults as F
 from spark_rapids_tpu.runtime import memory as mem
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import retry as R
+from spark_rapids_tpu.runtime import tracing
 from spark_rapids_tpu.runtime.tracing import trace_range
 
 # max pairs expanded per output chunk (the JoinGatherer row-target analog)
@@ -66,7 +67,9 @@ def _emit_pairs(join_type, stream_is_left, condition, preproject,
     """Pair-expansion emit shared by HashJoinExec and the join-chain fallback:
     expand in chunks (one fused program per chunk capacity), yield batches."""
     from spark_rapids_tpu.runtime import fuse
-    total = int(total)
+    with tracing.span("sync.count") as sp:
+        total = int(total)
+        sp.set(pairs=total)
     semi_anti = join_type in (J.LEFT_SEMI, J.LEFT_ANTI)
     cond = condition
     cond_key = fuse.expr_key(cond) if cond is not None else None
@@ -81,18 +84,20 @@ def _emit_pairs(join_type, stream_is_left, condition, preproject,
                 build_perm, lo, hi, counts, start, _cap)
             s_cols = gather_cols(s_in, s_idx, live)
             if preproject is not None:
-                pctx = EvalContext(s_cols, n_out, _cap)
-                s_cols = [e.eval(pctx) for e in preproject]
+                with jax.named_scope("ProjectExec"):
+                    pctx = EvalContext(s_cols, n_out, _cap)
+                    s_cols = [e.eval(pctx) for e in preproject]
             if semi_anti:
                 cols = s_cols
             else:
                 b_cols = _null_extended(b_in, b_idx, b_matched)
                 cols = (s_cols + b_cols) if stream_is_left else (b_cols + s_cols)
             if cond is not None:
-                ctx = EvalContext(cols, n_out, _cap)
-                pred = cond.eval(ctx)
-                keep = pred.values & pred.validity & live
-                return compact_cols(cols, keep)
+                with jax.named_scope("condition"):
+                    ctx = EvalContext(cols, n_out, _cap)
+                    pred = cond.eval(ctx)
+                    keep = pred.values & pred.validity & live
+                    return compact_cols(cols, keep)
             return cols, None
 
         key = ("join_emit", semi_anti, stream_is_left, out_cap,
@@ -431,8 +436,15 @@ class _JoinCore:
             lambda: kernel(self.build_keys_raw, n_build, stream_cols,
                            n_stream))
         if track_matched:
-            self.build_matched_acc |= np.asarray(matched)
+            self._sync_matched(matched)
         return build_perm, lo, hi, counts, total
+
+    def _sync_matched(self, matched) -> None:
+        """Fold one stream batch's matched-build-rows mask into the host
+        accumulator: a blocking device-to-host read per batch."""
+        with tracing.span("sync.matched") as sp:
+            self.build_matched_acc |= np.asarray(matched)
+            sp.set(rows=self.n_build, capacity=int(matched.shape[0]))
 
     def _probe_batch_eager(self, stream_batch, jt, track_matched):
         """Context-sensitive stream keys: evaluate with the batch's full
@@ -451,7 +463,7 @@ class _JoinCore:
         total = J.total_pairs(counts)
         if track_matched:
             _, blo, bhi = J.probe(s_ranks, b_ranks)
-            self.build_matched_acc |= np.asarray((bhi - blo) > 0)
+            self._sync_matched((bhi - blo) > 0)
         return build_perm, lo, hi, counts, total
 
     def _probe_batch_fast(self, stream_batch, jt, track_matched):
@@ -574,7 +586,7 @@ class _JoinCore:
             key, "HashJoin.probe", lambda: kernel, args,
             lambda: kernel(*args))
         if track_matched:
-            self.build_matched_acc |= np.asarray(matched)
+            self._sync_matched(matched)
         return self._build_perm, lo, hi, counts, total
 
     # -- whole-stage join-chain surface (BroadcastHashJoinChainExec) ---------
@@ -1101,19 +1113,28 @@ class BroadcastHashJoinChainExec(TpuExec):
                     cap_in = stream_cols[0].values.shape[0]
                     live = jnp.arange(cap_in, dtype=jnp.int32) < n_stream
                     cur = stream_cols
-                    for lk, (cargs, b_cols), spec in zip(lookups, hop_args,
-                                                         specs):
+                    for hop, (lk, (cargs, b_cols), spec) in enumerate(
+                            zip(lookups, hop_args, specs)):
                         sk_expr, prefilter, preproject, sil = spec
                         ctx = EvalContext(cur, n_stream, cap_in)
-                        if prefilter is not None:
-                            p = prefilter.eval(ctx)
-                            live = live & p.values & p.validity
-                        k = sk_expr.eval(ctx)
-                        row, hit = lk(cargs, k)
-                        hit = hit & k.validity & live
-                        bg = gather_cols(b_cols, jnp.where(hit, row, 0), hit)
-                        s_cols = ([e.eval(ctx) for e in preproject]
-                                  if preproject is not None else cur)
+                        # each hop, and the filter and projection fused into
+                        # it, under its own name in the op metadata
+                        with jax.named_scope(f"hop{hop}"):
+                            if prefilter is not None:
+                                with jax.named_scope("FilterExec"):
+                                    p = prefilter.eval(ctx)
+                                    live = live & p.values & p.validity
+                            with jax.named_scope("lookup"):
+                                k = sk_expr.eval(ctx)
+                                row, hit = lk(cargs, k)
+                                hit = hit & k.validity & live
+                            bg = gather_cols(b_cols, jnp.where(hit, row, 0),
+                                             hit)
+                            if preproject is not None:
+                                with jax.named_scope("ProjectExec"):
+                                    s_cols = [e.eval(ctx) for e in preproject]
+                            else:
+                                s_cols = cur
                         cur = (s_cols + bg) if sil else (bg + s_cols)
                         live = hit
                     out, count = compact_cols(cur, live)
@@ -1129,7 +1150,10 @@ class BroadcastHashJoinChainExec(TpuExec):
 
         cap = min(pred_cap[0], scap) if pred_cap[0] is not None else scap
         cols, count = run(cap)
-        count = int(count)   # one host sync per batch (the emit-total analog)
+        with tracing.span("sync.count") as sp:
+            # one host sync per batch (the emit-total analog)
+            count = int(count)
+            sp.set(rows=count, capacity=cap)
         if count == 0:
             pred_cap[0] = bucket_capacity(1)
             return None
@@ -1226,9 +1250,16 @@ class NestedLoopJoinExec(TpuExec):
                                      if self.join_type == J.FULL_OUTER else None)
                 for lb in self.children[0].execute_partition(split):
                     acquire_semaphore(self.metrics)
-                    with trace_range("NestedLoopJoin", self._join_time):
-                        yield from self._join_batch(lb, build, n_build, out_schema,
-                                                    pair_schema, right_matched_acc)
+                    # the range closes before each batch goes downstream: a
+                    # span left open across a yield would adopt the consumer
+                    pairs = self._join_batch(lb, build, n_build, out_schema,
+                                             pair_schema, right_matched_acc)
+                    while True:
+                        with trace_range("NestedLoopJoin", self._join_time):
+                            out = next(pairs, None)
+                        if out is None:
+                            break
+                        yield out
                 if right_matched_acc is not None:
                     self._shared.merge_matched(right_matched_acc)
                 if reader.finish_once():

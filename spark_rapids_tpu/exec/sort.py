@@ -13,6 +13,7 @@ from spark_rapids_tpu.ops.sorting import SortOrder, sort_permutation
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime.tracing import trace_range
 
+import jax
 import jax.numpy as jnp
 
 
@@ -72,7 +73,8 @@ class SortExec(TpuExec):
                     def kernel(cols, num_rows):
                         cap = cols[0].values.shape[0]
                         ctx = EvalContext(cols, num_rows, cap)
-                        key_cols = [e.eval(ctx) for e in exprs]
+                        with jax.named_scope("sort_keys"):
+                            key_cols = [e.eval(ctx) for e in exprs]
                         perm = sort_permutation(key_cols, orders, num_rows, cap)
                         live = jnp.arange(cap, dtype=jnp.int32) < num_rows
                         return gather_cols(ctx.cols, perm, live)

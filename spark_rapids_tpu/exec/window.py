@@ -94,7 +94,8 @@ class WindowExec(TpuExec):
             acquire_semaphore(self.metrics)
             with trace_range("WindowExec", self._win_time):
                 batch = concat_batches(batches)
-                yield self._compute(batch)
+                out = self._compute(batch)
+            yield out
         return self.wrap_output(it())
 
     def _compute(self, batch: ColumnarBatch) -> ColumnarBatch:

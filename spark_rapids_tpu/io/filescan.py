@@ -330,10 +330,15 @@ class FileSourceScanExec(TpuExec):
                 meta = _scan_meta(path)
                 for rg in range(n_groups):
                     acquire_semaphore(self.metrics)
-                    with trace_range("FileScan.devdecode", self._scan_time):
+                    with trace_range("FileScan.devdecode",
+                                     self._scan_time) as sp:
                         batch = PN.read_row_group_device(
                             path, rg, self.output, cols, pf=pf,
                             encoded=encoded)
+                        if sp:   # the row count is the footer's: a host int
+                            sp.set(rows=batch.num_rows,
+                                   capacity=batch.capacity,
+                                   columns=batch.num_cols)
                     batch.metadata = meta
                     yield batch
         return it()

@@ -24,6 +24,8 @@ import typing
 
 import numpy as np
 
+from spark_rapids_tpu.runtime import tracing
+
 
 # -- thrift compact protocol (just enough for PageHeader) --------------------
 
@@ -403,11 +405,13 @@ def _merge_packed_pages(pages: ChunkPages) -> ChunkPages:
 
 
 def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
-                    encoded: bool = False):
+                    encoded: bool = False, span=tracing.NO_SPAN):
     """Decode a parsed chunk into a TpuColumnVector. The common fast path
     (every hybrid segment bit-packed) unpacks indices ON DEVICE; pages with
     mixed RLE runs fall back to the host hybrid decode, keeping the
-    dictionary gather on device either way."""
+    dictionary gather on device either way. ``span`` is the caller's
+    ``scan.column`` span: it learns which of the two paths the chunk took
+    and what the chunk was made of."""
     import jax.numpy as jnp
     from spark_rapids_tpu import types as T
     from spark_rapids_tpu.columnar.vector import TpuColumnVector
@@ -425,6 +429,12 @@ def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
         dict_dev = jnp.asarray(np.asarray(pages.dict_values))
     from spark_rapids_tpu.columnar.vector import bucket_capacity
 
+    if span:
+        segs = [s for p in pages.index_segments for s in p[5]]
+        span.set(pages=len(pages.index_segments),
+                 packed=sum(s.kind == "packed" for s in segs),
+                 rle=sum(s.kind != "packed" for s in segs),
+                 encoded_bytes=sum(len(p[3]) for p in pages.index_segments))
     pages = _merge_packed_pages(pages)
     # fast path: ONE data page, all-packed index segments → a single fused
     # program (unpack + dict gather + null spread + canonicalize). The eager
@@ -436,37 +446,42 @@ def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
         if segs and all(s.kind == "packed" for s in segs):
             packed = b"".join(page_bytes[s.byte_off:s.byte_off + s.byte_len]
                               for s in segs)
+            span.set(path="fused")
             return _decode_single_page_fused(
                 packed, bw, def_levels, dict_dev, num_values, capacity,
                 pages, spark_type, sorted_dict, encoded=encoded)
 
+    # page by page: some twenty-five eager device calls a page
+    span.set(path="pages")
     all_vals, all_valid = [], []
     for (num_values, def_levels, bw, page_bytes, values_off, segs) in \
             pages.index_segments:
-        pcap = bucket_capacity(max(num_values, 1))
-        n_present = int(def_levels.sum())
-        if segs and all(s.kind == "packed" for s in segs):
-            # segments each hold whole 8-value groups at byte boundaries:
-            # concatenating their BYTES preserves bit alignment
-            packed = b"".join(page_bytes[s.byte_off:s.byte_off + s.byte_len]
-                              for s in segs)
-            vals, valid = PD.decode_dictionary_page(
-                np.frombuffer(packed, np.uint8), bw, n_present, def_levels,
-                dict_dev, pcap)
-        else:
-            idx = decode_rle_host(page_bytes, values_off + 1,
-                                  len(page_bytes), bw, n_present) \
-                if segs else np.zeros(0, np.int32)
-            nd = int(dict_dev.shape[0])
-            idx_d = jnp.zeros((pcap,), jnp.int32).at[:len(idx)].set(
-                jnp.asarray(np.clip(idx, 0, max(nd - 1, 0))))
-            # an all-null page may carry an EMPTY dictionary — nothing to
-            # gather, every slot is the canonical default
-            present = dict_dev[idx_d] if nd else jnp.zeros((pcap,),
-                                                           dict_dev.dtype)
-            dl = jnp.zeros((pcap,), jnp.bool_).at[:len(def_levels)].set(
-                jnp.asarray(def_levels.astype(bool)))
-            vals, valid = PD.expand_present_to_rows(present, dl, pcap)
+        with tracing.span("scan.page", values=num_values):
+            pcap = bucket_capacity(max(num_values, 1))
+            n_present = int(def_levels.sum())
+            if segs and all(s.kind == "packed" for s in segs):
+                # segments each hold whole 8-value groups at byte boundaries:
+                # concatenating their BYTES preserves bit alignment
+                packed = b"".join(
+                    page_bytes[s.byte_off:s.byte_off + s.byte_len]
+                    for s in segs)
+                vals, valid = PD.decode_dictionary_page(
+                    np.frombuffer(packed, np.uint8), bw, n_present,
+                    def_levels, dict_dev, pcap)
+            else:
+                idx = decode_rle_host(page_bytes, values_off + 1,
+                                      len(page_bytes), bw, n_present) \
+                    if segs else np.zeros(0, np.int32)
+                nd = int(dict_dev.shape[0])
+                idx_d = jnp.zeros((pcap,), jnp.int32).at[:len(idx)].set(
+                    jnp.asarray(np.clip(idx, 0, max(nd - 1, 0))))
+                # an all-null page may carry an EMPTY dictionary — nothing to
+                # gather, every slot is the canonical default
+                present = dict_dev[idx_d] if nd else jnp.zeros((pcap,),
+                                                               dict_dev.dtype)
+                dl = jnp.zeros((pcap,), jnp.bool_).at[:len(def_levels)].set(
+                    jnp.asarray(def_levels.astype(bool)))
+                vals, valid = PD.expand_present_to_rows(present, dl, pcap)
         all_vals.append(vals[:num_values])
         all_valid.append(valid[:num_values])
 
@@ -612,21 +627,32 @@ def read_row_group_device(path: str, row_group: int, schema,
     cols, fields = [], []
     for name in want:
         sf = schema[name] if schema is not None else None
-        try:
-            if name not in leaf_of:
-                raise NotImplementedError(f"nested column {name}")
-            pages = read_chunk_pages(path, row_group, leaf_of[name], md=md)
-            cv = chunk_to_device(
-                pages, sf.data_type if sf else None, cap, encoded=encoded)
-            if isinstance(cv, EncodedColumnVector):
-                _MV.record_h2d(cv.encoded_payload_bytes(),
-                               site="scan.encoded")
-            else:
-                _MV.record_h2d(cv.device_memory_size(), site="scan.device")
-        except NotImplementedError:
-            arr = pf.read_row_group(row_group, columns=[name]).column(0)
-            cv = array_to_device(arr, sf.data_type if sf else None, cap)
-            _MV.record_h2d(cv.device_memory_size(), site="scan.fallback")
+        # one span a column chunk, with the path it took: fused (one
+        # program), pages (page by page, eager) or fallback (pyarrow)
+        with tracing.span("scan.column", column=name) as sp:
+            try:
+                if name not in leaf_of:
+                    raise NotImplementedError(f"nested column {name}")
+                pages = read_chunk_pages(path, row_group, leaf_of[name],
+                                         md=md)
+                cv = chunk_to_device(pages, sf.data_type if sf else None,
+                                     cap, encoded=encoded, span=sp)
+                if isinstance(cv, EncodedColumnVector):
+                    _MV.record_h2d(cv.encoded_payload_bytes(),
+                                   site="scan.encoded")
+                else:
+                    _MV.record_h2d(cv.device_memory_size(),
+                                   site="scan.device")
+            except NotImplementedError:
+                arr = pf.read_row_group(row_group, columns=[name]).column(0)
+                cv = array_to_device(arr, sf.data_type if sf else None, cap)
+                _MV.record_h2d(cv.device_memory_size(), site="scan.fallback")
+                if sp:
+                    sp.set(path="fallback", encoded_bytes=(
+                        md.row_group(row_group).column(leaf_of[name])
+                        .total_compressed_size if name in leaf_of else 0))
+            if sp:
+                sp.set(decoded_bytes=cv.device_memory_size())
         cols.append(cv)
         fields.append(sf or T.StructField(name, cols[-1].dtype, True))
     return ColumnarBatch(cols, n_rows, T.StructType(fields))

@@ -21,7 +21,7 @@ import pyarrow.parquet as pq
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.plan.nodes import PlanNode
-from spark_rapids_tpu.runtime.tracing import trace_range
+from spark_rapids_tpu.runtime import tracing
 
 
 @dataclasses.dataclass
@@ -210,13 +210,15 @@ def write_columnar(exec_or_node, path: str, fmt: str = "parquet",
 
     from spark_rapids_tpu.runtime import metrics as M
     collector = M.current_collector()
+    parent_span = tracing.current_span()
 
     def run_split(split):
         writer = _TaskWriter(temp_dir, split, fmt, compression, partition_by,
                              schema, job_uuid, native=native)
         try:
             if isinstance(exec_or_node, TpuExec):
-                with M.collector_context(collector), TaskContext():
+                with M.collector_context(collector), TaskContext(), \
+                        tracing.child_of(parent_span):
                     for batch in exec_or_node.execute_partition(split):
                         writer.write_batch(batch)
             else:

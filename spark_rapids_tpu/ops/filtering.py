@@ -14,14 +14,17 @@ from jax import lax
 
 from spark_rapids_tpu.expr.core import Col
 from spark_rapids_tpu.ops.windowing import cumsum
+from spark_rapids_tpu.runtime import tracing
 
 
+@jax.named_scope("selection_mask")
 def selection_mask(pred: Col, num_rows, capacity: int):
     """Rows kept by a filter: predicate true AND valid AND a live (non-pad) row."""
     live = jnp.arange(capacity) < num_rows
     return pred.values & pred.validity & live
 
 
+@jax.named_scope("compact_cols")
 def compact_cols(cols, keep_mask):
     """Stable-move surviving rows to the front. Returns (new_cols, new_count).
 
@@ -67,6 +70,7 @@ def compact_cols(cols, keep_mask):
     return out, count
 
 
+@jax.named_scope("gather_cols")
 def gather_cols(cols, indices, valid_out):
     """Gather rows by index (join/sort output). valid_out masks output slots."""
     out = []
@@ -101,10 +105,12 @@ def host_compact_cols(cols, keep_mask, min_shrink: int = 4):
     from spark_rapids_tpu.columnar.vector import bucket_capacity
     from spark_rapids_tpu.runtime import fuse
 
-    keep = np.asarray(keep_mask)
-    capacity = int(keep.shape[0])
-    idx = np.nonzero(keep)[0]
-    count = int(idx.size)
+    with tracing.span("sync.count") as sp:
+        keep = np.asarray(keep_mask)
+        capacity = int(keep.shape[0])
+        idx = np.nonzero(keep)[0]
+        count = int(idx.size)
+        sp.set(rows=count, capacity=capacity)
     out_cap = bucket_capacity(count)
     if out_cap * min_shrink > capacity:
         return None
@@ -142,7 +148,12 @@ def maybe_host_resize(cols, count, min_shrink: int = 4):
     capacity = int(cols[0].values.shape[0])
     if capacity < (1 << 16):
         return None
-    n = int(count)
+    if isinstance(count, int):
+        n = count
+    else:
+        with tracing.span("sync.count") as sp:
+            n = int(count)
+            sp.set(rows=n, capacity=capacity)
     out_cap = bucket_capacity(n)
     if out_cap * min_shrink > capacity:
         return None

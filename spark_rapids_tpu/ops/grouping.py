@@ -47,6 +47,7 @@ class SegCtx(typing.NamedTuple):
     capacity: int
 
 
+@jax.named_scope("compact_key_codes")
 def compact_key_codes(key_cols, max_domain: int = 1 << 20):
     """(codes int32, strides) for keys whose domains are STATICALLY known
     (dictionary-coded strings, booleans); nulls get each key's top code
@@ -89,6 +90,7 @@ def combine_compact_keys(key_cols):
     return Col(combined, jnp.ones_like(combined, dtype=jnp.bool_), T.INT)
 
 
+@jax.named_scope("dense_group_sum")
 def dense_group_sum(vals, mask, codes, n_domain: int, use_matmul: bool,
                     count_like: bool = False):
     """(n_domain,) per-group totals of `vals` over UNSORTED small-domain
@@ -127,6 +129,7 @@ def dense_group_sum(vals, mask, codes, n_domain: int, use_matmul: bool,
 _STACK_MAX_DOMAIN = 64   # per-domain masked matvecs unroll D times
 
 
+@jax.named_scope("resolve_dense_group_sums")
 def resolve_dense_group_sums(reqs, codes, n_domain: int, live):
     """CPU batch executor for a batch's dense_group_sum requests
     (`reqs` = [(vals, mask, acc_dtype, count_like), ...]) → results in
@@ -166,6 +169,7 @@ def resolve_dense_group_sums(reqs, codes, n_domain: int, live):
     return outs
 
 
+@jax.named_scope("group_segments")
 def group_segments(key_cols, num_rows, capacity: int, range_hint=None,
                    presorted: bool = False):
     """Sort by keys and compute segment structure.
@@ -212,6 +216,7 @@ def group_segments(key_cols, num_rows, capacity: int, range_hint=None,
     return perm, seg_ids, boundary, live
 
 
+@jax.named_scope("segment_structure")
 def segment_structure(seg_ids, capacity: int) -> SegCtx:
     """Per-row segment start/end from sorted seg_ids (two NATIVE cumulative
     ops — see windowing.seg_starts/seg_ends — shared by every aggregate in
@@ -318,11 +323,13 @@ def _seg_extreme(data, ctx: SegCtx, largest: bool):
     return sorted_vals[pos]
 
 
+@jax.named_scope("segment_count")
 def segment_count(validity, ctx: SegCtx):
     """Per-row count of valid rows in the row's segment."""
     return _edge_sum(validity.astype(jnp.int64), ctx)
 
 
+@jax.named_scope("segment_sum")
 def segment_sum(values, validity, ctx: SegCtx):
     data = jnp.where(validity, values, jnp.zeros_like(values))
     if jnp.issubdtype(data.dtype, jnp.floating):
@@ -334,6 +341,7 @@ def segment_sum(values, validity, ctx: SegCtx):
     return s, segment_count(validity, ctx)
 
 
+@jax.named_scope("segment_min")
 def segment_min(values, validity, ctx: SegCtx, dtype: T.DataType):
     if isinstance(dtype, T.FractionalType):
         sentinel = jnp.asarray(jnp.inf, values.dtype)
@@ -352,6 +360,7 @@ def segment_min(values, validity, ctx: SegCtx, dtype: T.DataType):
     return _seg_extreme(data, ctx, largest=False)
 
 
+@jax.named_scope("segment_max")
 def segment_max(values, validity, ctx: SegCtx, dtype: T.DataType):
     if isinstance(dtype, T.FractionalType):
         nan = jnp.isnan(values)
@@ -369,6 +378,7 @@ def segment_max(values, validity, ctx: SegCtx, dtype: T.DataType):
     return _seg_extreme(data, ctx, largest=True)
 
 
+@jax.named_scope("segment_first")
 def segment_first(values, validity, ctx: SegCtx, ignore_nulls: bool):
     """First (by sorted order) value per group; Spark First(ignoreNulls)."""
     idx = jnp.arange(ctx.capacity, dtype=jnp.int32)
@@ -382,6 +392,7 @@ def segment_first(values, validity, ctx: SegCtx, ignore_nulls: bool):
     return vals, valid
 
 
+@jax.named_scope("segment_last")
 def segment_last(values, validity, ctx: SegCtx, ignore_nulls: bool):
     """Last (by sorted order) value per group; Spark Last(ignoreNulls)."""
     idx = jnp.arange(ctx.capacity, dtype=jnp.int32)

@@ -68,6 +68,7 @@ def _order_rank(v):
                             side="left").astype(jnp.int32)
 
 
+@jax.named_scope("tuple_ranks")
 def _tuple_ranks(key_cols, total_cap: int):
     """int32 ranks over the rows of integer-backed key columns such that rank
     equality == key-tuple equality and rank order == tuple order, or None
@@ -94,6 +95,7 @@ def _tuple_ranks(key_cols, total_cap: int):
     return acc
 
 
+@jax.named_scope("join_ranks")
 def join_ranks(build_keys, n_build, build_cap, stream_keys, n_stream, stream_cap):
     """Ranks for both sides such that rank equality == key-tuple equality.
     Null-keyed rows get side-specific sentinel ranks so they never match; padding
@@ -122,6 +124,7 @@ def join_ranks(build_keys, n_build, build_cap, stream_keys, n_stream, stream_cap
     return ranks[:build_cap], ranks[build_cap:]
 
 
+@jax.named_scope("probe")
 def probe(build_ranks, stream_ranks):
     """Sorted-build probe. Returns (build_perm, lo, hi) with lo/hi per stream row."""
     build_perm = jnp.argsort(build_ranks, stable=True)
@@ -135,6 +138,7 @@ def probe(build_ranks, stream_ranks):
     return build_perm, lo, hi
 
 
+@jax.named_scope("pair_counts")
 def pair_counts(lo, hi, n_stream, stream_cap, join_type):
     """Per-stream-row emitted pair count for the join type."""
     live = jnp.arange(stream_cap, dtype=jnp.int32) < n_stream
@@ -152,6 +156,7 @@ def pair_counts(lo, hi, n_stream, stream_cap, join_type):
     return jnp.where(live, counts, 0)
 
 
+@jax.named_scope("expand_pairs")
 def expand_pairs(build_perm, lo, hi, counts, start_pair: int, out_cap: int):
     """Materialize pairs [start_pair, start_pair+out_cap) as
     (stream_idx, build_idx, build_matched, pair_live).
@@ -174,5 +179,6 @@ def expand_pairs(build_perm, lo, hi, counts, start_pair: int, out_cap: int):
     return stream_idx_c, build_idx, build_matched & pair_live, pair_live
 
 
+@jax.named_scope("total_pairs")
 def total_pairs(counts):
     return jnp.sum(counts)

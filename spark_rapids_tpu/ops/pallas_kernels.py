@@ -205,6 +205,7 @@ def murmur3_words(words, lengths, seed) -> jnp.ndarray:
         ],
         out_specs=pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
         interpret=_interpret(),
+        name="srt_pallas_murmur3",
     )(words_p, slab(lengths), slab(seed_rows))
     return out.reshape(n_pad)[:n]
 
@@ -263,6 +264,7 @@ def bitunpack128(words_u32, bit_width: int, n: int, capacity: int):
         in_specs=[pl.BlockSpec((tile, 4 * bw), lambda i: (i, _I0))],
         out_specs=pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
         interpret=_interpret(),
+        name="srt_pallas_bitunpack",
     )(w)
     flat = out.reshape(-1)
     idx = jnp.arange(capacity, dtype=jnp.int32)
@@ -341,6 +343,7 @@ def onehot_sum_f32(vals, codes, n_domain: int):
                   pl.BlockSpec((1, bk), lambda i, k: (_I0, k))],
         out_specs=pl.BlockSpec((1, _OH_BD), lambda i, k: (_I0, i)),
         interpret=_interpret(),
+        name="srt_pallas_onehot",
     )(codes2, vals2)
     return out[0, :n_domain]
 
@@ -412,6 +415,7 @@ def radix_ranks(ids, num_lanes: int):
         out_specs=[pl.BlockSpec((1, bk), lambda i: (_I0, i)),
                    pl.BlockSpec((dp, 1), lambda i: (_I0, _I0))],
         interpret=_interpret(),
+        name="srt_pallas_radix",
     )(ids_p)
     return ranks[0, :cap], counts[:num_lanes, 0]
 
@@ -531,6 +535,7 @@ def hash_join_probe(table_keys, table_rows, stream_i64, num_buckets: int):
         out_specs=[pl.BlockSpec((1, tile), lambda i: (_I0, i)),
                    pl.BlockSpec((1, tile), lambda i: (_I0, i))],
         interpret=_interpret(),
+        name="srt_pallas_hashjoin",
     )(sp, table_keys.reshape(1, hs), table_rows.reshape(1, hs))
     return pos[0, :n], found[0, :n].astype(jnp.bool_)
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -78,6 +79,7 @@ def _key_bits(c: Col) -> int | None:
     return None
 
 
+@jax.named_scope("packed_key")
 def _packed_key(key_cols, orders, num_rows, capacity: int,
                 range_hint=None):
     """Pack (pad-rank, per-key null-rank + value image, row index) into ONE
@@ -146,6 +148,7 @@ def _packed_key(key_cols, orders, num_rows, capacity: int,
     return (acc << iota_bits) | jnp.arange(capacity, dtype=jnp.int64), iota_bits
 
 
+@jax.named_scope("wide_single_key")
 def _wide_single_key(key_cols, orders, num_rows, capacity: int):
     """Single int key too wide for the packed operand (int64/timestamp):
     TWO int64 operands instead of the 4-operand stable comparator sort
@@ -182,6 +185,7 @@ def _wide_single_key(key_cols, orders, num_rows, capacity: int):
     return (s2 & ((1 << iota_bits) - 1)).astype(jnp.int32)
 
 
+@jax.named_scope("sort_permutation")
 def sort_permutation(key_cols, orders, num_rows, capacity: int,
                      range_hint=None):
     """Stable permutation sorting live rows by keys; padding sinks to the end."""
@@ -206,6 +210,7 @@ def sort_permutation(key_cols, orders, num_rows, capacity: int,
     return res[-1]
 
 
+@jax.named_scope("sort_cols")
 def sort_cols(cols, key_indices, orders, num_rows, capacity):
     from spark_rapids_tpu.ops.filtering import gather_cols
     perm = sort_permutation([cols[i] for i in key_indices], orders, num_rows, capacity)
@@ -213,6 +218,7 @@ def sort_cols(cols, key_indices, orders, num_rows, capacity):
     return gather_cols(cols, perm, live)
 
 
+@jax.named_scope("partition_permutation")
 def partition_permutation(part_ids, num_partitions: int, num_rows,
                           capacity: int):
     """Stable permutation grouping live rows by partition id with padding

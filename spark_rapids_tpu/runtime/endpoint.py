@@ -87,6 +87,7 @@ from spark_rapids_tpu.runtime import blackbox as BB
 from spark_rapids_tpu.runtime import faults as F
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import scheduler as SCHED
+from spark_rapids_tpu.runtime import tracing
 from spark_rapids_tpu.runtime.checksum import block_checksum
 from spark_rapids_tpu.shuffle.transport import (TransportError,
                                                 configure_socket,
@@ -697,6 +698,13 @@ class QueryEndpoint:
     def _serve_query(self, sock, payload) -> bool:
         """Run one submission and stream its results; returns False when the
         connection is dead and the handler loop should exit."""
+        # SUBMIT frame read to the END frame written: the request's whole
+        # stay on this connection thread, and the parent of the worker
+        # thread's ``query`` span
+        with tracing.span("endpoint.request") as req_span:
+            return self._serve_query_in(sock, payload, req_span)
+
+    def _serve_query_in(self, sock, payload, req_span) -> bool:
         try:
             req = json.loads(payload.decode("utf-8"))
             sql = req["sql"]
@@ -750,7 +758,8 @@ class QueryEndpoint:
             self._journey_finish(jctx, "shed", reason="draining")
             return self._shed_draining(sock)
         worker = threading.Thread(target=self._run_query,
-                                  args=(df, stream, req.get("trace"), record),
+                                  args=(df, stream, req.get("trace"), record,
+                                        req_span),
                                   daemon=True, name=wname)
         worker.start()
         try:
@@ -768,7 +777,7 @@ class QueryEndpoint:
                 self._active.pop(key, None)
 
     def _run_query(self, df, stream: _ResultStream, trace: str | None = None,
-                   record: dict | None = None):
+                   record: dict | None = None, req_span=tracing.NO_SPAN):
         """Worker thread: execute the action, pushing each result batch into
         the stream as a CRC-stamped Arrow-IPC payload. Partitions run in
         order on this one thread (batch order must be deterministic for the
@@ -777,17 +786,20 @@ class QueryEndpoint:
         byte budget overlaps compute with the network send. A client-supplied
         `trace` id is handed to the query's collector so server-side spans
         land in the client's distributed trace. `record` collects the clean
-        wire frames for the result cache (admitted only on success)."""
+        wire frames for the result cache (admitted only on success).
+        `req_span` is the connection thread's ``endpoint.request`` span: the
+        query's spans on this thread become its children, and it is told
+        what the reply was made of."""
         from spark_rapids_tpu.exec.base import TaskContext, TpuExec
         from spark_rapids_tpu.runtime import pipeline as P
-        from spark_rapids_tpu.runtime import tracing
         if trace:
             tracing.set_pending_trace(str(trace))
-        counts = {"rows": 0, "batches": 0}
+        counts = {"rows": 0, "batches": 0, "bytes": 0}
 
         def sink(tbl: pa.Table):
-            body = _table_to_ipc(tbl)
-            crc = block_checksum(body)
+            with tracing.span("endpoint.encode"):
+                body = _table_to_ipc(tbl)
+                crc = block_checksum(body)
             if record is not None and not record["over"]:
                 # record BEFORE fault corruption — a chaos byte flip must
                 # reach exactly one client, never be replayed from cache
@@ -800,12 +812,15 @@ class QueryEndpoint:
             # chaos: flip a byte AFTER the CRC is stamped — the client's
             # verification must catch it and raise typed TransportError
             body = F.maybe_corrupt("endpoint.corrupt", body)
-            if not stream.put(_CRC.pack(crc) + body):
+            with tracing.span("endpoint.send"):
+                queued = stream.put(_CRC.pack(crc) + body)
+            if not queued:
                 SCHED.check_cancel()   # raises the token's typed error
                 raise SCHED.QueryCancelledError(
                     "result stream closed by the connection")
             counts["rows"] += tbl.num_rows
             counts["batches"] += 1
+            counts["bytes"] += _CRC.size + len(body)
 
         def run(hybrid):
             if isinstance(hybrid, TpuExec):
@@ -827,7 +842,9 @@ class QueryEndpoint:
             return None
 
         try:
-            df._run_action(df._plan, run)
+            with tracing.child_of(req_span.id):
+                df._run_action(df._plan, run)
+            req_span.set(**counts)
             qm = df._last_collector
             summary = {
                 "query": qm.query_id, "trace": qm.trace_id,

@@ -23,6 +23,7 @@ replays); a healthy query does O(stages) traces and O(batches) dispatches.
 
 from __future__ import annotations
 
+import re
 import threading
 import types as _types
 
@@ -51,20 +52,6 @@ _last_sweep_traces = 0
 # counters are module-global (queries share kernels); reset via reset_metrics()
 _counts = {"traces": 0, "dispatches": 0}
 
-# SRT_FUSE_PROFILE=1: block on every kernel dispatch and record wall time per
-# kernel name (kernel_profile()) — the steering tool for finding slow stages
-import os as _os
-_PROFILE = _os.environ.get("SRT_FUSE_PROFILE", "") == "1"
-_profile: dict = {}
-
-
-def kernel_profile() -> dict:
-    """{kernel_name: (total_seconds, calls)} — only populated under
-    SRT_FUSE_PROFILE=1."""
-    with _lock:
-        return dict(_profile)
-
-
 def stage_metrics() -> dict:
     """{'traces': n_xla_compiles, 'dispatches': n_program_replays}."""
     with _lock:
@@ -87,6 +74,13 @@ class _Unset:
 
 
 _UNSET = _Unset()
+
+
+def program_name(name: str) -> str:
+    """``HashJoin.emit`` -> ``srt_HashJoin_emit``: what a kernel's XLA
+    program is called in a device trace, and the outermost scope of every
+    operation inside it."""
+    return "srt_" + re.sub(r"\W", "_", name)
 
 
 class BatchKernel:
@@ -117,7 +111,7 @@ class BatchKernel:
         self._digest = _UNSET       # lazily: hex str, or None (undigestable)
         self._compiled: dict = {}   # sig digest -> AOT-loaded executable
 
-        def traced(*args):
+        def program(*args):
             with _lock:
                 _counts["traces"] += 1
             # per-query retrace attribution: the tracing thread runs inside
@@ -126,7 +120,10 @@ class BatchKernel:
             _M.compile_add("compiles")
             return fn(*args)
 
-        self._jit = jax.jit(traced)
+        # the device trace names a program after its function: jit_srt_<name>
+        # (the call site's name, ~30 of them; the fingerprint stays out)
+        program.__name__ = program.__qualname__ = program_name(name)
+        self._jit = jax.jit(program)
 
     def cache_size(self) -> int:
         """Live compiled-executable count: one per traced shape signature in
@@ -201,15 +198,6 @@ class BatchKernel:
         if do_sweep:
             _sweep_executables()
         _M.compile_add("dispatches")
-        if _PROFILE:
-            import time
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(self._dispatch(args))
-            dt = time.perf_counter() - t0
-            with _lock:
-                tot, n = _profile.get(self.name, (0.0, 0))
-                _profile[self.name] = (tot + dt, n + 1)
-            return out
         return self._dispatch(args)
 
 
@@ -219,7 +207,8 @@ _backend_tag_memo = None
 # key: persistent entries are keyed by (semantic key, arg signature), not by
 # the traced HLO, so a stale store replaying an old program would be a silent
 # wrong answer — the version tag turns it into a cache miss instead.
-KERNEL_CACHE_VERSION = 1
+# 2: programs carry their kernel's name (jit_srt_<name>) and operator scopes.
+KERNEL_CACHE_VERSION = 2
 
 
 def _backend_tag() -> str:

@@ -304,6 +304,7 @@ def stage_iterator(gen, *, edge: str, conf=None, registry=None, node_id=None,
     if _queue_cb is not None:
         _queue_cb(q)
     collector = M.current_collector()
+    parent_span = tracing.current_span()
     frame_producer = node_id is not None or self_time_metric is not None
 
     def produce():
@@ -313,9 +314,10 @@ def stage_iterator(gen, *, edge: str, conf=None, registry=None, node_id=None,
         try:
             # one span per segment run: the srt-pipe-<edge> thread becomes
             # its own lane in the merged Perfetto timeline (trace id via the
-            # re-entered collector scope, or the executor's process trace)
+            # re-entered collector scope, or the executor's process trace;
+            # parent = the span open where the segment was set up)
             with M.collector_context(collector), TaskContext(), \
-                    tracing.span(f"pipeline.{edge}"):
+                    tracing.span(f"pipeline.{edge}", parent=parent_span):
                 while True:
                     # segment batch loops are the issue's canonical
                     # cancellation points: one check per produced item

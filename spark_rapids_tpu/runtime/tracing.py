@@ -1,22 +1,43 @@
-"""Trace ranges — the NVTX analog, plus the cross-process span plane.
+"""Spans — the NVTX analog: one primitive, three places a span lands.
 
 Reference: NvtxWithMetrics.scala:42 couples an NVTX range with a timing metric;
 ranges wrap every hot region (GpuSemaphore.scala:107, aggregate.scala:356) and are
-viewed in Nsight. TPU equivalent: jax.profiler.TraceAnnotation ranges viewable in
-Perfetto/XProf, coupled to GpuMetric timers, gated by spark.rapids.tpu.sql.trace.enabled.
+viewed in Nsight. Here ``trace_range(name, metric, **counts)`` and
+``span(name, **counts)`` are ONE code path (``_Span``), switched by
+spark.rapids.tpu.sql.trace.enabled. When that is on a span
 
-Distributed spans (spark.rapids.tpu.trace.dir): the reference views
+  (a) opens a ``jax.profiler.TraceAnnotation`` of the same name, so it lands
+      in a profiler capture (``.xplane.pb``, Perfetto/XProf) on the device
+      trace's own clock and idle device time can be put down to it;
+  (b) appends one record to a bounded in-process buffer: name, span id,
+      parent id (the span open on this thread, or the one handed over with
+      ``current_span()`` / ``child_of()`` when work crosses to a pipeline,
+      pool or endpoint worker thread), the query's trace id, thread name,
+      start and end in ``time.perf_counter_ns()`` and the counts given at
+      the boundary (``span(..., rows=n)`` or ``sp.set(rows=n)``).
+
+A span that is a root, or that was opened with counts, carries those counts
+and ``t0_ns`` (its ``perf_counter_ns`` start) as annotation metadata, so the
+offset between this clock and the capture's is read from the capture itself.
+The buffer is only read after the fact (``recorded()``, ``drain()``,
+``summarize()``); nothing is written on the hot path. It holds the newest
+``MAX_RECORDS`` spans; ``dropped()`` counts what fell off, and a reader that
+needs whole queries treats any drop as "no reading". With tracing off every
+span site costs one module-level check and the shared no-op ``NO_SPAN``.
+
+  (c) Distributed spans (spark.rapids.tpu.trace.dir): the reference views
 whole-cluster execution in Nsight because NVTX ranges from every process land
-in one capture. Here each process appends its ranges to its own JSONL span
-file (``spans-<pid>-<stamp>.jsonl``) tagged with a per-query **trace id** that
-propagates across every process boundary — the MiniCluster task protocol,
+in one capture. Here each process appends the same records to its own JSONL
+span file (``spans-<pid>-<stamp>.jsonl``) tagged with a per-query **trace id**
+that propagates across every process boundary — the MiniCluster task protocol,
 shuffle-transport frame headers, and the endpoint SUBMIT frame — so
 ``tools/profiler.py trace <dir>`` can merge them into one Chrome-trace
 timeline (Perfetto) with per-process clock-offset correction
 (runtime/eventlog.set_clock_offset, measured by the driver's two-timestamp
-handshake exchange) and walk the critical path.
+handshake exchange) and walk the critical path. The file sink works with or
+without sql.trace.enabled.
 
-Span record schema (validate_span):
+Span record schema of the file sink (validate_span):
   name  str    range name (trace_range/span) or event name (span_event) or
                counter track name (counter)
   ph    "X"|"i"|"C"  complete span | zero-duration instant | counter sample
@@ -29,7 +50,9 @@ Span record schema (validate_span):
   tid   str    thread name (pipeline edges appear as their srt-pipe-* lanes)
   trace str|None  the query's trace id (None for out-of-query spans)
   off   float  clock offset toward the driver (omitted when 0)
-  args  dict   optional attributes
+  args  dict   optional attributes (the span's counts)
+  id    int    span id, unique in the process (ph == "X" only)
+  parent int|None  id of the enclosing span (ph == "X" only)
 """
 
 from __future__ import annotations
@@ -37,6 +60,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import datetime
+import itertools
 import json
 import os
 import threading
@@ -45,7 +69,8 @@ import time
 from spark_rapids_tpu.runtime import eventlog as _eventlog
 from spark_rapids_tpu.runtime import metrics as _metrics
 
-_enabled = False
+_enabled = False   # sql.trace.enabled: annotations + in-memory records
+_active = False    # _enabled or a span file is open: what a site checks
 
 # zero-duration span events (oom.retry / oom.split / fetch.recompute …): a
 # bounded in-memory ring that chaos tests and postmortems read regardless of
@@ -168,6 +193,7 @@ def configure_spans(directory: str, process: "str | None" = None) -> str:
     if _span_writer is not None:
         _span_writer.close()
     _span_writer = SpanWriter(path, process or f"pid{os.getpid()}")
+    _set_active()
     return path
 
 
@@ -185,10 +211,11 @@ def shutdown_spans() -> None:
     if _span_writer is not None:
         _span_writer.close()
         _span_writer = None
+    _set_active()
 
 
 def _emit_span(name: str, ph: str, ts: float, dur: "float | None",
-               attrs: "dict | None") -> None:
+               attrs: "dict | None", ids: "tuple | None" = None) -> None:
     w = _span_writer
     if w is None:
         return
@@ -197,6 +224,8 @@ def _emit_span(name: str, ph: str, ts: float, dur: "float | None",
            "trace": current_trace_id()}
     if dur is not None:
         rec["dur"] = dur
+    if ids is not None:
+        rec["id"], rec["parent"] = ids
     off = _eventlog.clock_offset()
     if off:
         rec["off"] = off
@@ -205,22 +234,215 @@ def _emit_span(name: str, ph: str, ts: float, dur: "float | None",
     w.write(rec)
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
-    """Explicit span-file range (the trace_range analog for regions that
-    have no metric and no NVTX need: tasks, pipeline segments, fetches).
-    Free when no span sink is configured."""
-    if _span_writer is None:
-        yield
-        return
-    ts = time.time()
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
+# ---------------------------------------------------------------------------
+# the span primitive
+# ---------------------------------------------------------------------------
+
+MAX_RECORDS = 1 << 18
+_records: "collections.deque" = collections.deque(maxlen=MAX_RECORDS)
+_records_lock = threading.Lock()
+_dropped = 0
+_span_ids = itertools.count(1)
+
+
+def _set_active() -> None:
+    global _active
+    _active = _enabled or _span_writer is not None
+
+
+class _NoSpan:
+    """What every span site gets while nothing listens: shared, falsy, and
+    every method a no-op."""
+
+    __slots__ = ()
+    id = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __bool__(self):
+        return False
+
+    def set(self, **counts) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Timed(_NoSpan):
+    """trace_range with a metric while nothing listens: the timer alone."""
+
+    __slots__ = ("_metric", "_t0")
+
+    def __init__(self, metric):
+        self._metric = metric
+
+    def __enter__(self):
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self._metric.add(time.perf_counter_ns() - self._t0)
+        return False
+
+
+class _Span:
+    """One open span; becomes its own record when it closes."""
+
+    __slots__ = ("name", "id", "parent", "trace", "thread", "t0", "t1",
+                 "counts", "_metric", "_outer", "_ann", "_ts")
+
+    def __init__(self, name, metric, parent, counts):
+        self.name, self._metric = name, metric
+        self.parent, self.counts = parent, counts
+        self.t1 = self._ann = None
+
+    def __enter__(self):
+        tls = _trace_tls
+        self._outer = getattr(tls, "open", None)
+        if self.parent is None:
+            self.parent = self._outer
+        self.id = tls.open = next(_span_ids)
+        self.trace = current_trace_id()
+        self.thread = threading.current_thread().name
+        self._ts = time.time() if _span_writer is not None else 0.0
+        self.t0 = time.perf_counter_ns()
+        if _enabled:
+            import jax
+            if self.counts or self.parent is None:
+                ann = jax.profiler.TraceAnnotation(
+                    self.name, t0_ns=self.t0, **self.counts)
+            else:
+                ann = jax.profiler.TraceAnnotation(self.name)
+            ann.__enter__()
+            self._ann = ann
+        return self
+
+    def __exit__(self, *exc):
+        global _dropped
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.t1 = time.perf_counter_ns()
+        _trace_tls.open = self._outer
+        if self._metric is not None:
+            self._metric.add(self.t1 - self.t0)
+        self._ann = self._metric = None    # the record keeps neither alive
+        if _enabled:
+            with _records_lock:
+                if len(_records) == MAX_RECORDS:
+                    _dropped += 1
+                _records.append(self)
         if _span_writer is not None:
-            _emit_span(name, "X", ts, (time.perf_counter_ns() - t0) / 1e9,
-                       attrs)
+            _emit_span(self.name, "X", self._ts, (self.t1 - self.t0) / 1e9,
+                       self.counts or None, (self.id, self.parent))
+        return False
+
+    def __bool__(self):
+        return True
+
+    def set(self, **counts) -> None:
+        """Counts known only at the boundary (rows out, bytes, the path
+        taken); the host must hold them already."""
+        self.counts.update(counts)
+
+    def as_dict(self) -> dict:
+        return {"name": self.name, "id": self.id, "parent": self.parent,
+                "trace": self.trace, "thread": self.thread, "t0": self.t0,
+                "t1": self.t1, "counts": dict(self.counts)}
+
+
+def span(name: str, *, parent: "int | None" = None, **counts):
+    """A span with no metric: ``with span("scan.column") as sp: ...;
+    sp.set(path="fused")``. ``parent`` overrides the span open on this
+    thread (a span id from ``current_span()`` on the submitting thread)."""
+    if not _active:
+        return NO_SPAN
+    return _Span(name, None, parent, counts)
+
+
+def trace_range(name: str, metric=None, **counts):
+    """NvtxWithMetrics analog: the same span, coupled to a timing metric
+    that accumulates whether or not anything listens."""
+    if not _active:
+        return NO_SPAN if metric is None else _Timed(metric)
+    return _Span(name, metric, None, counts)
+
+
+def current_span() -> "int | None":
+    """Id of the span open on this thread, to hand to a worker thread."""
+    return getattr(_trace_tls, "open", None) if _active else None
+
+
+class _ChildOf:
+    __slots__ = ("_parent", "_outer")
+
+    def __init__(self, parent):
+        self._parent = parent
+
+    def __enter__(self):
+        self._outer = getattr(_trace_tls, "open", None)
+        _trace_tls.open = self._parent
+        return self
+
+    def __exit__(self, *exc):
+        _trace_tls.open = self._outer
+        return False
+
+
+def child_of(parent: "int | None"):
+    """On a worker thread: spans opened inside are children of ``parent``
+    (what ``current_span()`` returned on the thread that handed the work
+    over). The companion of ``metrics.collector_context``."""
+    return NO_SPAN if parent is None else _ChildOf(parent)
+
+
+def recorded() -> list:
+    """The buffered spans, oldest first, one dict each (``_Span.as_dict``).
+    Read after the work, not during it."""
+    with _records_lock:
+        spans = list(_records)
+    return [s.as_dict() for s in spans]
+
+
+def dropped() -> int:
+    """Spans that fell off the buffer since the last ``drain()``. A reader
+    that needs whole queries gives no reading when this is not 0."""
+    return _dropped
+
+
+def drain() -> list:
+    """``recorded()``, and the buffer and its drop count start anew."""
+    global _dropped
+    with _records_lock:
+        spans = list(_records)
+        _records.clear()
+        _dropped = 0
+    return [s.as_dict() for s in spans]
+
+
+def summarize(spans: list) -> dict:
+    """{name: {"count", "total_s", "self_s"}} over ``recorded()`` records.
+    Self time is a span's duration minus its children's on the same thread
+    (a child on another thread runs beside its parent, not inside it)."""
+    by_id = {s["id"]: s for s in spans}
+    child_ns: dict = {}
+    for s in spans:
+        p = by_id.get(s["parent"])
+        if p is not None and p["thread"] == s["thread"]:
+            child_ns[p["id"]] = child_ns.get(p["id"], 0) + s["t1"] - s["t0"]
+    out: dict = {}
+    for s in spans:
+        d = s["t1"] - s["t0"]
+        row = out.setdefault(s["name"],
+                             {"count": 0, "total_s": 0.0, "self_s": 0.0})
+        row["count"] += 1
+        row["total_s"] += d / 1e9
+        row["self_s"] += (d - child_ns.get(s["id"], 0)) / 1e9
+    return out
 
 
 def instant(name: str, **attrs) -> None:
@@ -308,41 +530,20 @@ def clear_events() -> None:
 def set_enabled(v: bool):
     global _enabled
     _enabled = bool(v)
-
-
-@contextlib.contextmanager
-def trace_range(name: str, metric=None):
-    """NvtxWithMetrics analog: profiler annotation + optional timing metric
-    + (when a span sink is configured) a span-file range, so every
-    NVTX-wrapped hot region lands on the merged distributed timeline for
-    free."""
-    w = _span_writer
-    need_t = metric is not None or w is not None
-    t0 = time.perf_counter_ns() if need_t else 0
-    ts = time.time() if w is not None else 0.0
-    with contextlib.ExitStack() as stack:
-        if _enabled:
-            import jax
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
-        try:
-            yield
-        finally:
-            if need_t:
-                dt = time.perf_counter_ns() - t0
-                if metric is not None:
-                    metric.add(dt)
-                if w is not None and _span_writer is not None:
-                    _emit_span(name, "X", ts, dt / 1e9, None)
+    _set_active()
 
 
 _profiling = False
 _profile_dir = None
 
 
-def start_profile(outdir: str) -> None:
+def start_profile(outdir: str, **options) -> None:
     """Whole-session XProf capture (idempotent; stopped at interpreter
     exit — use stop_profile() to flush earlier in long-lived processes).
-    Viewable in Perfetto/XProf — the Nsight-workflow analog."""
+    Viewable in Perfetto/XProf — the Nsight-workflow analog. ``options``
+    are attributes of ``jax.profiler.ProfileOptions``: on a chip pass
+    ``python_tracer_level=0``, or the Python tracer's events swamp the
+    capture."""
     global _profiling, _profile_dir
     if _profiling:
         if outdir != _profile_dir:
@@ -354,7 +555,13 @@ def start_profile(outdir: str) -> None:
     _profile_dir = outdir
     import atexit
     import jax
-    jax.profiler.start_trace(outdir)
+    if options:
+        opts = jax.profiler.ProfileOptions()
+        for k, v in options.items():
+            setattr(opts, k, v)
+        jax.profiler.start_trace(outdir, profiler_options=opts)
+    else:
+        jax.profiler.start_trace(outdir)
     _profiling = True
 
     atexit.register(stop_profile)

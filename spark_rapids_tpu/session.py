@@ -33,6 +33,34 @@ from spark_rapids_tpu.plan.overrides import TpuOverrides
 from spark_rapids_tpu.plan.transitions import execute_hybrid
 
 
+def _plan_counts(hybrid) -> dict:
+    """What the ``query.plan`` span says of the plan it made: operators,
+    fused stages, and operators that stayed on the host. Walked only while
+    tracing is on."""
+    from spark_rapids_tpu.exec.base import TpuExec
+    from spark_rapids_tpu.plan.stages import assign_stages
+    from spark_rapids_tpu.plan.transitions import (DeviceBridgeExec,
+                                                   HostBridgeNode)
+    operators = fallback = stages = 0
+    todo = [(hybrid, False)]
+    while todo:
+        node, under_device = todo.pop()
+        if isinstance(node, DeviceBridgeExec):
+            todo.append((node.host_node, False))
+            continue
+        if isinstance(node, HostBridgeNode):
+            todo.append((node.tpu_exec, False))
+            continue
+        operators += 1
+        on_device = isinstance(node, TpuExec)
+        if not on_device:
+            fallback += 1
+        elif not under_device:   # the root of a device subtree
+            stages += len(set(assign_stages(node).values()))
+        todo.extend((c, on_device) for c in node.children)
+    return {"operators": operators, "stages": stages, "fallback": fallback}
+
+
 def _abort_execs(collector) -> None:
     """Query-death sweep: give every exec registered with the dead query's
     collector its `abort_query()` cleanup hook (shuffle exchanges free map
@@ -351,30 +379,41 @@ class DataFrame:
                     collector.wall_s)
         admitted = False
         with M.collector_context(collector), \
-                tracing.span("query", query=collector.query_id):
-            hybrid = TpuOverrides(conf).apply(plan)
-            collector.set_root(hybrid)
-            if EL.enabled():
-                from spark_rapids_tpu.plan.stages import emit_stage_events
-                emit_stage_events(hybrid, collector.query_id)
-            try:
-                queue_timeout = conf.get(CFG.SCHEDULER_QUEUE_TIMEOUT)
+                tracing.span("query", query=collector.query_id) as query_span:
+            # query.plan: overrides, stage split and footprint estimate, up
+            # to the scheduler's door — the part of collect() that no clock
+            # outside the program can see
+            with tracing.span("query.plan") as plan_span:
+                hybrid = TpuOverrides(conf).apply(plan)
+                collector.set_root(hybrid)
+                if EL.enabled():
+                    from spark_rapids_tpu.plan.stages import \
+                        emit_stage_events
+                    emit_stage_events(hybrid, collector.query_id)
                 # admission footprint: per-shape observed history when the
                 # store has seen this plan's fingerprint, else the static
                 # scan-bytes heuristic (stats plane; provenance kept on the
                 # collector for plan.stats / bench / explain(stats=True))
                 collector.footprint = SCHED.estimate_footprint_ex(plan, conf)
-                sched.submit(
-                    collector.query_id,
-                    collector.footprint["estimate"],
-                    priority=priority,
-                    token=token,
-                    timeout_s=queue_timeout if queue_timeout > 0 else None,
-                    description=collector.description)
+                if plan_span:
+                    plan_span.set(**_plan_counts(hybrid))
+            try:
+                queue_timeout = conf.get(CFG.SCHEDULER_QUEUE_TIMEOUT)
+                with tracing.span("query.admission"):
+                    sched.submit(
+                        collector.query_id,
+                        collector.footprint["estimate"],
+                        priority=priority,
+                        token=token,
+                        timeout_s=queue_timeout if queue_timeout > 0
+                        else None,
+                        description=collector.description)
                 admitted = True
                 EL.emit("query.start", query=collector.query_id,
                         description=collector.description)
                 out = run(hybrid)
+                if query_span and hasattr(out, "num_rows"):
+                    query_span.set(rows=out.num_rows)
                 # end-of-query leak detection (memory observability plane):
                 # the action has drained, so any device bytes still tagged
                 # to this query are a leak — event + counter + reclaim,
@@ -1042,7 +1081,9 @@ class TpuSession:
     def sql(self, text: str) -> DataFrame:
         """Run a SQL query over the registered temp views (the reference's
         entire surface is SQL text — qa_nightly_sql.py; see sql/)."""
+        from spark_rapids_tpu.runtime import tracing
         from spark_rapids_tpu.sql import lower_sql
-        if self._stream_sources:
-            self._refresh_stream_views()
-        return DataFrame(lower_sql(text, self._views, self), self)
+        with tracing.span("sql.parse"):
+            if self._stream_sources:
+                self._refresh_stream_views()
+            return DataFrame(lower_sql(text, self._views, self), self)

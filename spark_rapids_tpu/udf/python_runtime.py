@@ -21,6 +21,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exec.base import TpuExec, acquire_semaphore
 from spark_rapids_tpu.expr.core import Expression
 from spark_rapids_tpu.runtime import metrics as M
+from spark_rapids_tpu.runtime import tracing
 from spark_rapids_tpu.runtime.tracing import trace_range
 
 
@@ -193,10 +194,12 @@ class ArrowEvalPythonExec(TpuExec):
             # prefetch threads re-enter the query scope so any event they
             # fire (spill during H2D, etc.) attributes to this query/node
             collector = M.current_collector()
+            parent_span = tracing.current_span()
 
             def eval_in_scope(batch):
                 with M.collector_context(collector), \
-                        M.node_frame(self._node_id, None):
+                        M.node_frame(self._node_id, None), \
+                        tracing.child_of(parent_span):
                     return eval_batch(batch)
 
             pending = []

@@ -102,6 +102,7 @@ def test_trace_range_metric_both_paths():
         pass
     v1 = m.value
     assert v1 > 0
+    tracing.drain()
     tracing.set_enabled(True)
     try:
         with tracing.trace_range("r", m):
@@ -109,6 +110,35 @@ def test_trace_range_metric_both_paths():
     finally:
         tracing.set_enabled(False)
     assert m.value > v1
+    # on, the same range is also one record of the in-memory buffer, and
+    # its duration is what the metric gained
+    rec, = tracing.drain()
+    assert rec["name"] == "r" and rec["t1"] - rec["t0"] == m.value - v1
+
+
+def test_query_root_span_says_what_the_plan_was_and_what_came_out():
+    tracing.drain()
+    tracing.set_enabled(True)
+    try:
+        s = TpuSession()
+        df = s.create_dataframe(pa.table({"a": [1, 2, 3, 4]}))
+        s.create_or_replace_temp_view("obs_t", df)
+        out = s.sql("select a from obs_t where a > 1").collect()
+    finally:
+        tracing.set_enabled(False)
+    spans = {r["name"]: r for r in tracing.drain()}
+    root = spans["query"]
+    assert root["parent"] is None and root["counts"]["rows"] == out.num_rows
+    assert root["counts"]["query"] == s.last_query_metrics().query_id
+    assert root["trace"] == root["counts"]["query"]
+    assert spans["sql.parse"]["parent"] is None
+    assert spans["sql.parse"]["t1"] <= root["t0"]
+    plan = spans["query.plan"]
+    assert plan["parent"] == root["id"]
+    assert plan["counts"]["operators"] >= 2 and plan["counts"]["stages"] >= 1
+    assert plan["counts"]["fallback"] == 0
+    assert spans["query.admission"]["parent"] == root["id"]
+    assert plan["t1"] <= spans["query.admission"]["t0"]
 
 
 def test_stop_profile_unregisters_atexit(monkeypatch):

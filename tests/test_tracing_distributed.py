@@ -176,6 +176,48 @@ def test_span_file_schema_and_trace_precedence(tmp_path):
     # the metric side of trace_range still accumulated
     assert timer.value > 0
     assert by_name["ProjectExec"]["dur"] > 0
+    # the merged primitive: complete spans gain an id and a parent, instants
+    # stay as they were, and a span's counts are its args
+    assert by_name["task.map"]["args"] == {"split": 3}
+    for name in ("ProjectExec", "task.map", "orphan"):
+        assert isinstance(by_name[name]["id"], int)
+        assert by_name[name]["parent"] is None
+    assert "id" not in by_name["oom.retry"]
+
+
+def test_span_file_carries_the_tree_without_the_profiler_switch(tmp_path):
+    """The file sink is fed from the same record as the in-memory buffer,
+    whether or not sql.trace.enabled is on; only the buffer needs it."""
+    def tree():
+        with tracing.trace_context("t1"), tracing.span("query") as q:
+            with tracing.trace_range("FilterExec") as f:
+                f.set(rows=7)
+            with tracing.span("FilterExec"):
+                pass
+        return q.id
+
+    tracing.drain()
+    path = tracing.configure_spans(str(tmp_path), process="driver")
+    root_off = tree()
+    assert tracing.recorded() == []
+    tracing.set_enabled(True)
+    try:
+        root_on = tree()
+    finally:
+        tracing.set_enabled(False)
+    tracing.shutdown_spans()
+    recs = [json.loads(ln) for ln in open(path)]
+    assert all(tracing.validate_span(r) == [] for r in recs)
+    assert [r["name"] for r in recs] == ["FilterExec", "FilterExec",
+                                         "query"] * 2
+    for root, part in ((root_off, recs[:3]), (root_on, recs[3:])):
+        assert part[2]["id"] == root and part[2]["parent"] is None
+        assert [r["parent"] for r in part[:2]] == [root, root]
+        assert part[0]["args"] == {"rows": 7} and "args" not in part[1]
+    mem = tracing.drain()
+    assert [(s["name"], s["id"], s["parent"]) for s in mem] == \
+        [(r["name"], r["id"], r["parent"]) for r in recs[3:]]
+    assert all(s["trace"] == "t1" for s in mem)
 
 
 def test_chrome_trace_schema(tmp_path):
