@@ -6,13 +6,15 @@ Reference: GpuParquetScan.scala:1235 hands raw column-chunk bytes to
 page headers and RLE run STRUCTURE are metadata (bytes to kilobytes — parsed
 on host, like string dictionaries), while the BULK bytes — bit-packed
 dictionary indices and definition levels — go to the device, where one jitted
-program unpacks bits and gathers dictionary values (ops/parquet_decode.py).
+program a column chunk unpacks bits and gathers dictionary values
+(ops/parquet_decode.py): the single-page decode for a chunk bit-packed at one
+width, the segment-table decode for RLE runs and widths that differ by page.
 The parquet dictionary page maps 1:1 onto the engine's own dictionary-encoded
 string representation, so a string column never materializes per-row bytes.
 
 Scope: UNCOMPRESSED / SNAPPY / GZIP / ZSTD chunks (compressed page bodies
 decompress on host through arrow's C codecs — stage 1.5; the reference uses
-nvcomp on GPU), RLE_DICTIONARY-encoded data pages (v1), flat schemas,
+nvcomp on GPU), RLE_DICTIONARY-encoded data pages (v1 and v2), flat schemas,
 physical types INT32/INT64/FLOAT/DOUBLE/BYTE_ARRAY. Anything else falls
 back to the arrow decode path per column chunk.
 """
@@ -180,6 +182,10 @@ def parse_rle_hybrid(buf: bytes, pos: int, end: int, bit_width: int,
     return segs
 
 
+# a page's segments as the scan keeps them: int64 (n, 5), one row a segment
+RUN_KIND, RUN_COUNT, RUN_VALUE, RUN_OFF, RUN_LEN = range(5)   # kind: 1=packed
+
+
 def decode_rle_host(buf: bytes, pos: int, end: int, bit_width: int,
                     total: int) -> np.ndarray:
     """Host (numpy-vectorized) hybrid decode — def levels and fallback path."""
@@ -207,8 +213,10 @@ class ChunkPages(typing.NamedTuple):
     physical_type: str
     dict_values: np.ndarray | list      # decoded PLAIN dictionary (host)
     index_segments: list                # per data page: (num_values,
-                                        #   def_levels np | None,
-                                        #   bit_width, packed bytes | np idx)
+                                        #   def_levels np, bit_width,
+                                        #   page bytes, offset of the
+                                        #   bit-width byte, segments
+                                        #   int64 (n, 5): RUN_* columns)
     num_values: int
 
 
@@ -272,11 +280,14 @@ def read_chunk_pages(path: str, row_group: int, column: int,
 
     # fast path: one native C call scans the whole chunk (thrift headers,
     # def-level RLE decode, hybrid segmentation — native/parquet_host.cpp);
-    # the Python loop below is the executable spec, the path for chunks the
-    # native scanner declines, and the compressed-chunk path (bodies must
-    # decompress before scanning). A scanner that cannot be built or loaded
-    # (NativeBuildError, OSError) is an error: the toolchain is part of
-    # this installation.
+    # the Python loop below is the path for chunks the native scanner
+    # declines and the compressed-chunk path (bodies must decompress before
+    # scanning): it walks the pages, and the native scanner still splits each
+    # index stream into its runs (tens of thousands a chunk in a flag
+    # column; `parse_rle_hybrid` is that step's executable spec). A scanner
+    # that cannot be built or loaded (NativeBuildError, OSError) is an
+    # error: the toolchain is part of this installation.
+    from spark_rapids_tpu.native import scan_hybrid_native
     raw_pages = None
     if dec is None:  # compressed bodies must decompress before scanning
         from spark_rapids_tpu.native import scan_chunk_native
@@ -290,10 +301,9 @@ def read_chunk_pages(path: str, row_group: int, column: int,
         dict_vals = _decode_plain_dictionary(
             col.physical_type, buf[d_off:d_off + d_len], d_n)
         pages = []
-        for (nv, dl, bw, values_off, body_off, body_len, _np_, rs) in raw_pages:
+        for (nv, dl, bw, values_off, body_off, body_len, _np_, segs) in \
+                raw_pages:
             page_bytes = buf[body_off:body_off + body_len]
-            segs = [RleSegment("packed" if k == 1 else "rle", c, v, bo, bl)
-                    for (k, c, v, bo, bl) in rs]
             pages.append((nv, dl, bw, page_bytes, values_off, segs))
         return ChunkPages(col.physical_type, dict_vals, pages, col.num_values)
 
@@ -332,8 +342,8 @@ def read_chunk_pages(path: str, row_group: int, column: int,
             bw = page_bytes[p]
             p += 1
             n_present = int(def_levels.sum())
-            segs = parse_rle_hybrid(page_bytes, p, len(page_bytes), bw,
-                                    n_present)
+            segs = scan_hybrid_native(page_bytes, p, len(page_bytes), bw,
+                                      n_present)
             pages.append((ph.num_values, def_levels, bw, page_bytes,
                           p - 1, segs))
             values_seen += ph.num_values
@@ -356,7 +366,7 @@ def read_chunk_pages(path: str, row_group: int, column: int,
                 def_levels = np.ones(ph.num_values, dtype=np.int32)
             bw = data[0]
             n_present = int(def_levels.sum())
-            segs = parse_rle_hybrid(data, 1, len(data), bw, n_present)
+            segs = scan_hybrid_native(data, 1, len(data), bw, n_present)
             pages.append((ph.num_values, def_levels, bw, data, 0, segs))
             values_seen += ph.num_values
         else:
@@ -375,10 +385,10 @@ def _merge_packed_pages(pages: ChunkPages) -> ChunkPages:
     every page but the last filling its packed bytes exactly (a bit-packed
     run holds whole 8-value groups, so it then ends on a value boundary).
     Writers cut a row group's chunk into many pages (pyarrow: 20,000 rows
-    each, ~38 per TPC-H SF 1 chunk); folded, the chunk rides the single
-    fused decode program instead of one eager pipeline per page — on the
-    chip that per-page pipeline re-lowered the Pallas unpack kernel for every
-    page of every run (~27 s per scan batch, hot or cold)."""
+    each, ~38 per TPC-H SF 1 chunk); folded, the chunk rides the
+    single-page decode program, whose expansion a consumer can fuse into
+    itself (``encoded``). A chunk that does not fold takes the segment-table
+    program."""
     if len(pages.index_segments) < 2:
         return pages
     bw0 = pages.index_segments[0][2]
@@ -386,10 +396,9 @@ def _merge_packed_pages(pages: ChunkPages) -> ChunkPages:
     parts, levels, n_values, n_present = [], [], 0, 0
     for i, (nv, dl, bw, page_bytes, _off, segs) in enumerate(
             pages.index_segments):
-        if bw != bw0 or not segs or any(s.kind != "packed" for s in segs):
+        if bw != bw0 or not len(segs) or not segs[:, RUN_KIND].all():
             return pages
-        packed = b"".join(page_bytes[s.byte_off:s.byte_off + s.byte_len]
-                          for s in segs)
+        packed = _packed_bytes(page_bytes, segs)
         present = int(dl.sum())
         if i < last and len(packed) * 8 != present * bw:
             return pages
@@ -398,115 +407,170 @@ def _merge_packed_pages(pages: ChunkPages) -> ChunkPages:
         n_values += nv
         n_present += present
     packed = b"".join(parts)
-    seg = RleSegment("packed", n_present, 0, 0, len(packed))
-    page = (n_values, np.concatenate(levels), bw0, packed, 0, [seg])
+    seg = np.array([[1, n_present, 0, 0, len(packed)]], np.int64)
+    page = (n_values, np.concatenate(levels), bw0, packed, 0, seg)
     return ChunkPages(pages.physical_type, pages.dict_values, [page],
                       pages.num_values)
 
 
+def _packed_bytes(page_bytes: bytes, segs: np.ndarray) -> bytes:
+    """The payload of a page's packed segments, concatenated. Segments each
+    hold whole 8-value groups at byte boundaries: concatenating their BYTES
+    preserves bit alignment."""
+    return b"".join(page_bytes[o:o + n] for k, o, n in
+                    segs[:, (RUN_KIND, RUN_OFF, RUN_LEN)].tolist() if k)
+
+
 def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
                     encoded: bool = False, span=tracing.NO_SPAN):
-    """Decode a parsed chunk into a TpuColumnVector. The common fast path
-    (every hybrid segment bit-packed) unpacks indices ON DEVICE; pages with
-    mixed RLE runs fall back to the host hybrid decode, keeping the
-    dictionary gather on device either way. ``span`` is the caller's
-    ``scan.column`` span: it learns which of the two paths the chunk took
-    and what the chunk was made of."""
+    """Decode a parsed chunk into a TpuColumnVector: host preparation, the
+    upload of its buffers, and ONE fused program, whatever its hybrid
+    segments are. Which program is read from the chunk: every segment
+    bit-packed at one width (after `_merge_packed_pages`) → the single-page
+    decode, which ``encoded`` may defer into the consumer; RLE runs, or pages
+    of different bit widths → the segment-table decode, always dense.
+    ``span`` is the caller's ``scan.column`` span: it learns which of the two
+    the chunk took and what the chunk was made of."""
     import jax.numpy as jnp
-    from spark_rapids_tpu import types as T
-    from spark_rapids_tpu.columnar.vector import TpuColumnVector
-    from spark_rapids_tpu.ops import parquet_decode as PD
 
-    is_string = pages.physical_type == "BYTE_ARRAY"
-    sorted_dict = None
-    if is_string:
+    if pages.physical_type == "BYTE_ARRAY":
         # parquet dictionary == the engine's string dictionary, sorted for
         # order-preserving codes (columnar/arrow.py design)
         from spark_rapids_tpu.ops.strings import sorted_dict_and_rank
-        sorted_dict, rank = sorted_dict_and_rank(pages.dict_values)
-        dict_dev = jnp.asarray(rank)        # parquet idx -> sorted code
-    else:
-        dict_dev = jnp.asarray(np.asarray(pages.dict_values))
-    from spark_rapids_tpu.columnar.vector import bucket_capacity
+        sorted_dict, dict_host = sorted_dict_and_rank(pages.dict_values)
+    else:                                   # dict_host: parquet idx -> value
+        sorted_dict, dict_host = None, np.asarray(pages.dict_values)
 
     if span:
-        segs = [s for p in pages.index_segments for s in p[5]]
-        span.set(pages=len(pages.index_segments),
-                 packed=sum(s.kind == "packed" for s in segs),
-                 rle=sum(s.kind != "packed" for s in segs),
+        segs = sum(len(p[5]) for p in pages.index_segments)
+        packed = sum(int(p[5][:, RUN_KIND].sum())
+                     for p in pages.index_segments)
+        span.set(pages=len(pages.index_segments), packed=packed,
+                 rle=segs - packed,
                  encoded_bytes=sum(len(p[3]) for p in pages.index_segments))
     pages = _merge_packed_pages(pages)
-    # fast path: ONE data page, all-packed index segments → a single fused
-    # program (unpack + dict gather + null spread + canonicalize). The eager
-    # per-page pipeline below cost ~25 XLA dispatches per chunk — at TPC-H
-    # scan width that dominated hot-query wall time on XLA:CPU.
+    span.set(path="fused")
     if len(pages.index_segments) == 1:
-        (num_values, def_levels, bw, page_bytes, values_off, segs) = \
+        (num_values, def_levels, bw, page_bytes, _off, segs) = \
             pages.index_segments[0]
-        if segs and all(s.kind == "packed" for s in segs):
-            packed = b"".join(page_bytes[s.byte_off:s.byte_off + s.byte_len]
-                              for s in segs)
-            span.set(path="fused")
+        if len(segs) and segs[:, RUN_KIND].all():
+            span.set(decode="packed", segments=1)
             return _decode_single_page_fused(
-                packed, bw, def_levels, dict_dev, num_values, capacity,
-                pages, spark_type, sorted_dict, encoded=encoded)
+                _packed_bytes(page_bytes, segs), bw, def_levels,
+                jnp.asarray(dict_host), num_values, capacity, pages,
+                spark_type, sorted_dict, encoded=encoded)
+    return _decode_runs_fused(pages, dict_host, capacity, spark_type,
+                              sorted_dict, span)
 
-    # page by page: some twenty-five eager device calls a page
-    span.set(path="pages")
-    all_vals, all_valid = [], []
-    for (num_values, def_levels, bw, page_bytes, values_off, segs) in \
-            pages.index_segments:
-        with tracing.span("scan.page", values=num_values):
-            pcap = bucket_capacity(max(num_values, 1))
-            n_present = int(def_levels.sum())
-            if segs and all(s.kind == "packed" for s in segs):
-                # segments each hold whole 8-value groups at byte boundaries:
-                # concatenating their BYTES preserves bit alignment
-                packed = b"".join(
-                    page_bytes[s.byte_off:s.byte_off + s.byte_len]
-                    for s in segs)
-                vals, valid = PD.decode_dictionary_page(
-                    np.frombuffer(packed, np.uint8), bw, n_present,
-                    def_levels, dict_dev, pcap)
-            else:
-                idx = decode_rle_host(page_bytes, values_off + 1,
-                                      len(page_bytes), bw, n_present) \
-                    if segs else np.zeros(0, np.int32)
-                nd = int(dict_dev.shape[0])
-                idx_d = jnp.zeros((pcap,), jnp.int32).at[:len(idx)].set(
-                    jnp.asarray(np.clip(idx, 0, max(nd - 1, 0))))
-                # an all-null page may carry an EMPTY dictionary — nothing to
-                # gather, every slot is the canonical default
-                present = dict_dev[idx_d] if nd else jnp.zeros((pcap,),
-                                                               dict_dev.dtype)
-                dl = jnp.zeros((pcap,), jnp.bool_).at[:len(def_levels)].set(
-                    jnp.asarray(def_levels.astype(bool)))
-                vals, valid = PD.expand_present_to_rows(present, dl, pcap)
-        all_vals.append(vals[:num_values])
-        all_valid.append(valid[:num_values])
 
-    vals = jnp.concatenate(all_vals) if len(all_vals) > 1 else all_vals[0]
-    valid = jnp.concatenate(all_valid) if len(all_valid) > 1 else all_valid[0]
-    n = pages.num_values
-    out_v = jnp.zeros((capacity,), vals.dtype).at[:n].set(vals[:n])
-    out_m = jnp.zeros((capacity,), jnp.bool_).at[:n].set(valid[:n])
+def _segment_table(pages: ChunkPages):
+    """A chunk's parsed pages → (uint8 packed bytes of every packed segment,
+    concatenated; int32 (4, rows) segment table as
+    ops/parquet_decode.unpack_runs_device reads it; widest bit width).
+    A segment whose row would repeat the row before it — a packed run whose
+    bytes continue the previous run's exactly, as a writer's 504-value runs
+    do — adds none."""
+    per_page = [p[5] for p in pages.index_segments]
+    segs = (np.concatenate(per_page) if per_page
+            else np.zeros((0, 5), np.int64))
+    n_segs = [len(s) for s in per_page]
+    bw = np.repeat(np.array([p[2] for p in pages.index_segments], np.int64),
+                   n_segs)
+    base = np.cumsum([0] + [len(p[3]) for p in pages.index_segments])[:-1]
+    off = np.repeat(base, n_segs) + segs[:, RUN_OFF]      # in all pages' bytes
+    live = segs[:, RUN_COUNT] > 0
+    segs, bw, off = segs[live], bw[live], off[live]
 
-    if is_string:
-        # canonical-null invariant (columnar/vector.py:10): invalid slots
-        # hold code 0, never rank-gather residue — group-by compares raw
-        # codes (ops/grouping.py)
-        codes = jnp.where(out_m, out_v.astype(jnp.int32), 0)
-        cv = TpuColumnVector(T.STRING, codes, out_m)
-        return cv.with_dictionary(sorted_dict)
+    packed = (segs[:, RUN_KIND] == 1) & (bw > 0)
+    count = segs[:, RUN_COUNT]
+    start = np.cumsum(count) - count
+    nbytes = np.where(packed, segs[:, RUN_LEN], 0)
+    before = np.cumsum(nbytes) - nbytes
+    rows = np.stack([start,
+                     np.where(packed, bw, 0),
+                     np.where(packed, before * 8 - start * bw, 0),
+                     np.where(packed, 0, segs[:, RUN_VALUE])])
+    keep = np.ones(rows.shape[1], bool)
+    keep[1:] = (rows[1:, 1:] != rows[1:, :-1]).any(axis=0)
+    # the bytes: byte j of the output is byte (j - before + off) of its run
+    every = np.frombuffer(b"".join(p[3] for p in pages.index_segments),
+                          np.uint8)
+    take = np.arange(int(nbytes.sum())) + np.repeat(off - before, nbytes)
+    # an RLE value is an unsigned bw-bit pattern; the table carries its bits
+    table = rows[:, keep].astype(np.uint32).view(np.int32)
+    return every[take], table, int(rows[1].max(initial=0))
+
+
+def _type_facts(pages: ChunkPages, spark_type):
+    """(spark type, decoded dtype, canonical default) of a chunk's column."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    if pages.physical_type == "BYTE_ARRAY":
+        return T.STRING, jnp.dtype(jnp.int32), 0
     np_to_spark = {"INT32": T.INT, "INT64": T.LONG,
                    "FLOAT": T.FLOAT, "DOUBLE": T.DOUBLE}
     st = spark_type or np_to_spark[pages.physical_type]
-    want = st.jnp_dtype
-    if out_v.dtype != jnp.dtype(want):
-        out_v = out_v.astype(want)
-    default = jnp.asarray(st.default_value(), out_v.dtype)
-    out_v = jnp.where(out_m, out_v, default)
-    return TpuColumnVector(st, out_v, out_m)
+    return st, jnp.dtype(st.jnp_dtype), st.default_value()
+
+
+def _padded(a: np.ndarray, cap: int) -> np.ndarray:
+    """`a` along its last axis, padded with zeros (or cut) to `cap`."""
+    out = np.zeros(a.shape[:-1] + (cap,), a.dtype)
+    n = min(a.shape[-1], cap)
+    out[..., :n] = a[..., :n]
+    return out
+
+
+def _decode_runs_fused(pages: ChunkPages, dict_host, capacity: int,
+                       spark_type, sorted_dict, span):
+    """One jitted program per (widest bit width, shape buckets, output
+    type) for a chunk with RLE runs or pages of different bit widths:
+    segment lookup → per-element bit-unpack → dictionary gather →
+    definition-level spread → canonical nulls
+    (ops/parquet_decode.decode_runs_cols). Table, bytes and dictionary pad
+    to their buckets so chunks share programs."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.vector import (TpuColumnVector,
+                                                  bucket_capacity)
+    from spark_rapids_tpu.ops import parquet_decode as PD
+    from spark_rapids_tpu.runtime import fuse
+
+    packed, table, max_bw = _segment_table(pages)
+    def_levels = (np.concatenate([p[1] for p in pages.index_segments])
+                  if pages.index_segments else np.zeros(0, np.int32))
+    n_present = int(def_levels.sum())
+    st, want, default = _type_facts(pages, spark_type)
+    pcap = bucket_capacity(max(n_present, 1))
+    scap = bucket_capacity(table.shape[1])
+    spec = PD.EncodedRunsSpec(
+        max_bw, scap, pcap, bucket_capacity(max(len(packed), 1)), capacity,
+        str(want), sorted_dict is not None, default)
+    span.set(decode="runs", segments=table.shape[1])
+    table_h = _padded(table, scap)
+    # rows past the last segment start beyond every position, each at its own
+    table_h[PD.SEG_START, table.shape[1]:] = \
+        pcap + np.arange(scap - table.shape[1], dtype=np.int32)
+    n = min(pages.num_values, capacity)
+    args = (jnp.asarray(_padded(packed, spec.bcap)),
+            jnp.asarray(table_h),
+            jnp.asarray(_padded(dict_host,
+                                bucket_capacity(len(dict_host)))),
+            jnp.asarray(_padded(def_levels.astype(bool), capacity)),
+            # 0-d array: a Python or NumPy scalar is converted by an eager
+            # program of its own
+            jnp.asarray(np.asarray(n, np.int32)))
+
+    def build():
+        def kernel(packed_d, table_d, dict_d, dl_d, n_t):
+            return PD.decode_runs_cols(spec, packed_d, table_d, dict_d, dl_d,
+                                       n_t)
+        return kernel
+
+    key = ("pq_runs_decode", spec)
+    v, m = fuse.call_fused(key, "ParquetScan.decode_runs", build, args,
+                           lambda: build()(*args))
+    cv = TpuColumnVector(st, v, m)
+    return cv.with_dictionary(sorted_dict) if spec.is_string else cv
 
 
 def _page_spec_and_args(packed: bytes, bw: int, def_levels, dict_dev,
@@ -516,42 +580,31 @@ def _page_spec_and_args(packed: bytes, bw: int, def_levels, dict_dev,
     (packed, dict, def-levels, n_present, n). The ONE place page bytes become
     device buffers, so both paths upload identical payloads."""
     import jax.numpy as jnp
-    from spark_rapids_tpu import types as T
     from spark_rapids_tpu.columnar.vector import bucket_capacity
     from spark_rapids_tpu.ops import parquet_decode as PD
     from spark_rapids_tpu.ops import pallas_kernels as PK
 
-    is_string = pages.physical_type == "BYTE_ARRAY"
     n_present = int(def_levels.sum())
     pcap = max(bucket_capacity(max(n_present, 1)), 8)
     bcap = max(bucket_capacity(max(len(packed), 1)), 8)
     use_pallas = PK.should_use("bitunpack")     # probe OUTSIDE the traced program
-
-    np_to_spark = {"INT32": T.INT, "INT64": T.LONG,
-                   "FLOAT": T.FLOAT, "DOUBLE": T.DOUBLE}
-    st = T.STRING if is_string else (spark_type
-                                     or np_to_spark[pages.physical_type])
-    want = jnp.dtype(jnp.int32) if is_string else jnp.dtype(st.jnp_dtype)
-    default = 0 if is_string else st.default_value()
+    st, want, default = _type_facts(pages, spark_type)
     # n_present is only STATIC under pallas (tile shapes); zeroing it
     # otherwise keeps the non-pallas compile cache shared across present
     # counts, exactly like the pre-spec key did
-    spec = PD.EncodedPageSpec(bw, pcap, bcap, capacity, str(want), is_string,
-                              default, use_pallas,
-                              n_present if use_pallas else 0)
-    if use_pallas:
-        words = PK.bytes_to_words_u32(np.frombuffer(packed, np.uint8))
-        packed_in = jnp.asarray(words)
-    else:
-        ph = np.zeros(bcap, np.uint8)
-        ph[:len(packed)] = np.frombuffer(packed, np.uint8)
-        packed_in = jnp.asarray(ph)
-    dh = np.zeros(capacity, bool)
-    nd_lv = min(len(def_levels), capacity)
-    dh[:nd_lv] = def_levels[:nd_lv].astype(bool)
+    spec = PD.EncodedPageSpec(bw, pcap, bcap, capacity, str(want),
+                              pages.physical_type == "BYTE_ARRAY", default,
+                              use_pallas, n_present if use_pallas else 0)
+    packed_h = np.frombuffer(packed, np.uint8)
+    packed_in = jnp.asarray(PK.bytes_to_words_u32(packed_h) if use_pallas
+                            else _padded(packed_h, bcap))
     n = min(num_values, pages.num_values, capacity)
-    args = (packed_in, dict_dev, jnp.asarray(dh),
-            jnp.asarray(n_present, jnp.int32), jnp.asarray(n, jnp.int32))
+    args = (packed_in, dict_dev,
+            jnp.asarray(_padded(def_levels.astype(bool), capacity)),
+            # 0-d arrays: a Python or NumPy scalar is converted by an eager
+            # program of its own
+            jnp.asarray(np.asarray(n_present, np.int32)),
+            jnp.asarray(np.asarray(n, np.int32)))
     return spec, st, args
 
 
@@ -628,7 +681,7 @@ def read_row_group_device(path: str, row_group: int, schema,
     for name in want:
         sf = schema[name] if schema is not None else None
         # one span a column chunk, with the path it took: fused (one
-        # program), pages (page by page, eager) or fallback (pyarrow)
+        # program, of which `decode` says which) or fallback (pyarrow)
         with tracing.span("scan.column", column=name) as sp:
             try:
                 if name not in leaf_of:

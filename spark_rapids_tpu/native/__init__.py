@@ -110,6 +110,13 @@ def parquet_lib():
             ctypes.c_void_p, ctypes.c_int64,        # def_levels, cap
             ctypes.c_void_p,                        # dict_out[3]
         ]
+        lib.sr_scan_hybrid.restype = ctypes.c_int64
+        lib.sr_scan_hybrid.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,        # page, page_len
+            ctypes.c_int64, ctypes.c_int64,         # pos, end
+            ctypes.c_int64, ctypes.c_int64,         # bit_width, total
+            ctypes.c_void_p, ctypes.c_int64,        # segs, cap
+        ]
         _pq_lib = lib
         return _pq_lib
 
@@ -118,12 +125,32 @@ _PAGE_FIELDS = 9   # int64 per SrPage (see parquet_host.cpp)
 _SEG_FIELDS = 5    # int64 per SrSeg
 
 
+def scan_hybrid_native(page: bytes, pos: int, end: int, bit_width: int,
+                       total: int):
+    """The run structure of the hybrid stream page[pos:end] holding `total`
+    values: int64 (n, 5) rows of (kind 1=packed/0=rle, count, value,
+    byte_off, byte_len), as io/parquet_native.parse_rle_hybrid lists them."""
+    import numpy as np
+    end = min(end, len(page))
+    # a run takes a header byte at least, and value or payload bytes if the
+    # width is not 0
+    cap = max(end - pos, 0) // (2 if bit_width else 1) + 1
+    segs = np.zeros((cap, _SEG_FIELDS), np.int64)
+    n = parquet_lib().sr_scan_hybrid(page, len(page), pos, end, bit_width,
+                                     total, segs.ctypes.data, cap)
+    if n < 0:
+        raise NotImplementedError(
+            f"native parquet scan: {_SR_ERRORS.get(int(n), n)}")
+    return segs[:n]
+
+
 def scan_chunk_native(buf: bytes, num_values: int, max_def: int):
     """One native call over a column-chunk buffer → (pages, dict_info).
 
     pages: list of (num_values, def_levels[np.int32], bit_width, values_off,
-                    body_off, body_len, n_present, segs) with segs
-                    page-relative (kind, count, value, byte_off, byte_len);
+                    body_off, body_len, n_present, segs) with segs int64
+                    (n, 5) rows of page-relative
+                    (kind, count, value, byte_off, byte_len);
     dict_info: (body_off, body_len, num_values).
     Raises NotImplementedError for out-of-stage-one chunks (same contract as
     the Python parser in io/parquet_native.py).
@@ -153,11 +180,9 @@ def scan_chunk_native(buf: bytes, num_values: int, max_def: int):
         for i in range(int(n)):
             (nv, def_off, n_present, bw, body_off, body_len, values_off,
              seg_off, seg_count) = (int(v) for v in pages_buf[i])
-            segs = [(int(k), int(c), int(v), int(bo), int(bl))
-                    for k, c, v, bo, bl in segs_buf[seg_off:seg_off + seg_count]]
             def_levels = def_buf[def_off:def_off + nv].copy()
             pages.append((nv, def_levels, bw, values_off, body_off, body_len,
-                          n_present, segs))
+                          n_present, segs_buf[seg_off:seg_off + seg_count]))
         return pages, (int(dict_buf[0]), int(dict_buf[1]), int(dict_buf[2]))
     raise NotImplementedError(
         "native parquet scan: segment/page capacity never converged "

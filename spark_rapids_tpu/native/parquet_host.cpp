@@ -209,6 +209,18 @@ static int64_t scan_hybrid(const uint8_t* page, int64_t page_len, int64_t pos,
     return got;
 }
 
+// The run structure of one hybrid index stream, page[pos, end): for a page
+// body that was decompressed on the host. Returns the segment count or a
+// negative SR_ERR_* code.
+int64_t sr_scan_hybrid(const uint8_t* page, int64_t page_len, int64_t pos,
+                       int64_t end, int64_t bit_width, int64_t total,
+                       SrSeg* segs, int64_t segs_cap) {
+    int64_t n_segs = 0;
+    int64_t got = scan_hybrid(page, page_len, pos, end, bit_width, total,
+                              segs, segs_cap, &n_segs, nullptr);
+    return got < 0 ? got : n_segs;
+}
+
 // Scan one UNCOMPRESSED dictionary-encoded column chunk buffer.
 // Returns the page count (>= 0) or a negative SR_ERR_* code.
 // dict_out = {body_off, body_len, num_values}.

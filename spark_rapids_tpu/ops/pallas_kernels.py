@@ -239,9 +239,9 @@ def bitunpack128(words_u32, bit_width: int, n: int, capacity: int):
 
     words_u32: (ceil(n/128)*4*bw,) int32 — packed little-endian words.
 
-    Jitted on its static arguments: an eager caller (the per-page decode
-    loop) then compiles once per shape, where a bare eager pallas_call is
-    re-lowered by Mosaic on every call.
+    Jitted on its static arguments: an eager caller then compiles once per
+    shape, where a bare eager pallas_call is re-lowered by Mosaic on every
+    call.
     """
     if not 1 <= bit_width <= 32:
         raise ValueError(f"bit width {bit_width} out of range")

@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import typing
 
-import numpy as np
 import jax.numpy as jnp
 from spark_rapids_tpu.ops.windowing import cumsum
 
@@ -69,27 +68,70 @@ def expand_present_to_rows(present_vals: jnp.ndarray,
     return vals, valid
 
 
-def decode_page_cols(spec: EncodedPageSpec, packed_d, dict_d, dl_d,
-                     n_present_t, n_t):
-    """TRACEABLE single-page decode: bit-unpack → dictionary gather →
-    definition-level spread → canonical nulls, returning (values, validity)
-    at spec.capacity. This is the single source of truth for page expansion —
-    the standalone fused decode kernel (io/parquet_native.py) and the
-    encoded-upload consumers (columnar/encoded.py, exec/aggregate.py) all
-    trace THIS body, so encoded-vs-dense results are bit-identical by
-    construction. Device args: packed bytes (or pallas words), the device
-    dictionary, def-levels as bool (capacity,), and int32 scalars for the
-    present/live counts."""
+class EncodedRunsSpec(typing.NamedTuple):
+    """Static facts of one chunk decoded from its segment table
+    (`decode_runs_cols`): the hybrid index streams of all its pages, RLE runs
+    and bit-packed runs of any widths. Hashable like EncodedPageSpec; two
+    chunks with the same spec share one compiled program whatever their
+    segments are."""
+    max_bw: int        # widest page: bounds the bytes that cover a value
+    scap: int          # segment-table capacity bucket
+    pcap: int          # present-value capacity bucket
+    bcap: int          # packed-byte capacity bucket
+    capacity: int      # output row capacity bucket
+    want: str          # decoded value dtype name (int32 codes for strings)
+    is_string: bool
+    default: object    # canonical fill for invalid slots
+
+
+# rows of the segment table (int32 (4, scap)), one column a segment
+SEG_START, SEG_BW, SEG_BIT0, SEG_VALUE = range(4)
+
+
+def unpack_runs_device(packed: jnp.ndarray, table: jnp.ndarray, max_bw: int,
+                       capacity: int) -> jnp.ndarray:
+    """(bytes,) uint8 + (4, scap) int32 segment table → (capacity,) int32
+    indices of a whole chunk's RLE / bit-packed hybrid streams.
+
+    Segment s covers present-value positions [start[s], start[s+1]). A packed
+    segment holds value i at bits [bit0 + i*bw, +bw) of `packed` (bit0 is
+    byte_off*8 - start*bw, so consecutive runs whose bytes are contiguous are
+    one row); an RLE segment has bw 0 — nothing to read — and its repeated
+    value in the value row, which is 0 on packed rows. Rows past the last
+    segment start at `capacity` or beyond. The segment of a position is a
+    prefix count of the starts at or before it; the bit width is then a
+    per-element operand of the same shift-and-mask as `unpack_bits_device`,
+    over as many covering bytes as the widest page needs."""
+    pos = jnp.arange(capacity, dtype=jnp.int32)
+    marks = jnp.zeros((capacity,), jnp.int32).at[table[SEG_START]].add(
+        1, mode="drop", indices_are_sorted=True, unique_indices=True)
+    seg = jnp.clip(cumsum(marks) - 1, 0, table.shape[1] - 1)
+    # gathers of whole columns, one for the table and one for the bytes: on
+    # the chip a 1-D gather of as many elements takes three times as long
+    # (PERF.md, PR 27)
+    row = table[:, seg]
+    bw = row[SEG_BW]
+    bit0 = row[SEG_BIT0] + pos * bw
+    shift = (bit0 & 7).astype(jnp.int64)
+    # the bytes that cover a value, a row a byte: the buffer beside itself
+    # shifted by one, two .. bytes
+    nbytes, cover = packed.shape[0], (max_bw + 14) // 8
+    beyond = jnp.concatenate([packed, jnp.zeros((cover,), packed.dtype)])
+    covering = jnp.stack([beyond[k:k + nbytes] for k in range(cover)])[
+        :, jnp.clip(bit0 >> 3, 0, nbytes - 1)]
+    window = jnp.zeros((capacity,), jnp.int64)
+    for k in range(cover):
+        window = window | (covering[k].astype(jnp.int64) << (8 * k))
+    mask = (jnp.int64(1) << bw.astype(jnp.int64)) - 1
+    vals = ((window >> shift) & mask).astype(jnp.int32)
+    return vals | row[SEG_VALUE]
+
+
+def _indices_to_rows(spec, idx, dict_d, dl_d, n_t):
+    """The tail every dictionary decode shares: (pcap,) indices of the
+    present values → dictionary gather → definition-level spread → live mask
+    → canonical nulls, at spec.capacity."""
     want = jnp.dtype(spec.want)
-    if spec.use_pallas:
-        from spark_rapids_tpu.ops import pallas_kernels as PK
-        # pallas tile shapes need the STATIC present count (part of the spec,
-        # hence part of every cache key that embeds the spec)
-        idx = PK.bitunpack128(packed_d, spec.bit_width, spec.n_present,
-                              spec.pcap)
-    else:
-        idx = unpack_bits_device(packed_d, spec.bit_width, n_present_t,
-                                 spec.pcap)
     nd = dict_d.shape[0]
     # an all-null page may carry an EMPTY dictionary: nothing to gather
     present = (dict_d[jnp.clip(idx, 0, max(nd - 1, 0))] if nd
@@ -104,30 +146,35 @@ def decode_page_cols(spec: EncodedPageSpec, packed_d, dict_d, dl_d,
     return v, m
 
 
-def decode_dictionary_page(packed_bytes: np.ndarray, bit_width: int,
-                           n_present: int, def_levels: np.ndarray,
-                           dict_values: jnp.ndarray, capacity: int):
-    """One data page → (values, validity) padded to capacity. The packed
-    index bytes and the dictionary live on device; run structure was already
-    validated host-side (single bit-packed region — parse_rle_hybrid)."""
-    from spark_rapids_tpu.columnar.vector import bucket_capacity
-    from spark_rapids_tpu.ops import pallas_kernels as PK
-    pcap = max(bucket_capacity(n_present), 8)
-    if PK.should_use("bitunpack"):
-        words = PK.bytes_to_words_u32(np.asarray(packed_bytes, np.uint8))
-        idx = PK.bitunpack128(jnp.asarray(words), bit_width, n_present, pcap)
+def decode_page_cols(spec: EncodedPageSpec, packed_d, dict_d, dl_d,
+                     n_present_t, n_t):
+    """TRACEABLE single-page decode: bit-unpack → dictionary gather →
+    definition-level spread → canonical nulls, returning (values, validity)
+    at spec.capacity. This is the single source of truth for page expansion —
+    the standalone fused decode kernel (io/parquet_native.py) and the
+    encoded-upload consumers (columnar/encoded.py, exec/aggregate.py) all
+    trace THIS body, so encoded-vs-dense results are bit-identical by
+    construction. Device args: packed bytes (or pallas words), the device
+    dictionary, def-levels as bool (capacity,), and int32 scalars for the
+    present/live counts."""
+    if spec.use_pallas:
+        from spark_rapids_tpu.ops import pallas_kernels as PK
+        # pallas tile shapes need the STATIC present count (part of the spec,
+        # hence part of every cache key that embeds the spec)
+        idx = PK.bitunpack128(packed_d, spec.bit_width, spec.n_present,
+                              spec.pcap)
     else:
-        packed_d = jnp.zeros((max(len(packed_bytes), 1),), jnp.uint8
-                             ).at[:len(packed_bytes)].set(
-            jnp.asarray(packed_bytes, dtype=jnp.uint8))
-        idx = unpack_bits_device(packed_d, bit_width, n_present, pcap)
-    nd = dict_values.shape[0]
-    present = dict_values[jnp.clip(idx, 0, max(nd - 1, 0))]
-    dl = jnp.zeros((capacity,), jnp.bool_).at[:len(def_levels)].set(
-        jnp.asarray(def_levels.astype(bool)))
-    # pad present values out to capacity before the rank gather (pcap <=
-    # capacity: n_present <= num_values and capacity is the row bucket)
-    present_padded = jnp.zeros((capacity,), present.dtype
-                               ).at[:pcap].set(present)
-    vals, valid = expand_present_to_rows(present_padded, dl, capacity)
-    return vals, valid
+        idx = unpack_bits_device(packed_d, spec.bit_width, n_present_t,
+                                 spec.pcap)
+    return _indices_to_rows(spec, idx, dict_d, dl_d, n_t)
+
+
+def decode_runs_cols(spec: EncodedRunsSpec, packed_d, table_d, dict_d, dl_d,
+                     n_t):
+    """TRACEABLE whole-chunk decode for a chunk whose index streams are not
+    one bit-packed run: indices from the segment table
+    (`unpack_runs_device`), then `decode_page_cols`'s own tail. Device args:
+    the concatenated packed bytes, the (4, scap) segment table, the device
+    dictionary, def-levels as bool (capacity,), the live row count."""
+    idx = unpack_runs_device(packed_d, table_d, spec.max_bw, spec.pcap)
+    return _indices_to_rows(spec, idx, dict_d, dl_d, n_t)

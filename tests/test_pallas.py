@@ -116,7 +116,7 @@ def test_pallas_dispatch_through_partitioning(monkeypatch):
 
 
 def test_pallas_dispatch_through_parquet_decode(tmp_path):
-    """Forced-on dispatch through decode_dictionary_page equals forced-off."""
+    """decode_page_cols with the Pallas unpack in its spec equals without."""
     rng = np.random.default_rng(3)
     dict_vals = jnp.asarray(rng.integers(0, 1000, 32), dtype=jnp.int64)
     n = 100
@@ -128,17 +128,22 @@ def test_pallas_dispatch_through_parquet_decode(tmp_path):
             bit = i * bw + b
             if (v >> b) & 1:
                 buf[bit >> 3] |= 1 << (bit & 7)
-    dl = np.ones(n, dtype=np.int32)
+    dl = jnp.arange(128) < n
+    count = jnp.asarray(n, jnp.int32)
 
-    PK.set_mode(True)
-    try:
-        v1, m1 = PD.decode_dictionary_page(buf, bw, n, dl, dict_vals, 128)
-    finally:
-        PK.set_mode(False)
-    v2, m2 = PD.decode_dictionary_page(buf, bw, n, dl, dict_vals, 128)
-    PK.set_mode(None)
+    def decode(pallas):
+        spec = PD.EncodedPageSpec(bw, 128, 0 if pallas else len(buf), 128,
+                                  "int64", False, 0, pallas,
+                                  n if pallas else 0)
+        packed = PK.bytes_to_words_u32(buf) if pallas else buf
+        return PD.decode_page_cols(spec, jnp.asarray(packed), dict_vals, dl,
+                                   count, count)
+
+    v1, m1 = decode(True)
+    v2, m2 = decode(False)
     assert (np.asarray(v1) == np.asarray(v2)).all()
     assert (np.asarray(m1) == np.asarray(m2)).all()
+    assert np.asarray(v2)[:n].tolist() == np.asarray(dict_vals)[idx].tolist()
 
 
 def test_onehot_sum_matches_numpy():

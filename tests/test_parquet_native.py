@@ -2,6 +2,8 @@
 hybrid, device bit-unpack + dictionary gather, per-column arrow fallback
 (reference GpuParquetScan.scala:1235 device decode role)."""
 
+import functools
+
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -205,7 +207,7 @@ def test_native_scanner_matches_python_parser(tmp_path, monkeypatch):
             assert nv_n == nv_p and bw_n == bw_p and vo_n == vo_p
             assert pb_n == pb_p
             assert (dl_n == dl_p).all()
-            assert segs_n == segs_p
+            assert np.array_equal(segs_n, segs_p)
 
 
 @pytest.mark.parametrize("codec", ["NONE", "snappy"])
@@ -228,9 +230,10 @@ def test_v2_data_pages_device_path(tmp_path, codec):
 
 def test_multi_page_chunks_fold_into_one_page(tmp_path):
     """A chunk cut into many all-packed pages of one bit width folds into
-    ONE page (so it rides the single fused decode program, compiled once),
-    and decodes to the same rows; a page whose present count is not a whole
-    number of 8-value groups keeps the chunk on the per-page path."""
+    ONE page (so it rides the single-page decode program, which a consumer
+    can fuse into itself), and decodes to the same rows; a page whose present
+    count is not a whole number of 8-value groups is not folded, and the
+    chunk takes the segment-table program: one program either way."""
     n = 40000
     r = np.random.default_rng(3)
     t = pa.table({
@@ -252,10 +255,223 @@ def test_multi_page_chunks_fold_into_one_page(tmp_path):
         folded[name] = len(merged.index_segments) == 1
         if folded[name]:
             nv, dl, _bw, _packed, _off, segs = merged.index_segments[0]
-            assert nv == n and len(dl) == n and segs[0].count == int(dl.sum())
+            assert nv == n and len(dl) == n
+            assert segs[:, PN.RUN_COUNT].tolist() == [int(dl.sum())]
     assert folded["q"] and folded["s"]
     assert not folded["nul"]        # present counts not multiples of 8
     schema = T.StructType.from_arrow(t.schema)
-    out = PN.read_row_group_device(f, 0, schema).to_arrow()
+    out, cols = _read_traced(f, 0, schema)
     for name in t.column_names:
         assert out.column(name).to_pylist() == t.column(name).to_pylist(), name
+    assert {c: s["decode"] for c, s in cols.items()} == {
+        "q": "packed", "s": "packed", "nul": "runs"}
+    assert all(s["path"] == "fused" for s in cols.values())
+    # a table row a page, but where a page happens to end on a group
+    assert 1 < cols["nul"]["segments"] <= cols["nul"]["pages"]
+    assert cols["nul"]["packed"] > cols["nul"]["pages"]
+
+
+# -- the segment-table decode: RLE runs, bit widths that differ by page -------
+
+def _read_traced(f, rg, schema):
+    """(arrow table, {column: counts of its scan.column span}) of one row
+    group through the device decode; no chunk may open a scan.page span."""
+    from spark_rapids_tpu.runtime import tracing
+    tracing.drain()
+    tracing.set_enabled(True)
+    try:
+        out = PN.read_row_group_device(f, rg, schema).to_arrow()
+        spans = tracing.recorded()
+    finally:
+        tracing.set_enabled(False)
+        tracing.drain()
+    assert {s["name"] for s in spans} == {"scan.column"}
+    return out, {s["counts"]["column"]: s["counts"] for s in spans}
+
+
+def _with_runs(r, n, card, runs=6):
+    """Random values below `card` with `runs` stretches of one value, each a
+    few 8-value groups long at least: the hybrid encoder writes RLE there."""
+    v = r.integers(0, card, n)
+    for at in r.integers(0, max(n - 600, 1), runs):
+        v[at:at + int(r.integers(64, 600))] = r.integers(0, card)
+    return v
+
+
+def _growing_keys(r, n_keys):
+    """Ascending keys, each one to seven times, as l_orderkey is: the
+    dictionary grows through the row group, and the bit width with it."""
+    return np.repeat(np.arange(n_keys, dtype=np.int64) * 4 + 1,
+                     r.integers(1, 8, n_keys))
+
+
+@functools.lru_cache(maxsize=None)
+def _runs_cases():
+    r = np.random.default_rng(27)
+    n = 30000
+    nulls = _with_runs(r, n, 5).astype(object)
+    nulls[::13] = None
+    nulls[4000:4300] = None            # a run of nulls between value runs
+    words = np.array([f"w{i:02d}" for i in range(12)])
+    mixed = {
+        "s": pa.array(words[_with_runs(r, n, 12)]),
+        "l": pa.array(_with_runs(r, n, 7).astype(np.int64) * 10**10 - 5),
+        "d": pa.array(_with_runs(r, n, 40) / 8.0),
+        "nul": pa.array(list(nulls), pa.int32()),
+    }
+    return {
+        # name: (columns, write options, columns that must decode by runs)
+        "bw1": ({"x": pa.array(_with_runs(r, n, 2), pa.int32())}, {}, "x"),
+        "bw2": ({"x": pa.array(_with_runs(r, n, 3), pa.int32())}, {}, "x"),
+        "bw4": ({"x": pa.array(_with_runs(r, n, 11), pa.int32())}, {}, "x"),
+        "constant": ({"x": pa.array(np.full(70000, 7), pa.int32())}, {}, "x"),
+        "growing_bit_width": (
+            {"k": pa.array(_growing_keys(r, 100000))}, {}, "k"),
+        "nulls_between_runs": ({"nul": mixed["nul"]}, {}, "nul"),
+        "string": ({"s": mixed["s"]}, {}, "s"),
+        "int64": ({"l": mixed["l"]}, {}, "l"),
+        "double": ({"d": mixed["d"]}, {}, "d"),
+        "all_null": ({"x": pa.array([None] * 3000, pa.int32()),
+                      "s": pa.array([None] * 3000, pa.string())}, {}, "xs"),
+        "page_v2": (mixed, {"data_page_version": "2.0"}, "sld"),
+        "snappy": (mixed, {"compression": "SNAPPY"}, "sld"),
+        "many_small_pages": (mixed, {"data_page_size": 2048}, "sld"),
+    }
+
+
+@pytest.mark.parametrize("case", [
+    "all_null", "bw1", "bw2", "bw4", "constant", "double",
+    "growing_bit_width", "int64", "many_small_pages", "nulls_between_runs",
+    "page_v2", "snappy", "string"])
+def test_runs_decode_matches_pyarrow(tmp_path, case):
+    """A chunk with RLE runs, or pages of different bit widths, decodes in
+    the segment-table program to what pyarrow reads from the same file."""
+    columns, options, by_runs = _runs_cases()[case]
+    f = str(tmp_path / "runs.parquet")
+    pq.write_table(pa.table(columns), f, use_dictionary=True,
+                   **{"compression": "NONE", **options})
+    want = pq.read_table(f)
+    schema = T.StructType.from_arrow(want.schema)
+    md = pq.ParquetFile(f).metadata
+    outs, widths = [], set()
+    for rg in range(md.num_row_groups):
+        out, cols = _read_traced(f, rg, schema)
+        outs.append(out)
+        for name, counts in cols.items():
+            assert counts["path"] == "fused", (name, counts)
+            # `nul`: a page of nulls ends inside an 8-value group
+            if name[0] in by_runs or name == "nul":
+                assert counts["decode"] == "runs", (name, counts)
+                assert 1 <= counts["segments"] <= \
+                    counts["packed"] + counts["rle"] or case == "all_null"
+        widths |= {p[2] for c in range(md.num_columns) for p in
+                   PN.read_chunk_pages(f, rg, c, md=md).index_segments}
+    got = pa.concat_tables(outs)
+    for name in want.column_names:
+        assert got.column(name).to_pylist() == \
+            want.column(name).to_pylist(), name
+    if case == "growing_bit_width":
+        assert {13, 14, 15, 16, 17} <= widths
+        assert cols["k"]["rle"] == 0
+    if case == "constant":
+        assert cols["x"]["packed"] == 0
+        assert cols["x"]["rle"] == cols["x"]["pages"] > 1
+    if case == "all_null":
+        assert cols["x"]["segments"] == 0
+    if case == "many_small_pages":
+        assert min(c["pages"] for c in cols.values()) > 4
+
+
+def _segments_array(segs):
+    """parse_rle_hybrid's list as the scan keeps a page's segments."""
+    return np.array([(s.kind == "packed", s.count, s.value, s.byte_off,
+                      s.byte_len) for s in segs], np.int64).reshape(-1, 5)
+
+
+def _hybrid_stream(r, bw, total):
+    """(bytes, values): a random RLE / bit-packed hybrid stream of `total`
+    values `bw` bits wide, written as a Parquet encoder may write one."""
+    out, vals = bytearray(), []
+    while len(vals) < total:
+        left = total - len(vals)
+        if r.random() < 0.4:
+            run, v = int(r.integers(1, 700)), int(r.integers(0, 1 << bw))
+            h = run << 1
+            while h >= 0x80:
+                out.append((h & 0x7F) | 0x80)
+                h >>= 7
+            out.append(h)
+            out += v.to_bytes((bw + 7) // 8, "little")
+            vals += [v] * min(run, left)
+        else:
+            groups = int(r.integers(1, 64))
+            v = r.integers(0, 1 << bw, groups * 8, dtype=np.int64)
+            out.append((groups << 1) | 1)
+            bits = ((v[:, None] >> np.arange(bw)) & 1).astype(np.uint8)
+            out += np.packbits(bits.reshape(-1), bitorder="little").tobytes()
+            vals += [int(x) for x in v[:left]]
+    return bytes(out), vals
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_unpack_runs_matches_host_decode(seed):
+    """The traceable body over a segment table built from random hybrid
+    streams (pages of random bit widths, runs of both kinds, the last run of
+    a page cut short) against decode_rle_host page by page."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import native as N
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
+    from spark_rapids_tpu.ops import parquet_decode as PD
+    r = np.random.default_rng(1000 + seed)
+    pages, want = [], []
+    for _ in range(int(r.integers(1, 9))):
+        bw, total = int(r.integers(1, 21)), int(r.integers(0, 3000))
+        buf, vals = _hybrid_stream(r, bw, total)
+        segs = PN.parse_rle_hybrid(buf, 0, len(buf), bw, total)
+        assert sum(s.count for s in segs) == total
+        want.append(PN.decode_rle_host(buf, 0, len(buf), bw, total))
+        assert want[-1].tolist() == vals
+        assert np.array_equal(N.scan_hybrid_native(buf, 0, len(buf), bw, total),
+                              _segments_array(segs))
+        pages.append((total, np.ones(total, np.int32), bw, buf, 0,
+                      _segments_array(segs)))
+    want = np.concatenate(want)
+    packed, table, max_bw = PN._segment_table(
+        PN.ChunkPages("INT32", [], pages, len(want)))
+    assert table.shape[1] <= sum(len(p[5]) for p in pages)
+    pcap = bucket_capacity(max(len(want), 1))
+    scap = bucket_capacity(table.shape[1])
+    table_h = PN._padded(table, scap)
+    table_h[PD.SEG_START, table.shape[1]:] = pcap + np.arange(
+        scap - table.shape[1])
+    got = PD.unpack_runs_device(
+        jnp.asarray(PN._padded(packed, bucket_capacity(max(len(packed), 1)))),
+        jnp.asarray(table_h), max_bw, pcap)
+    assert np.asarray(got)[:len(want)].tolist() == want.tolist()
+
+
+def test_a_mixed_chunk_is_one_dispatch_and_no_page_span(tmp_path):
+    """Runs and packed segments over several pages: exactly one call_fused
+    (the `dispatches` counter), under the program's own name, and the
+    bookkeeping of the movement ledger the page path had."""
+    from spark_rapids_tpu.runtime import fuse, movement
+    r = np.random.default_rng(5)
+    t = pa.table({"x": pa.array(_with_runs(r, 50000, 3), pa.int32())})
+    f = str(tmp_path / "one.parquet")
+    pq.write_table(t, f, compression="NONE", use_dictionary=True)
+    schema = T.StructType.from_arrow(t.schema)
+    _read_traced(f, 0, schema)                  # compiled
+    before = fuse.stage_metrics()
+    movement.reset()
+    out, cols = _read_traced(f, 0, schema)      # asserts: scan.column only
+    after = fuse.stage_metrics()
+    assert after["dispatches"] - before["dispatches"] == 1
+    assert after["traces"] == before["traces"]
+    assert cols["x"]["pages"] > 1 and cols["x"]["rle"] >= 1
+    assert cols["x"]["packed"] > cols["x"]["segments"] - cols["x"]["rle"]
+    assert any(k[0] == "pq_runs_decode" for k in fuse._kernels
+               if isinstance(k, tuple))
+    moved = {k[2]: v["bytes"] for k, v in movement.snapshot().items()
+             if k[0] == "h2d"}
+    assert moved == {"scan.device": cols["x"]["decoded_bytes"]}
+    assert out.column("x").to_pylist() == t.column("x").to_pylist()

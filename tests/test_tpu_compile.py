@@ -198,6 +198,29 @@ def test_parquet_dictionary_decode_compiles(one_chip, as_on_tpu):
     assert _fits(compiled)
 
 
+@pytest.mark.parametrize("max_bw,dict_dtype", [(2, jnp.int32),
+                                               (17, jnp.int64)])
+def test_parquet_runs_decode_compiles(one_chip, as_on_tpu, max_bw,
+                                      dict_dtype):
+    """One lineitem chunk from its segment table → rows
+    (ops/parquet_decode.decode_runs_cols): a flag column's string codes, and
+    l_orderkey's pages of growing bit width."""
+    from spark_rapids_tpu.ops import parquet_decode as PD
+    is_string = dict_dtype == jnp.int32
+    spec = PD.EncodedRunsSpec(max_bw, 512, N, N * max_bw // 8, N,
+                              jnp.dtype(dict_dtype).name, is_string, 0)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda b, t, d, dl, n: PD.decode_runs_cols(spec, b, t, d, dl, n),
+        shape((spec.bcap,), jnp.uint8), shape((4, spec.scap), jnp.int32),
+        shape((8 if is_string else 1 << 17,), dict_dtype),
+        shape((N,), jnp.bool_), shape((), jnp.int32))
+    assert _fits(compiled)
+
+
 def test_mesh_all_to_all_exchange_compiles_for_four_chips(topo, as_on_tpu):
     """The mesh data plane's exchange step (distributed/exchange.row_exchange
     under shard_map) on a 4-device mesh built from the described chips: the

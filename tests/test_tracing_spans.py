@@ -257,7 +257,7 @@ def test_parent_and_trace_id_cross_an_endpoint_worker_thread(traced):
 
 # -- the scan's spans ------------------------------------------------------
 
-def test_a_planted_rle_run_shows_as_one_column_on_the_page_path(
+def test_a_planted_rle_run_shows_as_one_column_decoded_by_runs(
         traced, tmp_path):
     n = 4096
     r = np.random.default_rng(11)
@@ -278,18 +278,23 @@ def test_a_planted_rle_run_shows_as_one_column_on_the_page_path(
     spans = tracing.recorded()
     cols = {s["counts"]["column"]: s for s in by_name(spans, "scan.column")}
     assert {c: s["counts"]["path"] for c, s in cols.items()} == {
-        "plain": "fused", "planted": "pages", "price": "fused",
+        "plain": "fused", "planted": "fused", "price": "fused",
         "unique": "fallback"}
+    # the run does not change the path: one program either way, and the
+    # span says which and from how many rows of the segment table
+    assert {c: s["counts"].get("decode") for c, s in cols.items()} == {
+        "plain": "packed", "planted": "runs", "price": "packed",
+        "unique": None}
     assert cols["planted"]["counts"]["rle"] >= 1
+    assert cols["planted"]["counts"]["segments"] >= 2
+    assert cols["plain"]["counts"]["segments"] == 1
     assert cols["plain"]["counts"]["rle"] == 0
     assert cols["plain"]["counts"]["packed"] >= 1
     for s in cols.values():
         assert s["counts"]["decoded_bytes"] > 0
         assert s["counts"]["encoded_bytes"] > 0
-    pages = by_name(spans, "scan.page")
-    assert len(pages) == cols["planted"]["counts"]["pages"]
-    assert sum(p["counts"]["values"] for p in pages) == n
-    assert all(p["parent"] == cols["planted"]["id"] for p in pages)
+    assert cols["planted"]["counts"]["pages"] >= 1
+    assert not by_name(spans, "scan.page")
 
 
 # -- under the profiler: one clock, and names on the device programs --------
