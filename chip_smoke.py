@@ -195,6 +195,37 @@ def _rows_agree(name, got, want) -> None:
                 raise AssertionError(f"{name}: mesh row {g} vs single {w}")
 
 
+def _partitions_on_their_chips(plan, devs) -> list:
+    """Run the plan's leaf mesh exchanges (those over no other) until one has
+    given every device rows, and see that partition d's batches lie on
+    device d and no other (columnar.batch.batch_devices, as
+    tests/test_mesh_placement.py does): replicated data is held everywhere,
+    so "every device held data" cannot tell. Returns that one's rows a
+    device."""
+    from spark_rapids_tpu.columnar.batch import batch_devices
+
+    def leaf_exchanges(node):
+        below = [x for child in node.children for x in leaf_exchanges(child)]
+        if below or type(node).__name__ != "MeshExchangeExec":
+            return below
+        return [node]
+
+    rows = []
+    for exchange in leaf_exchanges(plan):
+        rows = [0] * len(devs)
+        for d, dev in enumerate(devs):
+            for b in exchange.execute_partition(d):
+                if batch_devices(b) != {dev}:
+                    raise AssertionError(
+                        f"partition {d} of {exchange.args_string()} lies on "
+                        f"{sorted(x.id for x in batch_devices(b))}, not on "
+                        f"device {dev.id} alone")
+                rows[d] += b.num_rows
+        if all(rows):
+            return rows
+    raise AssertionError(f"no leaf exchange gave every device rows: {rows}")
+
+
 def four_chips(args) -> None:
     """The mesh data plane and what it is compared with, nothing else."""
     import jax
@@ -221,12 +252,15 @@ def four_chips(args) -> None:
             df = tpch.QUERIES[name](tpch.load(spark, paths,
                                               files_per_partition=1))
             if label == "mesh":
-                tree = repr(TpuOverrides(spark.conf).apply(df._plan))
+                plan = TpuOverrides(spark.conf).apply(df._plan)
+                tree = repr(plan)
                 n_ex = tree.count("MeshExchangeExec")
                 if n_ex < 3:
                     raise AssertionError(
                         f"{name}: mesh plan holds {n_ex} MeshExchangeExec, "
                         f"expected at least 3:\n{tree}")
+                note(phase="mesh.placement", query=name,
+                     rows_by_device=_partitions_on_their_chips(plan, devs))
             res, secs, sites, cm = _timed_collect(spark, df)
             CHECKS[name](res.to_pylist(), expected)
             results[label] = res.to_pylist()

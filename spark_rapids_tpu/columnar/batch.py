@@ -67,7 +67,7 @@ class ColumnarBatch:
                              metadata=self.metadata)
 
     # -- host interop -------------------------------------------------------
-    def to_arrow(self):
+    def to_arrow(self, site: str = "batch.to_arrow"):
         import pyarrow as pa
         from spark_rapids_tpu.runtime import movement as _MV
         n = self.num_rows
@@ -76,7 +76,7 @@ class ColumnarBatch:
         # device bytes crossing to the host at this boundary: one call
         # feeds the per-node stats ledger (d2hBytes) AND the movement
         # ledger's d2h/pcie edge (runtime/movement.py)
-        _MV.record_d2h(self.device_memory_size())
+        _MV.record_d2h(self.device_memory_size(), site=site)
         # from_arrays, not a dict: Spark allows duplicate output column names
         return pa.Table.from_arrays(
             [col.to_arrow(n) for col in self.columns], names=list(names))
@@ -98,3 +98,69 @@ class ColumnarBatch:
     def __repr__(self):
         n = self._num_rows if isinstance(self._num_rows, int) else "<device>"
         return f"ColumnarBatch(rows={n}, cols={self.num_cols}, cap={self.capacity})"
+
+
+# -- placement (a mesh partition lives on its own chip) ------------------------
+
+def _column_arrays(col):
+    """Every device array of a column, nested vectors included."""
+    flat = getattr(col, "flat", None)
+    out = [] if flat is None else _column_arrays(flat)
+    return out + [a for a in (col.data, col.validity,
+                              *(col._dict_device or ()))
+                  if hasattr(a, "devices")]
+
+
+def batch_devices(batch: ColumnarBatch) -> set:
+    """The devices that hold the batch's columns: one for a batch of a mesh
+    partition, every device of the mesh for one that XLA left replicated."""
+    out: set = set()
+    for col in batch.columns:
+        for a in _column_arrays(col):
+            out |= a.devices()
+    return out
+
+
+def batch_device(batch: ColumnarBatch):
+    """The device of the batch's first column (None for a batch of none):
+    where a batch of one mesh partition lies."""
+    for col in batch.columns:
+        for a in _column_arrays(col):
+            return next(iter(a.devices()))
+    return None
+
+
+def batch_to_device(batch: ColumnarBatch, device) -> ColumnarBatch:
+    """`batch` with every array on `device`: the explicit move where
+    partitions of several chips come together (a gather to one partition, a
+    broadcast build). A batch that is there already is returned as it is, so
+    on one device this is nothing."""
+    import copy
+    import jax
+    if device is None or batch_devices(batch) <= {device}:
+        return batch
+
+    def move_col(col):
+        moved = copy.copy(col)
+        moved.data = jax.device_put(col.data, device)
+        moved.validity = jax.device_put(col.validity, device)
+        moved._dict_device = None   # packed again on `device` when asked for
+        if getattr(col, "flat", None) is not None:
+            moved.flat = move_col(col.flat)
+        return moved
+
+    n = batch.lazy_num_rows
+    if hasattr(n, "devices"):
+        n = jax.device_put(n, device)
+    return ColumnarBatch([move_col(c) for c in batch.columns], n,
+                         batch.schema, metadata=batch.metadata)
+
+
+def on_one_device(batches):
+    """The batches as they come, each on the device of the first: what
+    gathers the partitions of a mesh exchange (one a chip) into one."""
+    target = None
+    for b in batches:
+        if target is None:
+            target = batch_device(b)
+        yield batch_to_device(b, target)

@@ -8,11 +8,44 @@ joins (GpuShuffledHashJoinBase.scala:97) and sorts ride co-partitioned exchanges
 On a TPU slice the idiomatic data plane is not peer-to-peer RPC but an XLA
 `all_to_all` collective over the mesh ("data" axis, ICI links): every device
 computes Spark-exact partition ids for its rows, compacts rows per destination,
-and one collective moves every row-group in a single step — no host hops. This
-exec keeps ShuffleExchangeExec's external contract (child partitions in, one
-output partition per device out) so HashJoinExec / HashAggregateExec / SortExec
+and one collective moves every row-group in a single step. This exec keeps
+ShuffleExchangeExec's external contract (child partitions in, one output
+partition per device out) so HashJoinExec / HashAggregateExec / SortExec
 compose with it unchanged: the planner routes exchanges here when
 `spark.rapids.tpu.mesh.enabled` is set.
+
+What it does today, stage by stage (each is a span under the query's root):
+
+  MeshExchange.map         the child's partitions are drained on a thread pool
+                           and brought to the HOST as Arrow tables (one D2H of
+                           every live row), their rows dealt evenly onto the
+                           shards whatever the child's partitions were.
+  MeshExchange.ingest      `encode_shards` uploads each shard again, pads it to
+                           one capacity and re-codes strings against a
+                           mesh-global dictionary; `put_stacked_shards` lays
+                           the stack over the mesh.
+  MeshExchange.collective  the SPMD program: partition ids, compaction by
+                           destination, `all_to_all`, re-pack. "No host hop"
+                           holds for this stage alone. The program is built
+                           once a shape (mesh, column types, capacity,
+                           partitioner, key expressions, dictionaries, bounds'
+                           shape) in runtime/fuse.py's kernel table and is
+                           named `jit_srt_MeshExchange_<hash|range|roundrobin>`
+                           in a device trace; range bounds are operands.
+  sync.count               ONE read of the four received-row counts.
+
+Reduce partition d is then built from device d's own shard of the result
+(`addressable_shards`), cut to its bucket by `jit_srt_MeshExchange_slice` ON
+device d: its columns are single-device arrays committed to that device, so
+whatever consumes the partition runs there, four partition tasks on four
+chips at once. What brings partitions of several chips together moves them
+explicitly (`columnar.batch.batch_to_device`: `_GatherAllExec`, the broadcast
+build); `collect()` reads each where it lies.
+
+The map side's host round trip is measured (`d2h_bytes`, `h2d_bytes`, the
+movement ledger's sites `mesh.exchange.map` / `mesh.exchange.ingest`), not yet
+mended: keeping scan partition p on device p % n and assembling the global
+array from single-device arrays is ROADMAP Queue 1's next item.
 
 Supported partitionings: hash (Spark murmur3, bit-exact — strings hash their
 UTF-8 bytes via the mesh-global dictionary so both join sides agree), range
@@ -27,7 +60,7 @@ import threading
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
@@ -39,7 +72,9 @@ from spark_rapids_tpu.expr.core import Col, EvalContext
 from spark_rapids_tpu.ops import hashing as H
 from spark_rapids_tpu.ops.filtering import compact_cols
 from spark_rapids_tpu.ops.hashing import pack_utf8_words
+from spark_rapids_tpu.runtime import fuse, tracing
 from spark_rapids_tpu.runtime import metrics as M
+from spark_rapids_tpu.runtime import movement as MV
 from spark_rapids_tpu.shuffle.partitioning import (
     HashPartitioner, Partitioner, RangePartitioner, RoundRobinPartitioner,
     murmur3_row_hash, range_part_ids)
@@ -103,10 +138,130 @@ def row_exchange(cols, n_rows, pids, n_dev: int, cap: int):
     return merged, m_rows
 
 
+def row_bytes(schema) -> int:
+    """Bytes one row of `schema` crosses a link with: every column's value
+    (strings as their int32 code) and its validity byte."""
+    return sum(np.dtype(f.data_type.jnp_dtype).itemsize + 1
+               for f in schema.fields)
+
+
+def _pids_fn(part: Partitioner, n: int, cap: int):
+    """(name, fn(cols, n_rows, bounds) -> pids) of a bound partitioner, run
+    inside shard_map. Closes over the partitioner's expressions and the
+    bounds' types only: the bounds' VALUES are operands of the program."""
+    if isinstance(part, HashPartitioner):
+        key_exprs = list(part.key_exprs)
+
+        def hash_pids(cols, n_rows, bounds):
+            ctx = EvalContext(cols, n_rows, cap)
+            keys = [e.eval(ctx) for e in key_exprs]
+            dict_words = {i: _string_dict_words(k)
+                          for i, k in enumerate(keys) if k.is_string}
+            h = murmur3_row_hash(keys, cap, dict_words=dict_words)
+            return H.pmod(h, n)
+        return "hash", hash_pids
+    if isinstance(part, RangePartitioner):
+        sort_exprs, orders = list(part.sort_exprs), list(part.orders)
+
+        def range_pids(cols, n_rows, bounds):
+            if not bounds:
+                return jnp.zeros((cap,), jnp.int32)
+            ctx = EvalContext(cols, n_rows, cap)
+            keys = [e.eval(ctx) for e in sort_exprs]
+            return range_part_ids(keys, bounds, orders, cap)
+        return "range", range_pids
+    if isinstance(part, RoundRobinPartitioner):
+        def rr_pids(cols, n_rows, bounds):
+            start = jax.lax.axis_index("data").astype(jnp.int32)
+            return (jnp.arange(cap, dtype=jnp.int32) + start) % n
+        return "roundrobin", rr_pids
+    raise ValueError(
+        f"mesh exchange does not support {type(part).__name__}")
+
+
+def _partitioner_key(part: Partitioner) -> tuple:
+    """Everything of a bound partitioner that the traced program depends on."""
+    if isinstance(part, HashPartitioner):
+        return ("hash", tuple(fuse.expr_key(e) for e in part.key_exprs))
+    if isinstance(part, RangePartitioner):
+        return ("range", tuple(fuse.expr_key(e) for e in part.sort_exprs),
+                tuple((o.ascending, o.nulls_first) for o in part.orders),
+                tuple((b.dtype, b.values.shape[0], _dict_ref(b.dictionary))
+                      for b in part._bounds or ()))
+    return (type(part).__name__,)
+
+
+def _dict_ref(dictionary):
+    return None if dictionary is None else fuse.DictRef(dictionary)
+
+
+def exchange_step(mesh: Mesh, schema, cap: int, part: Partitioner, dicts):
+    """The SPMD exchange program for one shape, from runtime/fuse.py's kernel
+    table: every later exchange and query of the session with the same mesh,
+    column types, capacity, partitioner, key expressions, dictionaries and
+    bounds' shape replays it. Called as step(*vals, *masks, nrows, *bounds)
+    with the range bounds' (values, validity) replicated."""
+    fields = list(schema.fields)
+    n_dev, n_cols = mesh.size, len(fields)
+    kind, pids_fn = _pids_fn(part, n_dev, cap)
+    bound_meta = [(b.dtype, b.dictionary)
+                  for b in getattr(part, "_bounds", None) or ()]
+    key = ("MeshExchange", mesh, tuple((f.data_type, f.nullable)
+                                       for f in fields), cap,
+           _partitioner_key(part),
+           tuple(sorted((ci, fuse.DictRef(d)) for ci, d in dicts.items())))
+
+    def build():
+        def shard_step(*flat):
+            vals = flat[:n_cols]
+            masks = flat[n_cols:2 * n_cols]
+            n_rows = flat[2 * n_cols][0]
+            bvals = flat[2 * n_cols + 1:]
+            # re-attach the mesh-global dictionaries (static metadata): string
+            # keys must hash/compare their actual UTF-8 bytes, not bare codes
+            cols = [Col(v[0], m[0], f.data_type, dicts.get(ci))
+                    for ci, (v, m, f) in enumerate(zip(vals, masks, fields))]
+            bounds = [Col(bvals[2 * i], bvals[2 * i + 1], dt, dictionary)
+                      for i, (dt, dictionary) in enumerate(bound_meta)]
+            pids = pids_fn(cols, n_rows, bounds)
+            merged, m_rows = row_exchange(cols, n_rows, pids, n_dev, cap)
+            return (tuple(c.values[None] for c in merged)
+                    + tuple(c.validity[None] for c in merged)
+                    + (m_rows[None],))
+
+        spec = P("data", None)
+        return jax.shard_map(
+            shard_step, mesh=mesh,
+            in_specs=tuple([spec] * (2 * n_cols) + [P("data")]
+                           + [P()] * (2 * len(bound_meta))),
+            out_specs=tuple([spec] * (2 * n_cols) + [P("data")]))
+
+    name = "MeshExchange." + kind
+    if not fuse.key_is_cacheable(key):
+        # a key expression with no stable content key (fuse.UNKEYABLE): the
+        # program is this exchange's own, as every one was before the table
+        return fuse.BatchKernel(build(), name)
+    return fuse.get_kernel(key, name, build)
+
+
+def _slice_kernel(pcap: int):
+    """Partition d's batch out of device d's shard of the exchange's result,
+    cut to its bucket ON that device: `(1, n_dev*cap)` pieces in, `(pcap,)`
+    columns out, validity cleared past the `n` rows that arrived."""
+    def build():
+        def cut(vals, masks, n):
+            live = jnp.arange(pcap, dtype=jnp.int32) < n
+            return (tuple(v[0, :pcap] for v in vals),
+                    tuple(m[0, :pcap] & live for m in masks))
+        return cut
+    return fuse.get_kernel(("MeshExchange.slice", pcap),
+                           "MeshExchange.slice", build)
+
+
 class MeshExchangeExec(TpuExec):
     """Mesh-backed drop-in for ShuffleExchangeExec: num_partitions == number of
     mesh devices; reduce partition d is whatever the all_to_all delivered to
-    device d."""
+    device d, and lives there."""
 
     def __init__(self, partitioner: Partitioner, child: TpuExec, conf=None,
                  devices=None):
@@ -133,93 +288,44 @@ class MeshExchangeExec(TpuExec):
     def num_partitions(self):
         return self.n
 
-    # -- partition-id programs (run inside shard_map, trace-time specialized) --
-    def _pids_fn(self, cap: int):
-        part = self.partitioner
-        if isinstance(part, HashPartitioner):
-            key_exprs = part.key_exprs
-            n = self.n
-
-            def hash_pids(cols, n_rows):
-                ctx = EvalContext(cols, n_rows, cap)
-                keys = [e.eval(ctx) for e in key_exprs]
-                dict_words = {i: _string_dict_words(k)
-                              for i, k in enumerate(keys) if k.is_string}
-                h = murmur3_row_hash(keys, cap, dict_words=dict_words)
-                return H.pmod(h, n)
-            return hash_pids
-        if isinstance(part, RangePartitioner):
-            sort_exprs, orders, bounds = part.sort_exprs, part.orders, part._bounds
-
-            def range_pids(cols, n_rows):
-                if bounds is None:
-                    return jnp.zeros((cap,), jnp.int32)
-                ctx = EvalContext(cols, n_rows, cap)
-                keys = [e.eval(ctx) for e in sort_exprs]
-                return range_part_ids(keys, bounds, orders, cap)
-            return range_pids
-        if isinstance(part, RoundRobinPartitioner):
-            n = self.n
-
-            def rr_pids(cols, n_rows):
-                start = jax.lax.axis_index("data").astype(jnp.int32)
-                return (jnp.arange(cap, dtype=jnp.int32) + start) % n
-            return rr_pids
-        raise ValueError(
-            f"mesh exchange does not support {type(part).__name__}")
-
-    # -- the SPMD exchange program --------------------------------------------
-    def _build_program(self, schema, cap, dicts):
-        n_dev = self.n
-        n_cols = len(schema.fields)
-        pids_fn = self._pids_fn(cap)
-
-        def shard_step(*flat):
-            vals = flat[:n_cols]
-            masks = flat[n_cols:2 * n_cols]
-            n_rows = flat[2 * n_cols][0]
-            # re-attach the mesh-global dictionaries (static metadata): string
-            # keys must hash/compare their actual UTF-8 bytes, not bare codes
-            cols = [Col(v[0], m[0], f.data_type, dicts.get(ci))
-                    for ci, (v, m, f) in enumerate(
-                        zip(vals, masks, schema.fields))]
-            pids = pids_fn(cols, n_rows)
-            merged, m_rows = row_exchange(cols, n_rows, pids, n_dev, cap)
-            return (tuple(c.values[None] for c in merged)
-                    + tuple(c.validity[None] for c in merged)
-                    + (m_rows[None],))
-
-        spec = P("data", None)
-        return jax.jit(jax.shard_map(
-            shard_step, mesh=self.mesh,
-            in_specs=tuple([spec] * (2 * n_cols) + [P("data")]),
-            out_specs=tuple([spec] * (2 * n_cols) + [P("data")])))
-
     # -- execution -------------------------------------------------------------
     def _collect_shard_tables(self):
-        """Drain child partitions on host (thread-pool map side, same as
-        ShuffleExchangeExec), dealing them round-robin onto the mesh devices."""
+        """Drain child partitions to the host (thread-pool map side, same as
+        ShuffleExchangeExec) and deal their rows evenly onto the mesh devices:
+        the shards' common capacity is then a quarter of the rows, not the
+        largest child partition (a scan that packs its files into one
+        partition would put every row in shard 0 and pad the other three to
+        its size). Returns (one Arrow table a shard, device bytes that came
+        down)."""
         import pyarrow as pa
         from concurrent.futures import ThreadPoolExecutor
-        per_dev: list[list] = [[] for _ in range(self.n)]
-        lock = threading.Lock()
+        nparts = self.child.num_partitions
+        drained: list[list] = [[] for _ in range(nparts)]
+        d2h = [0] * nparts
+        collector = M.current_collector()
+        parent_span = tracing.current_span()
 
         def map_task(split):
-            with TaskContext():
-                got = [b.to_arrow() for b in self.child.execute_partition(split)
-                       if b.num_rows]
-            with lock:
-                per_dev[split % self.n].extend(got)
+            # pool thread: re-enter the query's scope and the span that
+            # started the map stage (as exec/exchange.py's map_task does)
+            with M.collector_context(collector), TaskContext(), \
+                    tracing.child_of(parent_span):
+                for b in self.child.execute_partition(split):
+                    if b.num_rows:
+                        d2h[split] += b.device_memory_size()
+                        drained[split].append(
+                            b.to_arrow(site="mesh.exchange.map"))
 
-        nparts = self.child.num_partitions
         nthreads = max(1, min(self.conf.get(C.NUM_LOCAL_TASKS), nparts))
         if nparts == 1:
             map_task(0)
         else:
             with ThreadPoolExecutor(max_workers=nthreads) as pool:
                 list(pool.map(map_task, range(nparts)))
-        empty = self._empty_table()
-        return [pa.concat_tables(ts) if ts else empty for ts in per_dev]
+        whole = pa.concat_tables(
+            [t for ts in drained for t in ts] or [self._empty_table()])
+        per = -(-whole.num_rows // self.n)
+        return [whole.slice(d * per, per) for d in range(self.n)], sum(d2h)
 
     def _empty_table(self):
         import pyarrow as pa
@@ -228,8 +334,21 @@ class MeshExchangeExec(TpuExec):
 
     def _run_exchange(self):
         schema = self.output
-        tables = self._collect_shard_tables()
-        shards, cap, global_dicts = encode_shards(tables, schema, self.n)
+        n_out, rb = len(schema.fields), row_bytes(schema)
+        with tracing.span("MeshExchange.map",
+                          partitions=self.child.num_partitions) as sp:
+            tables, d2h = self._collect_shard_tables()
+            rows_in = sum(t.num_rows for t in tables)
+            sp.set(rows=rows_in, d2h_bytes=d2h)
+        with tracing.span("MeshExchange.ingest") as sp:
+            shards, cap, global_dicts = encode_shards(tables, schema, self.n)
+            vals, masks, nrows = put_stacked_shards(self.mesh, shards)
+            # each shard goes up at its own bucket and is padded to the
+            # common capacity on the device
+            h2d = sum(bucket_capacity(t.num_rows) for t in tables) * rb
+            MV.record_h2d(h2d, site="mesh.exchange.ingest")
+            sp.set(rows=rows_in, h2d_bytes=h2d, capacity=cap)
+        bounds = []
         if isinstance(self.partitioner, RangePartitioner):
             # bounds from a host-side sample of the ENCODED shards so string
             # bounds live in the mesh-global (sorted) dictionary space
@@ -237,26 +356,42 @@ class MeshExchangeExec(TpuExec):
                       for cols, nr in shards if nr > 0]
             if sample:
                 self.partitioner.set_bounds_from_sample(sample)
+            bounds = [a for b in self.partitioner._bounds or ()
+                      for a in (b.values, b.validity)]
 
-        with self._partition_time.timed():
-            step = self._build_program(schema, cap, global_dicts)
-            vals, masks, nrows = put_stacked_shards(self.mesh, shards)
-            out = step(*vals, *masks, nrows)
+        step = exchange_step(self.mesh, schema, cap, self.partitioner,
+                             global_dicts)
+        # what the all_to_all is handed on every device: a (n, cap) block a
+        # column, values and validity, padding included
+        operand = self.n * self.n * cap * rb
+        with tracing.trace_range(
+                "MeshExchange.collective", self._partition_time,
+                rows=rows_in, capacity=cap, columns=n_out, row_bytes=rb,
+                devices=self.n, operand_bytes=operand,
+                partitioner=step.name.rpartition(".")[2]):
+            out = step(*vals, *masks, nrows, *bounds)
+        MV.record("ici.collective", operand, link="ici",
+                  site="mesh.exchange", payload_bytes=rows_in * rb)
 
-        n_out = len(schema.fields)
         out_v, out_m, m_rows = out[:n_out], out[n_out:2 * n_out], out[-1]
-        counts = np.asarray(m_rows)  # ONE host sync at the stage boundary
-        dicts = global_dicts
+        with tracing.span("sync.count") as sp:
+            counts = np.asarray(m_rows)  # ONE host sync at the stage boundary
+            sp.set(rows=int(counts.sum()), capacity=self.n * self.n * cap)
+        # partition d from device d's own piece of every result array: the
+        # batch is committed to that device and its consumers run there
+        pieces = [{s.device: s.data for s in a.addressable_shards}
+                  for a in (*out_v, *out_m)]
         batches = []
-        for d in range(self.n):
+        for d, dev in enumerate(self.mesh.devices.flat):
             n = int(counts[d])
             pcap = min(bucket_capacity(max(n, 1)), self.n * cap)
-            cvs = []
-            for ci, f in enumerate(schema.fields):
-                v = out_v[ci][d][:pcap]
-                m = out_m[ci][d][:pcap] & (jnp.arange(pcap) < n)
-                cvs.append(TpuColumnVector(f.data_type, v, m, dicts.get(ci)))
-            batches.append(ColumnarBatch(cvs, n, schema))
+            cut_v, cut_m = _slice_kernel(pcap)(
+                tuple(p[dev] for p in pieces[:n_out]),
+                tuple(p[dev] for p in pieces[n_out:]), n)
+            batches.append(ColumnarBatch(
+                [TpuColumnVector(f.data_type, v, m, global_dicts.get(ci))
+                 for ci, (f, v, m) in enumerate(
+                     zip(schema.fields, cut_v, cut_m))], n, schema))
         self._shard_out = batches
 
     def _ensure_exchange(self):

@@ -78,10 +78,16 @@ class BroadcastExchangeExec(TpuExec):
         # single-batch registration gets a spill-only retry
         with trace_range("BroadcastExchange.build", self._build_time), \
                 F.scope("joins.build"):
-            batches = []
-            for split in range(self.child.num_partitions):
-                with TaskContext():
-                    batches.extend(self.child.execute_partition(split))
+            from spark_rapids_tpu.columnar.batch import on_one_device
+
+            def child_batches():
+                for split in range(self.child.num_partitions):
+                    with TaskContext():
+                        yield from self.child.execute_partition(split)
+
+            # partitions of a mesh exchange lie one a chip: the relation is
+            # built on the first batch's (nothing to move on one device)
+            batches = list(on_one_device(child_batches()))
             batch = concat_all(iter(batches), self.child.output,
                                conf=self.conf)
             size = batch.device_memory_size()

@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.batch import (ColumnarBatch, batch_device,
+                                             batch_to_device)
 from spark_rapids_tpu.columnar.vector import TpuColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import TpuExec, TaskContext, acquire_semaphore
 from spark_rapids_tpu.exec.coalesce import concat_all
@@ -1250,6 +1251,9 @@ class NestedLoopJoinExec(TpuExec):
                                      if self.join_type == J.FULL_OUTER else None)
                 for lb in self.children[0].execute_partition(split):
                     acquire_semaphore(self.metrics)
+                    # a stream partition of a mesh exchange lies on its own
+                    # chip: the relation goes there (nothing on one device)
+                    build = batch_to_device(build, batch_device(lb))
                     # the range closes before each batch goes downstream: a
                     # span left open across a yield would adopt the consumer
                     pairs = self._join_batch(lb, build, n_build, out_schema,

@@ -156,5 +156,9 @@ class _GatherAllExec(TpuExec):
         return 1
 
     def execute_partition(self, split):
-        for p in range(self.child.num_partitions):
-            yield from self.child.execute_partition(p)
+        # partitions of a mesh exchange lie one a chip: what is gathered goes
+        # to the chip of the first batch, once (nothing on one device)
+        from spark_rapids_tpu.columnar.batch import on_one_device
+        return on_one_device(
+            b for p in range(self.child.num_partitions)
+            for b in self.child.execute_partition(p))

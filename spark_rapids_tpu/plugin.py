@@ -70,7 +70,13 @@ def executor_init(conf) -> None:
         raise PluginInitError(
             f"device {ordinal} acquisition failed: {e}") from e
     DeviceManager.initialize(conf)
-    TpuSemaphore.initialize(conf.get(CFG.CONCURRENT_TPU_TASKS))
+    # the permits are a chip's: under the mesh a partition's task runs on its
+    # own chip (one executor a chip), so each chip of the mesh admits as many
+    permits = conf.get(CFG.CONCURRENT_TPU_TASKS)
+    if conf.get(CFG.MESH_ENABLED):
+        from spark_rapids_tpu.distributed.exchange import mesh_devices
+        permits *= max(1, len(mesh_devices(conf)))
+    TpuSemaphore.initialize(permits)
 
 
 def driver_init(conf) -> dict:

@@ -35,6 +35,21 @@ def test_executor_init_acquires_device():
     assert TpuSemaphore.get().max_concurrent == 3
 
 
+def test_executor_init_gives_each_mesh_chip_its_permits():
+    """Under the mesh a partition's task runs on its own chip: the permits
+    are a chip's, so four chips admit four times as many tasks."""
+    from spark_rapids_tpu.runtime.semaphore import TpuSemaphore
+    conf = RapidsConf({"spark.rapids.tpu.sql.concurrentTpuTasks": "3",
+                       "spark.rapids.tpu.mesh.enabled": "true",
+                       "spark.rapids.tpu.mesh.devices": "4"})
+    try:
+        PL.executor_init(conf)
+        assert TpuSemaphore.get().max_concurrent == 12
+    finally:
+        PL.executor_init(RapidsConf())
+    assert TpuSemaphore.get().max_concurrent == 2
+
+
 def test_bootstrap_idempotent_and_eager():
     conf = RapidsConf({"spark.rapids.tpu.device.eagerInit": "true"})
     PL.bootstrap(conf)

@@ -252,3 +252,40 @@ def test_mesh_all_to_all_exchange_compiles_for_four_chips(topo, as_on_tpu):
                              sharding=NamedSharding(mesh, P("data"))))
     assert "all-to-all" in compiled.as_text()
     assert _fits(compiled)
+
+
+def test_mesh_exchange_program_of_q3_at_sf1_compiles_for_four_chips(
+        topo, as_on_tpu):
+    """The exchange as the program builds it since PR 29 (`exchange_step`
+    from the kernel table, named for the trace) at the shape Q3's lineitem
+    side has at SF 1 with its rows dealt evenly: 4 columns, 1 Mi rows a
+    shard; and the cut of one partition out of its chip's piece."""
+    from spark_rapids_tpu.distributed import exchange as X
+    from spark_rapids_tpu.expr.core import BoundReference
+    from spark_rapids_tpu.shuffle.partitioning import HashPartitioner
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    schema = T.StructType([T.StructField("l_orderkey", T.LONG, True),
+                           T.StructField("l_extendedprice", T.DOUBLE, True),
+                           T.StructField("l_discount", T.DOUBLE, True),
+                           T.StructField("l_shipdate", T.DateType(), True)])
+    step = X.exchange_step(
+        mesh, schema, N, HashPartitioner([BoundReference(0, T.LONG)], 4), {})
+    assert step._jit.__wrapped__.__name__ == "srt_MeshExchange_hash"
+    sh = NamedSharding(mesh, P("data", None))
+    dtypes = [f.data_type.jnp_dtype for f in schema.fields]
+    compiled = step._jit.lower(
+        *[jax.ShapeDtypeStruct((4, N), dt, sharding=sh) for dt in dtypes],
+        *[jax.ShapeDtypeStruct((4, N), jnp.bool_, sharding=sh)
+          for _ in dtypes],
+        jax.ShapeDtypeStruct((4,), jnp.int32,
+                             sharding=NamedSharding(mesh, P("data")))
+    ).compile()
+    assert "all-to-all" in compiled.as_text()
+    assert _fits(compiled)
+    one = SingleDeviceSharding(topo.devices[2])
+    cut = X._slice_kernel(N)._jit.lower(
+        tuple(jax.ShapeDtypeStruct((1, 4 * N), dt, sharding=one)
+              for dt in dtypes),
+        tuple(jax.ShapeDtypeStruct((1, 4 * N), jnp.bool_, sharding=one)
+              for _ in dtypes), 760000).compile()
+    assert _fits(cut)
