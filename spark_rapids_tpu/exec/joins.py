@@ -19,6 +19,8 @@ and conditional inner plus outer/semi/anti against a broadcast build side.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
 
 import numpy as np
@@ -119,6 +121,34 @@ def _emit_pairs(join_type, stream_is_left, condition, preproject,
         pos += out_cap
 
 
+def _dense_table(sorted_vals, n_valid, vmin, *, slots: int):
+    """The direct-address table of a sorted unique build: slot `key - vmin`
+    holds the key's position in the sorted build, the rest -1. One scatter a
+    build; the sorted distinct keys give ascending distinct slots, which XLA
+    is told, and the tail past `n_valid` continues beyond the table and is
+    dropped."""
+    i = jnp.arange(sorted_vals.shape[0], dtype=jnp.int32)
+    slot = jnp.where(i < n_valid,
+                     (sorted_vals.astype(jnp.int64) - vmin).astype(jnp.int32),
+                     slots + i)
+    return jnp.full((slots,), -1, jnp.int32).at[slot].set(
+        i, mode="drop", indices_are_sorted=True, unique_indices=True)
+
+
+def _dense_lookup(dense, svals):
+    """Direct-address probe `(table, vmin, vmax), stream key values ->
+    (sorted build position, hit)`: one gather a stream row. The table holds
+    the position in the sorted build of each key of [vmin, vmax], -1 where
+    the build has none; its length is that domain's bucket. The range test
+    compares and never subtracts first, so no stream key wraps into the
+    domain."""
+    table, vmin, vmax = dense
+    sv = svals.astype(jnp.int64)
+    in_dom = (sv >= vmin) & (sv <= vmax)
+    r = table[jnp.where(in_dom, sv - vmin, 0).astype(jnp.int32)]
+    return r, in_dom & (r >= 0)
+
+
 def _int_backed(dtype) -> bool:
     """Orderable fixed-point key: comparisons over raw device values ARE key
     comparisons (unlike string codes, which are only comparable under one
@@ -132,8 +162,9 @@ class _JoinCore:
 
     Single fixed-point-key joins take a FAST path: the build side is sorted
     ONCE (invalid/padding rows forced to the type max and clamped out via the
-    valid count), and each stream batch probes with two searchsorted calls —
-    no per-batch re-sort of build+stream (the rank path pays a multi-key sort
+    valid count), and each stream batch probes it by direct address or by
+    searchsorted (`_prep_fast_build` picks the mode from the build) — no
+    per-batch re-sort of build+stream (the rank path pays a multi-key sort
     over both sides per stream batch)."""
 
     def __init__(self, build_batch: ColumnarBatch, build_key_exprs,
@@ -173,8 +204,20 @@ class _JoinCore:
         # probe paths do not evaluate the prefilter
         assert stream_prefilter is None or (self.fast
                                             and not self.ctx_sensitive)
-        if self.fast:
-            self._prep_fast_build()
+        self.domain = self.table_slots = 0    # of the fast path's key range
+        with tracing.span("HashJoin.build_prep") as sp:
+            if self.fast:
+                self._prep_fast_build()
+            sp.set(mode=self.mode, rows=self.n_build, capacity=self.build_cap,
+                   domain=self.domain, table_slots=self.table_slots)
+
+    @property
+    def mode(self) -> str:
+        """How a stream batch finds its build rows: `dense` / `one` / `two`
+        / `pallas_hash` over the build sorted once, `rank` for several keys,
+        keys that are no integers, or keys that read the batch's context."""
+        return (self._probe_mode if self.fast and not self.ctx_sensitive
+                else "rank")
 
     def _prep_fast_build(self):
         """Sort the single int build key once. Strategy picked from the key
@@ -184,11 +227,14 @@ class _JoinCore:
         - range fits the packed budget → ONE-operand int64 sort of
           ((val - vmin) << idx_bits | row_idx); ~8x cheaper than the
           3-operand comparator sort (an XLA:CPU measurement).
-        - afterwards, uniqueness + compact domain decide the probe mode:
-          dense direct-address rank table (O(1) gather per stream row),
-          unique single-searchsorted, or the general two-searchsorted."""
+        - afterwards, uniqueness + compact domain decide the probe mode, on
+          every backend: dense direct-address rank table (one gather per
+          stream row), unique single-searchsorted, or the general
+          two-searchsorted. Timed on a v5e at SF 1 shapes (PERF.md section 6,
+          PR 30): the table's scatter 0.7 to 1.9 ms a build, a dense lookup
+          of 1 Mi stream keys 8.5 ms, the searchsorted it replaces 240 to
+          480 ms."""
         from spark_rapids_tpu.runtime import fuse
-        import numpy as np
         k = self.build_keys_raw[0]
         cap = k.values.shape[0]
         idx_bits = max(int(cap - 1).bit_length(), 1)
@@ -216,66 +262,15 @@ class _JoinCore:
         # dtype-max key can never collide with/overflow into the sentinel
         packable = (self.n_build > 0 and rng < (1 << (62 - idx_bits))
                     and vmax < (1 << 62))
-        # one size/budget for BOTH dense-table builders (direct and
-        # post-sort) so they make consistent engage/skip decisions
-        dsize = rng + 2 if self.n_build > 0 else 1
+        # the direct-address table covers [vmin, vmax], its length rounded up
+        # to a bucket so the programs that take it are shaped by the bucket
+        # and not by the data; the budget is a power of two, so a domain
+        # under it has its bucket under it too
+        self.domain = domain = vmax - vmin + 1 if vmax >= vmin else 0
         dense_budget = max(4 * cap, 1 << 22)
-        from spark_rapids_tpu.runtime.hw import scatters_cheap
-        direct_ok = (scatters_cheap() and self.n_build > 0
-                     and self.build_matched_acc is None
-                     and dsize <= dense_budget)
-        if direct_ok:
-            # CPU-only sort-free build: scatter row indices straight into the
-            # direct-address table (XLA:CPU scatters are cheap; the sort they
-            # replace was the dominant build cost on XLA:CPU). A
-            # duplicate-key build falls through to the sorted paths below;
-            # on TPU large scatters serialize, so this path never engages.
-            def rel_of(k, n_build, vmin):
-                vals = k.values.astype(jnp.int8) \
-                    if k.values.dtype == jnp.bool_ else k.values
-                eligible = k.validity & (
-                    jnp.arange(cap, dtype=jnp.int32) < n_build)
-                return jnp.where(eligible, vals.astype(jnp.int64) - vmin,
-                                 jnp.asarray(dsize, jnp.int64))
-
-            # two kernels so a duplicate-key build discards only the cheap
-            # uniqueness scatter, not a full table build
-            def uniq_check(k, n_build, vmin):
-                counts = jnp.zeros((dsize,), jnp.int32
-                                   ).at[rel_of(k, n_build, vmin)].add(
-                    1, mode="drop")
-                return jnp.all(counts <= 1)
-
-            def mktable_direct(k, n_build, vmin):
-                return jnp.full((dsize,), -1, jnp.int32
-                                ).at[rel_of(k, n_build, vmin)].set(
-                    jnp.arange(cap, dtype=jnp.int32), mode="drop")
-
-            dkey = ("join_build_direct_uniq", k.dtype, cap, dsize)
-            dargs = (k, n_build_t, jnp.asarray(vmin, jnp.int64))
-            uniq_t = fuse.call_fused(
-                dkey, "HashJoin.build_prep", lambda: uniq_check, dargs,
-                lambda: uniq_check(*dargs))
-            if bool(uniq_t):
-                tkey = ("join_build_direct_table", k.dtype, cap, dsize)
-                table_t = fuse.call_fused(
-                    tkey, "HashJoin.build_prep", lambda: mktable_direct,
-                    dargs, lambda: mktable_direct(*dargs))
-                self._probe_mode = "dense"
-                self._dense_size = dsize
-                self._dense_table = table_t
-                # ranks ARE build-row indices for the direct table
-                self._build_perm = jnp.arange(cap, dtype=jnp.int32)
-                self._sorted_build = (k.values.astype(jnp.int8)
-                                      if k.values.dtype == jnp.bool_
-                                      else k.values)  # dtype carrier only
-                self._n_valid = n_valid
-                self._vmin = vmin
-                return
-
         from spark_rapids_tpu.ops import pallas_kernels as PK
         # Pallas VMEM hash table (sparse domains the dense table can't
-        # afford; the TPU path where large scatters rule `dense` out).
+        # afford).
         # vmin > int64 min keeps the slot sentinel unambiguous; the build
         # itself refuses duplicate keys / overfull buckets via `ok`.
         nb = PK.hash_join_buckets(self.n_build)
@@ -304,18 +299,17 @@ class _JoinCore:
                                       if k.values.dtype == jnp.bool_
                                       else k.values)  # dtype carrier only
                 self._n_valid = n_valid
-                self._vmin = vmin
                 return
 
         if packable:
-            def prep(k, n_build, vmin):
+            def prep(k, n_build, vmin, tail_rel):
                 vals = k.values.astype(jnp.int8) \
                     if k.values.dtype == jnp.bool_ else k.values
                 eligible = k.validity & (
                     jnp.arange(cap, dtype=jnp.int32) < n_build)
                 rel = (vals.astype(jnp.int64) - vmin)
                 # ineligible rows above every real key (rng+1 relative)
-                rel = jnp.where(eligible, rel, jnp.asarray(rng + 1, jnp.int64))
+                rel = jnp.where(eligible, rel, tail_rel)
                 packed = (rel << idx_bits) | jnp.arange(cap, dtype=jnp.int64)
                 # one operand of distinct values: stability buys nothing
                 s = jax.lax.sort(packed, is_stable=False)
@@ -331,8 +325,10 @@ class _JoinCore:
                 unique = ~jnp.any(same & in_valid)
                 return sorted_vals, perm, unique
 
-            pkey = ("join_build_pack", k.dtype, cap, idx_bits, rng + 1)
-            args = (k, n_build_t, jnp.asarray(vmin, jnp.int64))
+            # vmin and the range are operands: the key range of the data
+            # shapes no program
+            pkey = ("join_build_pack", k.dtype, cap)
+            args = (k, n_build_t, vmin_t, jnp.asarray(rng + 1, jnp.int64))
             self._sorted_build, self._build_perm, uniq_t = fuse.call_fused(
                 pkey, "HashJoin.build_prep", lambda: prep, args,
                 lambda: prep(*args))
@@ -365,34 +361,28 @@ class _JoinCore:
                 key, "HashJoin.build_prep", lambda: prep, args,
                 lambda: prep(*args))
         self._n_valid = n_valid
-        # probe-mode choice — static per compiled probe kernel
-        self._vmin = vmin
+        # probe-mode choice, from what the build shows (uniqueness, key
+        # range, capacity) and on every backend — static per compiled probe
+        # kernel. Different needs, not knobs: "two" for duplicate keys, "one"
+        # for a unique build whose domain is over the budget, "dense" below it
         unique = bool(uniq_t) if self.n_build > 0 else True
         self._probe_mode = "two"
         if unique and self.build_matched_acc is None:
             self._probe_mode = "one"
-            if dsize <= dense_budget and scatters_cheap():
-                # direct-address rank table: scatter once per build, O(1)
-                # gather per probe row (kept off-TPU: large 1:1 scatters
-                # serialize there; searchsorted stays the TPU path)
+            slots = bucket_capacity(domain)
+            # slot numbers (the dropped tail's too) are int32
+            if domain <= dense_budget and slots + cap < (1 << 31):
+                # direct-address rank table: ONE scatter a build, one gather
+                # a probe row where "one" pays a log2(capacity)+1-step
+                # searchsorted loop over 64-bit halves a stream batch
                 self._probe_mode = "dense"
-                self._dense_size = dsize
-
-                def mktable(sorted_vals, n_valid, vmin):
-                    i = jnp.arange(cap, dtype=jnp.int32)
-                    slot = jnp.where(
-                        i < n_valid,
-                        sorted_vals.astype(jnp.int64) - vmin,
-                        jnp.asarray(dsize, jnp.int64))   # tail → dropped
-                    table = jnp.full((dsize,), -1, jnp.int32)
-                    return table.at[slot].set(i, mode="drop")
-
-                tkey = ("join_dense_table", k.dtype, cap, dsize)
-                targs = (self._sorted_build, n_valid,
-                         jnp.asarray(vmin, jnp.int64))
+                self._vmin, self._vmax = vmin_t, vmax_t
+                self.table_slots = slots
+                mktable = functools.partial(_dense_table, slots=slots)
+                targs = (self._sorted_build, n_valid, vmin_t)
                 self._dense_table = fuse.call_fused(
-                    tkey, "HashJoin.dense_table", lambda: mktable, targs,
-                    lambda: mktable(*targs))
+                    ("join_dense_table", slots), "HashJoin.dense_table",
+                    lambda: mktable, targs, lambda: mktable(*targs))
 
     def probe_batch(self, stream_batch: ColumnarBatch):
         from spark_rapids_tpu.runtime import fuse
@@ -478,14 +468,12 @@ class _JoinCore:
         from spark_rapids_tpu.runtime import fuse
         stream_key_exprs = self.stream_key_exprs
         mode = self._probe_mode
-        vmin = self._vmin
-        dsize = getattr(self, "_dense_size", 0)
         hash_buckets = getattr(self, "_hash_buckets", 0)
 
         stream_prefilter = self.stream_prefilter
 
         def kernel(sorted_build, n_valid, n_build, build_keys_raw, stream_cols,
-                   n_stream, dense_table, hash_keys, hash_rows):
+                   n_stream, dense, hash_keys, hash_rows):
             scap = stream_cols[0].values.shape[0]
             sctx = EvalContext(stream_cols, n_stream, scap)
             k = stream_key_exprs[0].eval(sctx)
@@ -514,10 +502,8 @@ class _JoinCore:
                 lo = jnp.where(hit, pos, 0).astype(jnp.int32)
                 hi = jnp.where(hit, pos + 1, lo).astype(jnp.int32)
             elif mode == "dense":
-                slot = svals.astype(jnp.int64) - vmin
-                in_dom = (slot >= 0) & (slot < dsize - 1)
-                r = dense_table[jnp.clip(slot, 0, dsize - 1)]
-                hit = in_dom & (r >= 0) & k.validity & live
+                r, hit = _dense_lookup(dense, svals)
+                hit = hit & k.validity & live
                 lo = jnp.where(hit, r, 0).astype(jnp.int32)
                 hi = jnp.where(hit, r + 1, lo).astype(jnp.int32)
             elif mode == "one":
@@ -562,24 +548,16 @@ class _JoinCore:
                 return lo, hi, counts, total, (bhi > blo) & b_eligible
             return lo, hi, counts, total, None
 
-        # vmin/dsize/bucket count are traced into the program only in their
-        # own modes; keying them otherwise would recompile per distinct
-        # build key range
+        # the dense table's vmin, vmax and length are operands: a build's
+        # key range shapes no program (jit specialises on the table's bucket)
         key = ("join_probe_fast", jt, track_matched, mode,
-               vmin if mode == "dense" else None,
-               dsize if mode == "dense" else None,
                hash_buckets if mode == "pallas_hash" else None,
                self._stream_key_key,
                fuse.schema_key(stream_batch.schema)
                if stream_batch.schema else None)
         stream_cols = [Col.from_vector(c) for c in stream_batch.columns]
         n_stream = jnp.asarray(stream_batch.lazy_num_rows, jnp.int32)
-        dense = (self._dense_table if mode == "dense"
-                 else jnp.zeros((1,), jnp.int32))
-        hk = (self._hash_keys if mode == "pallas_hash"
-              else jnp.zeros((1,), jnp.int64))
-        hr = (self._hash_rows if mode == "pallas_hash"
-              else jnp.zeros((1,), jnp.int32))
+        _, _, _, dense, hk, hr = self.chain_args()
         args = (self._sorted_build, self._n_valid,
                 jnp.asarray(self.n_build, jnp.int32), self.build_keys_raw,
                 stream_cols, n_stream, dense, hk, hr)
@@ -602,20 +580,20 @@ class _JoinCore:
                 and self._probe_mode in ("dense", "one", "pallas_hash"))
 
     def chain_static(self):
-        """Kernel-key part: everything `chain_lookup` bakes into the trace."""
+        """Kernel-key part: everything `chain_lookup` bakes into the trace
+        (the dense table's vmin, vmax and length are operands)."""
         mode = self._probe_mode
         return (mode,
-                getattr(self, "_vmin", None) if mode == "dense" else None,
-                getattr(self, "_dense_size", None) if mode == "dense" else None,
                 getattr(self, "_hash_buckets", None)
                 if mode == "pallas_hash" else None)
 
     def chain_args(self):
-        """Traced operands for `chain_lookup` (unused modes ride dummies so
-        the pytree shape stays uniform across modes)."""
+        """Traced operands for `chain_lookup`: `dense` is the table with its
+        vmin and vmax (`_dense_lookup`'s operand), empty in the other modes;
+        the hash arrays of the other modes ride dummies."""
         mode = self._probe_mode
-        dense = (self._dense_table if mode == "dense"
-                 else jnp.zeros((1,), jnp.int32))
+        dense = ((self._dense_table, self._vmin, self._vmax)
+                 if mode == "dense" else ())
         hk = (self._hash_keys if mode == "pallas_hash"
               else jnp.zeros((1,), jnp.int64))
         hr = (self._hash_rows if mode == "pallas_hash"
@@ -631,8 +609,6 @@ class _JoinCore:
         unfused path). Validity/liveness masking is the caller's job."""
         from spark_rapids_tpu.ops import pallas_kernels as PK
         mode = self._probe_mode
-        vmin = getattr(self, "_vmin", 0)
-        dsize = getattr(self, "_dense_size", 0)
         buckets = getattr(self, "_hash_buckets", 0)
 
         def lookup(cargs, k):
@@ -646,10 +622,7 @@ class _JoinCore:
                 row = perm[jnp.clip(pos, 0, pcap - 1)]
                 return jnp.where(found, row, 0).astype(jnp.int32), found
             if mode == "dense":
-                slot = svals.astype(jnp.int64) - vmin
-                in_dom = (slot >= 0) & (slot < dsize - 1)
-                r = dense[jnp.clip(slot, 0, dsize - 1)]
-                hit = in_dom & (r >= 0)
+                r, hit = _dense_lookup(dense, svals)
                 row = perm[jnp.clip(r, 0, pcap - 1)]
                 return jnp.where(hit, row, 0).astype(jnp.int32), hit
             # mode == "one": single searchsorted + equality (same common-type
@@ -766,7 +739,8 @@ class HashJoinExec(TpuExec):
         halves — the reference withRetry over the stream iterator) with the
         matched-row accumulator checkpointed per attempt."""
         def probe(b):
-            with trace_range("HashJoin.probe", self._join_time), \
+            with trace_range("HashJoin.probe", self._join_time,
+                             mode=core.mode), \
                     R.with_restore_on_retry(core):
                 return b, core.probe_batch(b)
 
@@ -792,36 +766,42 @@ class HashJoinExec(TpuExec):
             # buildSelfTime and is subtracted from this join's selfTime, so
             # the profiler can render the build as a distinct line item
             # without double counting (buildTime stays the INCLUSIVE timer)
-            with trace_range("HashJoin.build", self._build_time), \
-                    M.node_frame(self._node_id,
-                                 self.metrics.metric(M.BUILD_SELF_TIME,
-                                                     M.MODERATE)), \
-                    F.scope("joins.build"):
-                from spark_rapids_tpu.runtime import pipeline as P
-                build_it = build_child.execute_partition(split)
-                if P.enabled(self.conf):
-                    # build-segment boundary: the build subtree (scan +
-                    # upstream operators) produces on the stage's worker
-                    # thread while this thread registers/concats
-                    build_it = P.stage_iterator(
-                        build_it, edge="join.build", conf=self.conf,
-                        registry=self.metrics,
-                        node_id=getattr(build_child, "_node_id", None),
-                        spillable=True)
-                build_batch = concat_all(build_it, build_child.output,
-                                         conf=self.conf)
-                # hold the built table spillable while we stream (reference
-                # LazySpillableColumnarBatch, GpuHashJoin.scala:200); the
-                # single-batch registration cannot split — spill-only retry
-                sb = R.call_with_retry(
-                    lambda: mem.SpillableColumnarBatch(
-                        build_batch, mem.ACTIVE_BATCHING_PRIORITY),
-                    scope="joins.build")
-            with sb:
-                bk = self.left_keys if not self.stream_is_left else self.right_keys
-                sk = self.right_keys if not self.stream_is_left else self.left_keys
-                core = _JoinCore(sb.get_batch(), bk, sk, self.join_type,
-                                 stream_prefilter=self.stream_prefilter)
+            with contextlib.ExitStack() as held:
+                with trace_range("HashJoin.build", self._build_time):
+                    with M.node_frame(self._node_id,
+                                      self.metrics.metric(M.BUILD_SELF_TIME,
+                                                          M.MODERATE)), \
+                            F.scope("joins.build"):
+                        from spark_rapids_tpu.runtime import pipeline as P
+                        build_it = build_child.execute_partition(split)
+                        if P.enabled(self.conf):
+                            # build-segment boundary: the build subtree (scan
+                            # + upstream operators) produces on the stage's
+                            # worker thread while this thread
+                            # registers/concats
+                            build_it = P.stage_iterator(
+                                build_it, edge="join.build", conf=self.conf,
+                                registry=self.metrics,
+                                node_id=getattr(build_child, "_node_id",
+                                                None),
+                                spillable=True)
+                        build_batch = concat_all(build_it, build_child.output,
+                                                 conf=self.conf)
+                        # hold the built table spillable while we stream
+                        # (reference LazySpillableColumnarBatch,
+                        # GpuHashJoin.scala:200); the single-batch
+                        # registration cannot split — spill-only retry
+                        sb = held.enter_context(R.call_with_retry(
+                            lambda: mem.SpillableColumnarBatch(
+                                build_batch, mem.ACTIVE_BATCHING_PRIORITY),
+                            scope="joins.build"))
+                    # the sorted build and its table belong to the build's
+                    # span (`HashJoin.build_prep` is its child), not to its
+                    # fault scope or its self-time frame
+                    bk = self.left_keys if not self.stream_is_left else self.right_keys
+                    sk = self.right_keys if not self.stream_is_left else self.left_keys
+                    core = _JoinCore(sb.get_batch(), bk, sk, self.join_type,
+                                     stream_prefilter=self.stream_prefilter)
                 out_schema = self.output
                 yield from self._probe_stream(core, sb, stream_child, split,
                                               out_schema)
@@ -928,12 +908,12 @@ class BroadcastHashJoinExec(HashJoinExec):
             reader = self._shared.reader()
             try:
                 stream_child = self.children[0] if self.stream_is_left else self.children[1]
-                with trace_range("BroadcastHashJoin.build", self._build_time):
-                    sb = self._shared.get()
                 bk = self.left_keys if not self.stream_is_left else self.right_keys
                 sk = self.right_keys if not self.stream_is_left else self.left_keys
-                core = _JoinCore(sb.get_batch(), bk, sk, self.join_type,
-                                 stream_prefilter=self.stream_prefilter)
+                with trace_range("BroadcastHashJoin.build", self._build_time):
+                    sb = self._shared.get()
+                    core = _JoinCore(sb.get_batch(), bk, sk, self.join_type,
+                                     stream_prefilter=self.stream_prefilter)
                 out_schema = self.output
                 yield from self._probe_stream(core, sb, stream_child, split,
                                               out_schema)
@@ -1039,22 +1019,25 @@ class BroadcastHashJoinChainExec(TpuExec):
                     sbs = [None] * len(self.hops)
                     for i in reversed(range(len(self.hops))):
                         sbs[i] = self.hops[i]._shared.get()
-                cores = []
-                for h, sb in zip(self.hops, sbs):
-                    bk = (h.left_keys if not h.stream_is_left
-                          else h.right_keys)
-                    sk = (h.right_keys if not h.stream_is_left
-                          else h.left_keys)
-                    cores.append(_JoinCore(
-                        sb.get_batch(), bk, sk, h.join_type,
-                        stream_prefilter=h.stream_prefilter))
+                    cores = []
+                    for h, sb in zip(self.hops, sbs):
+                        bk = (h.left_keys if not h.stream_is_left
+                              else h.right_keys)
+                        sk = (h.right_keys if not h.stream_is_left
+                              else h.left_keys)
+                        cores.append(_JoinCore(
+                            sb.get_batch(), bk, sk, h.join_type,
+                            stream_prefilter=h.stream_prefilter))
                 fused_ok = all(c.chain_capable() for c in cores)
                 out_schema = self.output
                 in_rows = self.metrics.metric(M.NUM_INPUT_ROWS, M.ESSENTIAL)
                 pred_cap = [None]   # survivor-count capacity prediction
 
+                modes = "+".join(c.mode for c in cores)    # a hop each
+
                 def probe(b):
-                    with trace_range("HashJoinChain.probe", self._join_time):
+                    with trace_range("HashJoinChain.probe", self._join_time,
+                                     modes=modes):
                         return self._fused_probe(b, cores, sbs, pred_cap,
                                                  out_schema)
 
@@ -1176,7 +1159,8 @@ class BroadcastHashJoinChainExec(TpuExec):
             schema = h.output
 
             def probe(b):
-                with trace_range("HashJoin.probe", self._join_time), \
+                with trace_range("HashJoin.probe", self._join_time,
+                                 mode=core.mode), \
                         R.with_restore_on_retry(core):
                     return b, core.probe_batch(b)
 

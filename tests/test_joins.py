@@ -390,3 +390,214 @@ def test_join_ranks_are_tuple_equality(dtypes):
     for t1, k1 in keyed:
         for t2, k2 in keyed:
             assert (k1 == k2) == (t1 == t2) and (k1 < k2) == (t1 < t2)
+
+
+# -- probe modes of the sorted-build path, under the chip's answers -----------
+
+@pytest.fixture
+def chip_answers(monkeypatch):
+    """tests/test_tpu_compile.py's `as_on_tpu` steering of the backend
+    question that code running here asks: the probe mode must not hang on
+    it."""
+    from spark_rapids_tpu.runtime import hw
+    monkeypatch.setattr(hw, "scatters_cheap", lambda: False)
+
+
+@pytest.fixture
+def spans():
+    from spark_rapids_tpu.runtime import tracing
+    tracing.drain()
+    tracing.set_enabled(True)
+    yield tracing
+    tracing.set_enabled(False)
+    tracing.drain()
+
+
+I32, I64 = np.iinfo(np.int32), np.iinfo(np.int64)
+
+
+def _keys(vals, nulls, typ):
+    return pa.array([None if m else int(v) for v, m in zip(vals, nulls)],
+                    type=typ)
+
+
+def _mode_case(name):
+    """(build table, stream table, key names, the mode the build must get).
+    Every stream holds null keys, keys outside [vmin, vmax], the extremes of
+    its dtype and keys that match; every build holds null keys."""
+    r = np.random.default_rng(sum(map(ord, name)))
+    nb, ns = 300, 700
+    btype = stype = pa.int64()
+    if name == "dense_negative_far_from_zero":
+        bk = -7_000_000_000 + r.permutation(900)[:nb]
+    elif name == "dense_int32_build_int64_stream":
+        # the build ends at its dtype's maximum; 2**32 + key must not match
+        bk = I32.max - r.permutation(900)[:nb]
+        btype = pa.int32()
+    elif name == "dense_int64_max":
+        # not packable beside a row index: the comparator sort builds it
+        bk = I64.max - r.permutation(900)[:nb]
+    elif name == "one_over_the_budget":
+        bk = -5 + r.permutation(900)[:nb] * (1 << 33)
+    elif name == "two_duplicate_keys":
+        bk = 40 + r.integers(0, 90, nb)
+    else:
+        assert name == "rank_two_keys", name
+        bk = -50 + r.integers(0, 60, nb)
+    far = [I64.min, I64.max, I64.min + 1, int(bk.min()) - 1, 0, -1,
+           int(bk.max() % (1 << 32)) + (1 << 32)]
+    far += [int(bk.max()) + 1] if int(bk.max()) < I64.max else []
+    sk = np.concatenate([r.choice(bk, ns - len(far)),
+                         np.asarray(far, dtype=np.int64)])
+    bt = {"rk": _keys(bk, r.random(nb) < 0.1, btype),
+          "rv": pa.array(np.arange(nb), type=pa.int32())}
+    st = {"lk": _keys(sk, r.random(ns) < 0.1, stype),
+          "lv": pa.array(np.arange(ns), type=pa.int32())}
+    lkeys, rkeys = ["lk"], ["rk"]
+    if name == "rank_two_keys":
+        bt["rk2"] = pa.array(r.integers(0, 3, nb), type=pa.int64())
+        st["lk2"] = pa.array(r.integers(0, 3, ns), type=pa.int64())
+        lkeys, rkeys = ["lk", "lk2"], ["rk", "rk2"]
+    return pa.table(bt), pa.table(st), lkeys, rkeys, name.split("_")[0]
+
+
+MODE_CASES = ["dense_negative_far_from_zero", "dense_int32_build_int64_stream",
+              "dense_int64_max", "one_over_the_budget", "two_duplicate_keys",
+              "rank_two_keys"]
+
+
+def ref_join_rows(st, bt, lkeys, rkeys, how):
+    """NumPy/plain-Python reference with Spark's semantics (a null key
+    matches nothing), as sorted row tuples."""
+    by_key = {}
+    for b in bt.to_pylist():
+        k = tuple(b[c] for c in rkeys)
+        if None not in k:
+            by_key.setdefault(k, []).append(b)
+    out = []
+    for s in st.to_pylist():
+        hits = by_key.get(tuple(s[c] for c in lkeys), [])
+        if how == "inner" or (how == "leftouter" and hits):
+            out += [{**s, **b} for b in hits]
+        elif how == "leftouter":
+            out.append({**s, **{c: None for c in bt.column_names}})
+        elif (how == "leftsemi") == bool(hits):
+            out.append(s)
+    return _sorted_rows(out)
+
+
+def _sorted_rows(rows):
+    return sorted((tuple(r.values()) for r in rows),
+                  key=lambda t: [(v is None, v or 0) for v in t])
+
+
+def _span_counts(tracing, name):
+    return [s["counts"] for s in tracing.recorded() if s["name"] == name]
+
+
+@pytest.mark.parametrize("how", ["inner", "leftouter", "leftsemi", "leftanti"])
+@pytest.mark.parametrize("case", MODE_CASES)
+def test_probe_mode_is_chosen_from_the_build_and_matches_numpy(
+        case, how, chip_answers, spans):
+    bt, st, lkeys, rkeys, mode = _mode_case(case)
+    conf = RapidsConf()
+    j = HashJoinExec(how, [col(c) for c in lkeys], [col(c) for c in rkeys],
+                     ArrowScanExec([st], conf=conf),
+                     ArrowScanExec([bt], conf=conf))
+    got = _sorted_rows(j.execute_collect().to_pylist())
+    assert got == ref_join_rows(st, bt, lkeys, rkeys, how)
+    assert len(got) > 0
+    (prep,) = _span_counts(spans, "HashJoin.build_prep")
+    assert prep["mode"] == mode, prep
+    assert {p["mode"] for p in _span_counts(spans, "HashJoin.probe")} == {mode}
+    if mode == "dense":
+        assert prep["table_slots"] >= prep["domain"] > 0
+    else:
+        assert prep["table_slots"] == 0
+        # over the budget the code has: max(4 x capacity, 4 Mi)
+        assert mode != "one" or prep["domain"] > max(4 * prep["capacity"],
+                                                     1 << 22)
+
+
+def _chain_over(st, bt, conf):
+    """`st` joined to `bt` on lk = rk, then to a small second build on
+    lv = k2, as the planner stacks broadcast joins."""
+    from spark_rapids_tpu.exec.joins import maybe_chain
+    b2 = pa.table({"k2": pa.array(np.arange(0, 700, 2), type=pa.int32()),
+                   "w": pa.array(np.arange(350) * 3, type=pa.int64())})
+    inner = BroadcastHashJoinExec("inner", [col("lk")], [col("rk")],
+                                  ArrowScanExec([st], conf=conf),
+                                  ArrowScanExec([bt], conf=conf))
+    outer = BroadcastHashJoinExec("inner", [col("lv")], [col("k2")], inner,
+                                  ArrowScanExec([b2], conf=conf))
+    return maybe_chain(outer, conf), b2
+
+
+def _chain_reference(st, bt, b2):
+    first = pa.Table.from_pylist(
+        [dict(zip(st.column_names + bt.column_names, row))
+         for row in ref_join_rows(st, bt, ["lk"], ["rk"], "inner")],
+        schema=pa.schema(list(st.schema) + list(bt.schema)))
+    return ref_join_rows(first, b2, ["lv"], ["k2"], "inner")
+
+
+@pytest.mark.parametrize("case", MODE_CASES[:-1])
+def test_probe_modes_through_the_join_chain(case, chip_answers, spans):
+    from spark_rapids_tpu.exec.joins import BroadcastHashJoinChainExec
+    bt, st, lkeys, rkeys, mode = _mode_case(case)
+    chain, b2 = _chain_over(st, bt, RapidsConf())
+    assert isinstance(chain, BroadcastHashJoinChainExec)
+    got = _sorted_rows(chain.execute_collect().to_pylist())
+    assert got == _chain_reference(st, bt, b2)
+    assert len(got) > 0
+    assert [p["mode"] for p in _span_counts(spans, "HashJoin.build_prep")] \
+        == [mode, "dense"]
+    fused = _span_counts(spans, "HashJoinChain.probe")
+    if mode == "two":       # duplicate keys: the hops run one by one
+        assert not fused
+        assert [p["mode"] for p in _span_counts(spans, "HashJoin.probe")] \
+            == ["two", "dense"]
+    else:
+        assert {p["modes"] for p in fused} == {mode + "+dense"}
+
+
+def _ranged_tables(vmin, n_build, step):
+    """A unique build of `n_build` keys from `vmin` in steps of `step`, and a
+    stream over and around them."""
+    bk = vmin + np.arange(n_build) * step
+    sk = np.concatenate([bk[::2], bk[:50] - 1, [vmin - 9, int(bk[-1]) + 9]])
+    bt = pa.table({"rk": pa.array(bk, type=pa.int64()),
+                   "rv": pa.array(np.arange(n_build), type=pa.int32())})
+    st = pa.table({"lk": pa.array(sk, type=pa.int64()),
+                   "lv": pa.array(np.arange(len(sk)), type=pa.int32())})
+    return bt, st
+
+
+@pytest.mark.parametrize("through", ["join", "chain"])
+def test_a_builds_key_range_shapes_no_program(through, chip_answers):
+    """Two builds of one capacity whose vmin and range differ inside one
+    bucket of table slots run ONE set of compiled programs: the second
+    traces nothing and adds no kernel."""
+    from spark_rapids_tpu.runtime import fuse
+
+    def run(vmin, n_build, step):
+        bt, st = _ranged_tables(vmin, n_build, step)
+        conf = RapidsConf()
+        if through == "chain":
+            node, b2 = _chain_over(st, bt, conf)
+            want = _chain_reference(st, bt, b2)
+        else:
+            node = HashJoinExec("inner", [col("lk")], [col("rk")],
+                                ArrowScanExec([st], conf=conf),
+                                ArrowScanExec([bt], conf=conf))
+            want = ref_join_rows(st, bt, ["lk"], ["rk"], "inner")
+        assert _sorted_rows(node.execute_collect().to_pylist()) == want
+
+    run(1000, 400, 3)                   # domain 1198: a table of 2048 slots
+    traces = fuse.stage_metrics()["traces"]
+    with fuse._lock:
+        kernels = len(fuse._kernels)
+    run(-123_456_789, 390, 5)           # domain 1946: the same bucket
+    assert fuse.stage_metrics()["traces"] == traces
+    with fuse._lock:
+        assert len(fuse._kernels) == kernels

@@ -174,6 +174,38 @@ def test_gather_form_compaction_compiles(one_chip, as_on_tpu):
     assert _fits(compiled)
 
 
+def test_dense_join_probe_compiles_without_a_loop(one_chip, as_on_tpu):
+    """The direct-address probe of a unique build over a compact key domain
+    (exec/joins.py mode `dense`) at q3's shapes: a 1 Mi-row lineitem batch
+    against the 2 Mi-slot table of the filtered orders (capacity 256 Ki).
+    The lookup is a gather, with no `while` (mode `one`'s `searchsorted` is
+    a 19-step loop over the 64-bit key's halves); the table is one scatter
+    a build."""
+    import functools
+    from spark_rapids_tpu.exec import joins as XJ
+    slots, bcap = 2 << 20, 1 << 18
+    s64 = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    s32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    probe = _compile(
+        lambda table, vmin, vmax, keys: XJ._dense_lookup(
+            (table, vmin, vmax), keys),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        s64, s64, jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip))
+    hlo = probe.as_text()
+    assert "while" not in hlo and "gather" in hlo
+    assert _fits(probe)
+    searched = _compile(
+        lambda build, keys: jnp.searchsorted(build, keys),
+        jax.ShapeDtypeStruct((bcap,), jnp.int64, sharding=one_chip),
+        jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip))
+    assert "while" in searched.as_text()     # what the probe no longer pays
+    table = _compile(
+        functools.partial(XJ._dense_table, slots=slots),
+        jax.ShapeDtypeStruct((bcap,), jnp.int64, sharding=one_chip), s32, s64)
+    assert "scatter" in table.as_text() and "while" not in table.as_text()
+    assert _fits(table)
+
+
 def test_parquet_dictionary_decode_compiles(one_chip, as_on_tpu):
     """One encoded lineitem page → rows: bit-unpack (the Pallas kernel when
     the table routes it, else the jnp form), dictionary gather,

@@ -297,6 +297,49 @@ def test_a_planted_rle_run_shows_as_one_column_decoded_by_runs(
     assert not by_name(spans, "scan.page")
 
 
+# -- the joins' spans ---------------------------------------------------------
+
+def test_a_join_says_how_each_build_is_probed(traced):
+    """A chain of two joins and a join on duplicate keys through the
+    session: every build's
+    `HashJoin.build_prep` is a child of its build span and carries the probe
+    mode with what decided it; every probe span carries its hops' modes."""
+    spark = TpuSession()
+    n = 3000
+    r = np.random.default_rng(4)
+    fact = spark.create_dataframe(pa.table({
+        "k": pa.array(r.integers(0, 500, n) + 10_000, pa.int64()),
+        "j": pa.array(r.integers(0, 40, n), pa.int64()),
+        "d": pa.array(r.integers(0, 9, n), pa.int64())}))
+    uniq = spark.create_dataframe(pa.table({
+        "k": pa.array(np.arange(400) + 10_000, pa.int64()),
+        "a": pa.array(np.arange(400), pa.int64())}))
+    uniq2 = spark.create_dataframe(pa.table({
+        "j": pa.array(np.arange(40), pa.int64()),
+        "b": pa.array(np.arange(40) * 2, pa.int64())}))
+    dup = spark.create_dataframe(pa.table({
+        "d": pa.array([1, 1, 2, 3], pa.int64()),
+        "c": pa.array([5, 6, 7, 8], pa.int64())}))
+    assert fact.join(uniq, on="k").join(uniq2, on="j").collect().num_rows > 0
+    assert fact.join(dup, on="d").collect().num_rows > 0
+    spans = tracing.recorded()
+    preps = by_name(spans, "HashJoin.build_prep")
+    by_rows = {s["counts"]["rows"]: s["counts"] for s in preps}
+    assert {rows: c["mode"] for rows, c in by_rows.items()} == {
+        400: "dense", 40: "dense", 4: "two"}
+    assert by_rows[400]["domain"] == 400 and by_rows[400]["table_slots"] == 512
+    assert by_rows[40]["domain"] == 40 and by_rows[40]["table_slots"] == 64
+    assert by_rows[4]["domain"] == 3 and by_rows[4]["table_slots"] == 0
+    for s in preps:
+        assert s["counts"]["capacity"] >= s["counts"]["rows"]
+        assert ancestors(spans, s)[0] in ("HashJoin.build",
+                                          "BroadcastHashJoin.build")
+    chain = by_name(spans, "HashJoinChain.probe")
+    assert chain and {s["counts"]["modes"] for s in chain} == {"dense+dense"}
+    single = by_name(spans, "HashJoin.probe")
+    assert single and {s["counts"]["mode"] for s in single} == {"two"}
+
+
 # -- under the profiler: one clock, and names on the device programs --------
 
 @pytest.fixture(scope="module")
