@@ -97,8 +97,18 @@ def _watch_compiles() -> None:
     monitoring.register_event_listener(on_event)
 
 
+def _h2d_sites():
+    """h2d bytes by metering SITE from the global movement ledger (the
+    per-query collector mirror aggregates by link only)."""
+    from spark_rapids_tpu.runtime import movement as MV
+    out: dict = {}
+    for (edge, link, site), rec in MV.snapshot().items():
+        if edge == "h2d":
+            out[site] = out.get(site, 0) + rec["bytes"]
+    return out
+
+
 def _timed_collect(spark, df):
-    from bench import _h2d_sites
     sites0, xla0 = _h2d_sites(), dict(_XLA)
     t0 = time.perf_counter()
     res = df.collect()
@@ -112,7 +122,7 @@ def _timed_collect(spark, df):
 
 def _run_checked(spark, name, make_df, expected):
     """One query cold then hot, both checked against the oracle's rows."""
-    from bench import CHECKS
+    from spark_rapids_tpu.benchmarks.tpch import CHECKS
     out = {}
     for run in ("cold", "hot"):
         res, secs, sites, cm = _timed_collect(spark, make_df())
@@ -229,7 +239,6 @@ def _partitions_on_their_chips(plan, devs) -> list:
 def four_chips(args) -> None:
     """The mesh data plane and what it is compared with, nothing else."""
     import jax
-    from bench import CHECKS
     from spark_rapids_tpu.benchmarks import tpch
     from spark_rapids_tpu.plan.overrides import TpuOverrides
     from spark_rapids_tpu.session import TpuSession
@@ -262,7 +271,7 @@ def four_chips(args) -> None:
                 note(phase="mesh.placement", query=name,
                      rows_by_device=_partitions_on_their_chips(plan, devs))
             res, secs, sites, cm = _timed_collect(spark, df)
-            CHECKS[name](res.to_pylist(), expected)
+            tpch.CHECKS[name](res.to_pylist(), expected)
             results[label] = res.to_pylist()
             note(phase="mesh" if label == "mesh" else "mesh.compared_with",
                  query=name, seconds=secs, h2d_sites=sites, **cm)

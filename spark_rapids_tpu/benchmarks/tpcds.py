@@ -1,9 +1,8 @@
 """TPC-DS subset benchmark: deterministic generator, star-join queries via
 the session API, and independent single-core NumPy oracles.
 
-Reference role: BASELINE.md config-3 (TPC-DS 10-query subset with the
-accelerated shuffle over ICI) and config-5 (full sweep); the reference's
-own nightly runs the analogous qa_nightly_select_test.py sweep
+Reference role: a TPC-DS 10-query subset with the accelerated shuffle over
+ICI, and the full sweep; the reference's own nightly runs the analogous qa_nightly_select_test.py sweep
 (integration_tests). Queries follow the official TPC-DS text restricted to
 this schema subset: q3, q42, q52, q55 (date×item star aggregates), q7
 (demographics + promotion), q19 (brand revenue where customer and store
@@ -1579,10 +1578,9 @@ def np_q65(tb):
 _late_bind_oracles()
 
 
-# Per-query float-tolerance column indexes shared by the test suite and
-# bench.py's recorded sweep: both must count a query "ok" under VALUE equality
-# (exact on keys/ints, rel-1e-9 on float slots) — row-count alone overstated
-# verification in BENCH_r03 (VERDICT r3 weak #3).
+# Per-query float-tolerance column indexes of the test suites: a query counts
+# "ok" under VALUE equality (exact on keys/ints, rel-1e-9 on float slots) —
+# row-count alone overstates verification (VERDICT r3 weak #3).
 FLOAT_COLS = {
     "q3": {3}, "q42": {3}, "q52": {3}, "q55": {2}, "q7": {1, 2, 3, 4},
     "q19": {3}, "q6": set(), "q27": {2, 3, 4, 5}, "q34": set(),
@@ -2096,8 +2094,8 @@ def np_q26(tb):
 
 def sql_suite_oracles():
     """{name: (oracle_fn, float_cols)} for every official SQL text in
-    sql/tpcds_queries.py — shared by tests/test_sql_tpcds.py and bench.py's
-    SQL-suite sweep (reference qa_nightly_sql.py role). Most queries reuse
+    sql/tpcds_queries.py, for tests/test_sql_tpcds.py (reference
+    qa_nightly_sql.py role). Most queries reuse
     the DataFrame suite's oracles; the SQL-only ones have their own."""
     sql_only = {
         "q13": (np_q13, {0, 1, 2, 3}),

@@ -1,10 +1,11 @@
 """TPC-H benchmark: deterministic data generator, q1/q3/q5 via the session
 API, and independent single-core NumPy oracles.
 
-Reference role: integration_tests mortgage app + BASELINE.md config-2 (TPC-H
-SF>=0.1 q1/q3/q5 — scan+filter+agg+join on one TPU VM). The NumPy oracles are
-the "CPU Spark" stand-in for vs_baseline AND the correctness check: bench runs
-refuse to report a time for a wrong answer.
+Reference role: integration_tests mortgage app (TPC-H q1/q3/q5 —
+scan+filter+agg+join on one TPU VM). The NumPy oracles are the correctness
+check of the tests and of chip_smoke.py (`CHECKS`, at the end): a wrong
+answer raises. The benchmark (benchmark/) keeps its own generator and
+reference on purpose and imports neither.
 
 Data layout follows dbgen's schema subset needed by q1/q3/q5; keys are dense
 (1..n) rather than dbgen's sparse permutations — join selectivity and group
@@ -362,3 +363,45 @@ def np_q5(tb):
     for k in np.unique(nat):
         out[name_of[int(k)]] = float(vol[nat == k].sum())
     return sorted(out.items(), key=lambda kv: -kv[1])
+
+
+# -- a session's rows against an oracle's (raise on a wrong answer) ----------
+
+def _check_q1(got, exp):
+    assert len(got) == len(exp), (len(got), len(exp))
+    for g_, e in zip(got, exp):
+        g = list(g_.values())
+        assert g[0] == e[0] and g[1] == e[1], (g, e)
+        for a, b in zip(g[2:], e[2:]):
+            assert abs(a - b) <= 1e-6 * max(1.0, abs(b)), (g, e)
+
+
+def _check_q3(got, exp):
+    assert len(got) == len(exp), (len(got), len(exp))
+    for g, (k, d, p, rev) in zip(got, exp):
+        assert g["l_orderkey"] == k, (g, k)
+        assert abs(g["revenue"] - rev) <= 1e-6 * max(1.0, abs(rev))
+
+
+def _check_q5(got, exp):
+    assert len(got) == len(exp), (len(got), len(exp))
+    for g, (n, v) in zip(got, exp):
+        assert g["n_name"] == n, (g, n)
+        assert abs(g["revenue"] - v) <= 1e-6 * max(1.0, abs(v))
+
+
+def _check_q18(got, exp):
+    assert len(got) == len(exp), (len(got), len(exp))
+    for g, (c, o, d, t, s) in zip(got, exp):
+        assert g["c_custkey"] == c and g["o_orderkey"] == o, (g, (c, o))
+        gd = g["o_orderdate"]
+        if isinstance(gd, datetime.date):
+            gd = (gd - EPOCH).days
+        assert gd == d, (gd, d)
+        assert abs(g["o_totalprice"] - t) <= 1e-6 * max(1.0, abs(t))
+        assert abs(g["sum_qty"] - s) <= 1e-6 * max(1.0, abs(s))
+
+
+# query -> check(rows of `collect().to_pylist()`, the matching np_q's rows)
+CHECKS = {"q1": _check_q1, "q3": _check_q3, "q5": _check_q5,
+          "q18": _check_q18}

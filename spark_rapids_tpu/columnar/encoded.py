@@ -39,7 +39,7 @@ class EncodedCol:
 
     def __init__(self, packed, dict_dev, dl, n_present_t, n_t,
                  spec: PD.EncodedPageSpec, dtype, dictionary=None):
-        self.packed = packed            # padded bytes (or pallas words)
+        self.packed = packed            # padded bytes
         self.dict_dev = dict_dev        # device dictionary / sorted-rank map
         self.dl = dl                    # def levels as bool, (capacity,)
         self.n_present_t = n_present_t  # int32 scalar, device

@@ -494,13 +494,6 @@ PIPELINE_MAX_QUEUE_BYTES = conf("spark.rapids.tpu.pipeline.maxQueueBytes").doc(
     "registered as spillable so the OOM-retry ladder can steal them"
 ).bytes_conf("256m")
 
-PALLAS_ENABLED = conf("spark.rapids.tpu.sql.pallas.enabled").doc(
-    "Route the string murmur3 hash, parquet bit-unpack, dense group-by "
-    "one-hot matmul, exchange radix partition, and unique-key hash-join "
-    "probe through the hand-written Pallas TPU kernels "
-    "(ops/pallas_kernels.py); when false (or off-TPU) the fused-XLA jnp "
-    "formulations run instead").boolean_conf(True)
-
 BROADCAST_TIMEOUT = conf("spark.rapids.tpu.sql.broadcast.timeout").doc(
     "Seconds a consumer waits for the broadcast relation to materialize; "
     "<=0 waits forever (Spark spark.sql.broadcastTimeout; reference "

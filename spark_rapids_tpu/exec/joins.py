@@ -214,7 +214,7 @@ class _JoinCore:
     @property
     def mode(self) -> str:
         """How a stream batch finds its build rows: `dense` / `one` / `two`
-        / `pallas_hash` over the build sorted once, `rank` for several keys,
+        over the build sorted once, `rank` for several keys,
         keys that are no integers, or keys that read the batch's context."""
         return (self._probe_mode if self.fast and not self.ctx_sensitive
                 else "rank")
@@ -268,39 +268,6 @@ class _JoinCore:
         # under it has its bucket under it too
         self.domain = domain = vmax - vmin + 1 if vmax >= vmin else 0
         dense_budget = max(4 * cap, 1 << 22)
-        from spark_rapids_tpu.ops import pallas_kernels as PK
-        # Pallas VMEM hash table (sparse domains the dense table can't
-        # afford).
-        # vmin > int64 min keeps the slot sentinel unambiguous; the build
-        # itself refuses duplicate keys / overfull buckets via `ok`.
-        nb = PK.hash_join_buckets(self.n_build)
-        if (nb and self.n_build > 0 and self.build_matched_acc is None
-                and vmin > jnp.iinfo(jnp.int64).min
-                and PK.should_use("hashjoin")):
-            def mktable_hash(k, n_build):
-                vals = k.values.astype(jnp.int8) \
-                    if k.values.dtype == jnp.bool_ else k.values
-                eligible = k.validity & (
-                    jnp.arange(cap, dtype=jnp.int32) < n_build)
-                return PK.hash_join_build(vals.astype(jnp.int64),
-                                          eligible, nb)
-            hkey = ("join_build_hash", k.dtype, cap, nb)
-            hargs = (k, n_build_t)
-            tk_t, tr_t, ok_t = fuse.call_fused(
-                hkey, "HashJoin.build_prep", lambda: mktable_hash, hargs,
-                lambda: mktable_hash(*hargs))
-            if bool(ok_t):    # one host sync per build, like vmin/vmax
-                self._probe_mode = "pallas_hash"
-                self._hash_buckets = nb
-                self._hash_keys, self._hash_rows = tk_t, tr_t
-                # probe positions ARE build-row indices
-                self._build_perm = jnp.arange(cap, dtype=jnp.int32)
-                self._sorted_build = (k.values.astype(jnp.int8)
-                                      if k.values.dtype == jnp.bool_
-                                      else k.values)  # dtype carrier only
-                self._n_valid = n_valid
-                return
-
         if packable:
             def prep(k, n_build, vmin, tail_rel):
                 vals = k.values.astype(jnp.int8) \
@@ -459,21 +426,16 @@ class _JoinCore:
 
     def _probe_batch_fast(self, stream_batch, jt, track_matched):
         """Pre-sorted-build probe. Modes (chosen at build, static per compiled
-        kernel): "pallas_hash" = VMEM hash-table probe kernel (unique keys;
-        pallas_kernels.hash_join_probe, interpret-mode off-TPU); "dense" =
-        O(1) direct-address rank-table gather (unique keys, compact domain);
-        "one" = single searchsorted + equality (unique keys); "two" = general
-        left+right searchsorted."""
-        from spark_rapids_tpu.ops import pallas_kernels as PK
+        kernel): "dense" = O(1) direct-address rank-table gather (unique keys,
+        compact domain); "one" = single searchsorted + equality (unique
+        keys); "two" = general left+right searchsorted."""
         from spark_rapids_tpu.runtime import fuse
         stream_key_exprs = self.stream_key_exprs
         mode = self._probe_mode
-        hash_buckets = getattr(self, "_hash_buckets", 0)
-
         stream_prefilter = self.stream_prefilter
 
         def kernel(sorted_build, n_valid, n_build, build_keys_raw, stream_cols,
-                   n_stream, dense, hash_keys, hash_rows):
+                   n_stream, dense):
             scap = stream_cols[0].values.shape[0]
             sctx = EvalContext(stream_cols, n_stream, scap)
             k = stream_key_exprs[0].eval(sctx)
@@ -492,16 +454,7 @@ class _JoinCore:
                                       n_stream, scap)
             else:
                 live = jnp.arange(scap, dtype=jnp.int32) < n_stream
-            if mode == "pallas_hash":
-                # equality over int64 images is equality over any narrower
-                # int key dtype, so no common-type promotion dance needed
-                pos, found = PK.hash_join_probe(
-                    hash_keys, hash_rows, svals.astype(jnp.int64),
-                    hash_buckets)
-                hit = found & k.validity & live
-                lo = jnp.where(hit, pos, 0).astype(jnp.int32)
-                hi = jnp.where(hit, pos + 1, lo).astype(jnp.int32)
-            elif mode == "dense":
+            if mode == "dense":
                 r, hit = _dense_lookup(dense, svals)
                 hit = hit & k.validity & live
                 lo = jnp.where(hit, r, 0).astype(jnp.int32)
@@ -551,16 +504,15 @@ class _JoinCore:
         # the dense table's vmin, vmax and length are operands: a build's
         # key range shapes no program (jit specialises on the table's bucket)
         key = ("join_probe_fast", jt, track_matched, mode,
-               hash_buckets if mode == "pallas_hash" else None,
                self._stream_key_key,
                fuse.schema_key(stream_batch.schema)
                if stream_batch.schema else None)
         stream_cols = [Col.from_vector(c) for c in stream_batch.columns]
         n_stream = jnp.asarray(stream_batch.lazy_num_rows, jnp.int32)
-        _, _, _, dense, hk, hr = self.chain_args()
+        _, _, _, dense = self.chain_args()
         args = (self._sorted_build, self._n_valid,
                 jnp.asarray(self.n_build, jnp.int32), self.build_keys_raw,
-                stream_cols, n_stream, dense, hk, hr)
+                stream_cols, n_stream, dense)
         lo, hi, counts, total, matched = fuse.call_fused(
             key, "HashJoin.probe", lambda: kernel, args,
             lambda: kernel(*args))
@@ -577,29 +529,19 @@ class _JoinCore:
         rows <= stream rows, so stream capacity bounds every hop)."""
         return (self.fast and not self.ctx_sensitive
                 and self.build_matched_acc is None
-                and self._probe_mode in ("dense", "one", "pallas_hash"))
+                and self._probe_mode in ("dense", "one"))
 
     def chain_static(self):
         """Kernel-key part: everything `chain_lookup` bakes into the trace
         (the dense table's vmin, vmax and length are operands)."""
-        mode = self._probe_mode
-        return (mode,
-                getattr(self, "_hash_buckets", None)
-                if mode == "pallas_hash" else None)
+        return self._probe_mode
 
     def chain_args(self):
         """Traced operands for `chain_lookup`: `dense` is the table with its
-        vmin and vmax (`_dense_lookup`'s operand), empty in the other modes;
-        the hash arrays of the other modes ride dummies."""
-        mode = self._probe_mode
+        vmin and vmax (`_dense_lookup`'s operand), empty in mode `one`."""
         dense = ((self._dense_table, self._vmin, self._vmax)
-                 if mode == "dense" else ())
-        hk = (self._hash_keys if mode == "pallas_hash"
-              else jnp.zeros((1,), jnp.int64))
-        hr = (self._hash_rows if mode == "pallas_hash"
-              else jnp.zeros((1,), jnp.int32))
-        return (self._sorted_build, self._n_valid, self._build_perm,
-                dense, hk, hr)
+                 if self._probe_mode == "dense" else ())
+        return (self._sorted_build, self._n_valid, self._build_perm, dense)
 
     def chain_lookup(self):
         """Traceable single-match probe `(chain_args, stream_key_col) ->
@@ -607,20 +549,13 @@ class _JoinCore:
         `_probe_batch_fast`, with the position->row mapping through
         `_build_perm` folded in (expand_pairs does that mapping on the
         unfused path). Validity/liveness masking is the caller's job."""
-        from spark_rapids_tpu.ops import pallas_kernels as PK
         mode = self._probe_mode
-        buckets = getattr(self, "_hash_buckets", 0)
 
         def lookup(cargs, k):
-            sorted_build, n_valid, perm, dense, hk, hr = cargs
+            sorted_build, n_valid, perm, dense = cargs
             pcap = perm.shape[0]
             svals = (k.values.astype(jnp.int8)
                      if k.values.dtype == jnp.bool_ else k.values)
-            if mode == "pallas_hash":
-                pos, found = PK.hash_join_probe(
-                    hk, hr, svals.astype(jnp.int64), buckets)
-                row = perm[jnp.clip(pos, 0, pcap - 1)]
-                return jnp.where(found, row, 0).astype(jnp.int32), found
             if mode == "dense":
                 r, hit = _dense_lookup(dense, svals)
                 row = perm[jnp.clip(r, 0, pcap - 1)]
@@ -979,7 +914,7 @@ class BroadcastHashJoinChainExec(TpuExec):
     Each absorbed join ("hop") keeps its BroadcastExchangeExec child in the
     plan tree; this node takes over the probe side. When every hop's build
     turns out unique-keyed at run time (`_JoinCore.chain_capable`: dense /
-    one / pallas_hash probe modes), a stream row matches at most one build
+    one probe modes), a stream row matches at most one build
     row per hop, so stream capacity statically bounds every intermediate —
     probe -> gather -> probe -> gather -> compact runs as one dispatch per
     batch instead of (project + probe + emit) per hop. The output lands at a

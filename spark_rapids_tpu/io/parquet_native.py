@@ -582,22 +582,16 @@ def _page_spec_and_args(packed: bytes, bw: int, def_levels, dict_dev,
     import jax.numpy as jnp
     from spark_rapids_tpu.columnar.vector import bucket_capacity
     from spark_rapids_tpu.ops import parquet_decode as PD
-    from spark_rapids_tpu.ops import pallas_kernels as PK
 
     n_present = int(def_levels.sum())
     pcap = max(bucket_capacity(max(n_present, 1)), 8)
     bcap = max(bucket_capacity(max(len(packed), 1)), 8)
-    use_pallas = PK.should_use("bitunpack")     # probe OUTSIDE the traced program
     st, want, default = _type_facts(pages, spark_type)
-    # n_present is only STATIC under pallas (tile shapes); zeroing it
-    # otherwise keeps the non-pallas compile cache shared across present
-    # counts, exactly like the pre-spec key did
+    # the present count is an operand, not part of the spec: pages that
+    # differ in it alone share one compiled program
     spec = PD.EncodedPageSpec(bw, pcap, bcap, capacity, str(want),
-                              pages.physical_type == "BYTE_ARRAY", default,
-                              use_pallas, n_present if use_pallas else 0)
-    packed_h = np.frombuffer(packed, np.uint8)
-    packed_in = jnp.asarray(PK.bytes_to_words_u32(packed_h) if use_pallas
-                            else _padded(packed_h, bcap))
+                              pages.physical_type == "BYTE_ARRAY", default)
+    packed_in = jnp.asarray(_padded(np.frombuffer(packed, np.uint8), bcap))
     n = min(num_values, pages.num_values, capacity)
     args = (packed_in, dict_dev,
             jnp.asarray(_padded(def_levels.astype(bool), capacity)),

@@ -2,35 +2,26 @@
 
 SURVEY.md §7 design stance: XLA fuses the dense columnar math; Pallas covers
 the parts XLA lowers poorly on TPU — byte-level bit twiddling with per-row
-data-dependent control (string murmur3) and bit-packed decode (parquet
-RLE_DICTIONARY indices). Reference analogs: cudf's murmur3 device hash
-(GpuHashPartitioning.scala:92 depends on it) and libcudf's parquet index
-decoder (GpuParquetScan.scala:1235 `Table.readParquet`).
+data-dependent control (string murmur3), the medium-domain one-hot group-by
+and the counting-sort partition step. Reference analog: cudf's murmur3
+device hash (GpuHashPartitioning.scala:92 depends on it).
 
-Both kernels are lane-static reformulations — no dynamic gathers, which
+The kernels are lane-static reformulations — no dynamic gathers, which
 Mosaic lowers badly:
 
 * ``murmur3_words``: rows tile over the grid; the word loop and the
   per-row tail-byte selection unroll over static columns with vector
   selects, so each (TILE, W) block is pure VPU work.
-* ``bitunpack128``: 128 consecutive bit-packed values of width ``bw``
-  occupy exactly ``4*bw`` 32-bit words, so value lane j always reads word
-  ``(j*bw)>>5`` — a static column index. The unpack becomes a per-lane
-  shift/mask over statically-selected columns: zero gathers.
+* ``onehot_sum_f32``: bucket sums over a medium code domain as a blocked
+  one-hot matmul whose (BK, 128) tiles are made in VMEM and fed to the MXU.
 * ``radix_ranks``: stable counting-sort ranks over a small partition domain
   as dense (BK, DP) one-hot cumsums, with the sequential TPU grid carrying
-  the per-partition running count between row tiles. Backs both the
-  exchange partition step (GpuPartitioning.sliceInternalOnGpu analog) and
-  the hash-table build.
-* ``hash_join_build``/``hash_join_probe``: the cudf innerJoinGatherMaps
-  analog (GpuHashJoin.scala:289) for unique fixed-point keys — an open
-  (H, HJ_SLOTS) hash table whose build is a radix partition by Fibonacci
-  hash bucket and whose probe unrolls the slot loop statically over a
-  VMEM-resident table.
+  the per-partition running count between row tiles. Backs the exchange
+  partition step (GpuPartitioning.sliceInternalOnGpu analog).
 
 Dispatch: compiled on TPU; ``interpret=True`` elsewhere (tests force the
-CPU platform). The jnp reference implementations in ops/hashing.py and
-ops/parquet_decode.py remain the oracle and the fallback.
+CPU platform). The jnp reference implementations in ops/hashing.py,
+ops/grouping.py and ops/sorting.py remain the oracle and the fallback.
 """
 
 from __future__ import annotations
@@ -56,40 +47,17 @@ _I0 = np.int32(0)
 
 
 # dispatch switch: None = auto (the table below, on the TPU backend); True
-# forces the kernels (interpret-mode off-TPU — tests); False forces the jnp
-# paths (spark.rapids.tpu.sql.pallas.enabled=false)
+# forces the kernels (interpret mode off the TPU: the tests' way in); False
+# forces the jnp paths
 _FORCE: bool | None = None
 
 # The one switch table: kernel -> None (on) or the reason it is off. "On"
 # means the chip's compiler accepted the kernel at the shapes the TPC-H SF 1
 # main path uses (tests/test_tpu_compile.py compiles each for a described
 # v5e). A kernel that is on and fails to compile on the chip raises the
-# compiler's error; nothing latches it off at run time. A kernel that is off
-# carries its reason: the compiler's message (its compile test is then a
-# strict xfail quoting it), or, after "chip:", what a run on the chip showed
-# of a kernel that does compile.
+# compiler's error; nothing latches it off at run time.
 KERNELS: dict[str, str | None] = {
-    # compiles, and is wrong on the chip: a smoke run on a TPU v5 lite
-    # (PR 24) unpacked 1 Mi random values per width and compared with NumPy.
-    # Widths 1-16 came back exact; every width 17-31 had wrong values (135 of
-    # 1 Mi at width 17, 1971 at width 24), always a value that straddles two
-    # words with its low part 16 bits or longer, always with bits >= 16
-    # missing (got 11459, want 76995). Interpret mode is exact at every
-    # width, and so is ops/parquet_decode.unpack_bits_device on the chip. It
-    # made TPC-H q5 wrong (c/s_nationkey and l_suppkey pages).
-    "bitunpack": (
-        "chip: Mosaic accepts the kernel but on a TPU v5 lite it drops bits "
-        ">= 16 of values that straddle two words, for every bit width "
-        "17-31 (135 wrong of 1 Mi at width 17; interpret mode is exact)"),
     "radix": None,
-    # build compiles (it rides radix_ranks); the probe does not
-    "hashjoin": (
-        "hash_join_probe: RecursionError: maximum recursion depth exceeded "
-        "in Mosaic lowering (the kernel is 64-bit: int64 key refs and a "
-        "64-bit multiply hash; the integer convert rule has no 64-bit "
-        "case). With keys split into int32 lanes and the hash moved out, "
-        "the per-row table gather tk[base + s] is refused: "
-        "NotImplementedError: Only 2D gather is supported"),
     "onehot": None,
     "murmur3": None,
 }
@@ -211,78 +179,6 @@ def murmur3_words(words, lengths, seed) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# parquet bit-unpack
-# ---------------------------------------------------------------------------
-
-_UNPACK_TILE = 64  # rows of 128 values → 8192 values per grid step
-
-
-def _bitunpack_kernel(w_ref, out_ref, *, bw: int):
-    w = w_ref[:]                              # (T, 4*bw) int32 words
-    mask = jnp.int32((1 << bw) - 1) if bw < 32 else jnp.int32(-1)
-    cols = []
-    for j in range(128):
-        off = j * bw
-        w0, sh = off >> 5, off & 31
-        v = lax.shift_right_logical(w[:, w0:w0 + 1], jnp.int32(sh))
-        if sh + bw > 32:                      # value spans two words
-            v = v | lax.shift_left(w[:, w0 + 1:w0 + 2], jnp.int32(32 - sh))
-        cols.append(v & mask)
-    out_ref[:] = jnp.concatenate(cols, axis=1)
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def bitunpack128(words_u32, bit_width: int, n: int, capacity: int):
-    """Unpack `n` bit-packed values of `bit_width` bits from 32-bit words
-    into (capacity,) int32. 128 values of width bw span exactly 4*bw words,
-    so the kernel reads only statically-indexed columns.
-
-    words_u32: (ceil(n/128)*4*bw,) int32 — packed little-endian words.
-
-    Jitted on its static arguments: an eager caller then compiles once per
-    shape, where a bare eager pallas_call is re-lowered by Mosaic on every
-    call.
-    """
-    if not 1 <= bit_width <= 32:
-        raise ValueError(f"bit width {bit_width} out of range")
-    _traced["bitunpack"] += 1
-    bw = bit_width
-    n128 = max(1, -(-n // 128))
-    tile = min(_UNPACK_TILE, n128)
-    rows = -(-n128 // tile) * tile
-    need = rows * 4 * bw
-    # a legal parquet chunk's final bit-packed run may declare more 8-value
-    # groups than remaining values — the packed buffer can be LONGER than
-    # `need`; truncate before writing into the padded buffer
-    k = min(words_u32.shape[0], need)
-    w = jnp.zeros((need,), jnp.int32).at[:k].set(
-        words_u32[:k].astype(jnp.int32)).reshape(rows, 4 * bw)
-    out = pl.pallas_call(
-        functools.partial(_bitunpack_kernel, bw=bw),
-        out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int32),
-        grid=(rows // tile,),
-        in_specs=[pl.BlockSpec((tile, 4 * bw), lambda i: (i, _I0))],
-        out_specs=pl.BlockSpec((tile, 128), lambda i: (i, _I0)),
-        interpret=_interpret(),
-        name="srt_pallas_bitunpack",
-    )(w)
-    flat = out.reshape(-1)
-    idx = jnp.arange(capacity, dtype=jnp.int32)
-    safe = jnp.clip(idx, 0, flat.shape[0] - 1)
-    return jnp.where(idx < n, flat[safe], 0)
-
-
-def bytes_to_words_u32(packed: np.ndarray) -> np.ndarray:
-    """Host prep: pad a uint8 byte buffer to 4-byte alignment and view as
-    little-endian int32 words for bitunpack128."""
-    nb = len(packed)
-    pad = -nb % 4
-    if pad:
-        packed = np.concatenate([packed, np.zeros(pad, np.uint8)])
-    return packed.view("<i4").astype(np.int32)
-
-
-# ---------------------------------------------------------------------------
 # blocked one-hot matmul (medium-domain dense group-by / histogram)
 # ---------------------------------------------------------------------------
 
@@ -354,7 +250,7 @@ def onehot_sum_f32(vals, codes, n_domain: int):
 
 _RP_BK = 256            # max rows per grid step
 _RP_TILE_BUDGET = 1 << 19  # one-hot tile elements (2 MB i32): bk*dp bound
-RADIX_MAX_PARTS = 4096  # lane cap (hash_join_buckets tops out here)
+RADIX_MAX_PARTS = 4096  # lane cap
 
 
 def _radix_kernel(ids_ref, rank_ref, counts_ref, *, bk: int, dp: int):
@@ -431,122 +327,3 @@ def radix_partition_permutation(ids, num_lanes: int):
     dest = offsets[jnp.clip(ids, 0, num_lanes - 1)] + ranks
     return jnp.zeros((cap,), jnp.int32).at[dest].set(
         jnp.arange(cap, dtype=jnp.int32), mode="drop")
-
-
-# ---------------------------------------------------------------------------
-# VMEM hash-table join build + probe (unique fixed-point keys)
-# ---------------------------------------------------------------------------
-
-HJ_SLOTS = 8            # bucket capacity; build falls back above this load
-_HJ_TILE = 8192         # stream rows per grid step: big tiles keep the grid
-#                         short (interpret mode pays per-step overhead; the
-#                         (tile, HJ_SLOTS) gather is ~512 KB in VMEM)
-_HJ_EMPTY = np.int64(np.iinfo(np.int64).min)  # slot sentinel (engage gate
-#                                               requires vmin > int64 min)
-# Fibonacci multiplicative constant 0x9E3779B97F4A7C15 as a signed int64
-_HJ_MULT = np.int64(np.uint64(0x9E3779B97F4A7C15).astype(np.int64))
-
-
-def _hj_bucket(vals_i64, h_bits: int):
-    h = vals_i64 * _HJ_MULT
-    return lax.shift_right_logical(h, jnp.int64(64 - h_bits)).astype(jnp.int32)
-
-
-def hash_join_build(keys_i64, eligible, num_buckets: int):
-    """Build the (num_buckets, HJ_SLOTS) open hash table over unique int64
-    keys: bucket = Fibonacci hash of the key, slot = the key's stable radix
-    rank within its bucket (the radix kernel again — build IS a radix
-    partition by hash bucket). Returns (table_keys, table_rows, ok) flat
-    (H*S,) arrays + a device scalar; ok=False means a bucket overflowed
-    HJ_SLOTS and the table must be discarded (caller falls back to the
-    searchsorted probe). cudf's innerJoinGatherMaps builds the same shape
-    with atomics (GpuHashJoin.scala:289); here the bucket ranks come from
-    the sequential-grid carry chain instead."""
-    if num_buckets & (num_buckets - 1) or num_buckets < 128:
-        raise ValueError(f"num_buckets {num_buckets}: need a power of two >= 128")
-    h_bits = num_buckets.bit_length() - 1
-    cap = keys_i64.shape[0]
-    bucket = jnp.where(eligible, _hj_bucket(keys_i64, h_bits),
-                       jnp.int32(num_buckets))            # sentinel lane
-    ranks, counts = radix_ranks(bucket, num_buckets)
-    ok = jnp.max(counts) <= HJ_SLOTS
-    slot = bucket * HJ_SLOTS + jnp.minimum(ranks, HJ_SLOTS - 1)
-    slot = jnp.where(eligible, slot, jnp.int32(num_buckets * HJ_SLOTS))
-    table_keys = jnp.full((num_buckets * HJ_SLOTS,), _HJ_EMPTY,
-                          jnp.int64).at[slot].set(keys_i64, mode="drop")
-    table_rows = jnp.full((num_buckets * HJ_SLOTS,), -1,
-                          jnp.int32).at[slot].set(
-        jnp.arange(cap, dtype=jnp.int32), mode="drop")
-    # duplicate keys land in one bucket (same hash) with distinct ranks: the
-    # unique-keys probe contract would silently under-count them, so the
-    # build refuses — S*(S-1)/2 static column compares over the table
-    t2 = table_keys.reshape(num_buckets, HJ_SLOTS)
-    dup = jnp.zeros((), jnp.bool_)
-    for s in range(HJ_SLOTS):
-        for t in range(s + 1, HJ_SLOTS):
-            dup = dup | jnp.any((t2[:, s] == t2[:, t])
-                                & (t2[:, s] != _HJ_EMPTY))
-    return table_keys, table_rows, ok & ~dup
-
-
-def _hash_probe_kernel(sk_ref, tk_ref, tr_ref, pos_ref, found_ref,
-                       *, h_bits: int):
-    """Probe one stream tile against the whole table (resident in VMEM —
-    both table blocks map to (0, 0) every grid step). The slot loop unrolls
-    statically; the only dynamic access is the per-row bucket gather, the
-    same class as the engine's dictionary-decode gathers."""
-    svals = sk_ref[0, :]                                  # (T,) int64
-    base = _hj_bucket(svals, h_bits) * HJ_SLOTS
-    tk = tk_ref[0, :]
-    tr = tr_ref[0, :]
-    pos = jnp.full(svals.shape, -1, jnp.int32)
-    found = jnp.zeros(svals.shape, jnp.bool_)
-    for s in range(HJ_SLOTS):
-        cand = tk[base + s]
-        hit = cand == svals                               # EMPTY never matches
-        pos = jnp.where(hit, tr[base + s], pos)
-        found = found | hit
-    pos_ref[0, :] = pos
-    found_ref[0, :] = found.astype(jnp.int32)
-
-
-def hash_join_probe(table_keys, table_rows, stream_i64, num_buckets: int):
-    """(build_row, found) per stream key — the innerJoinGatherMaps probe.
-    Unique-keys contract: at most one slot matches. Validity/liveness
-    masking is the caller's job (hash of an invalid row's value is
-    harmless; its hit is masked off outside)."""
-    _traced["hashjoin"] += 1
-    h_bits = num_buckets.bit_length() - 1
-    n = stream_i64.shape[0]
-    tile = min(_HJ_TILE, max(8, n))
-    n_pad = -(-n // tile) * tile
-    hs = num_buckets * HJ_SLOTS
-    sp = jnp.zeros((1, n_pad), jnp.int64).at[0, :n].set(stream_i64)
-    pos, found = pl.pallas_call(
-        functools.partial(_hash_probe_kernel, h_bits=h_bits),
-        out_shape=[jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((1, n_pad), jnp.int32)],
-        grid=(n_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((1, tile), lambda i: (_I0, i)),
-            pl.BlockSpec((1, hs), lambda i: (_I0, _I0)),
-            pl.BlockSpec((1, hs), lambda i: (_I0, _I0)),
-        ],
-        out_specs=[pl.BlockSpec((1, tile), lambda i: (_I0, i)),
-                   pl.BlockSpec((1, tile), lambda i: (_I0, i))],
-        interpret=_interpret(),
-        name="srt_pallas_hashjoin",
-    )(sp, table_keys.reshape(1, hs), table_rows.reshape(1, hs))
-    return pos[0, :n], found[0, :n].astype(jnp.bool_)
-
-
-def hash_join_buckets(n_build: int) -> int:
-    """Bucket count for a build of `n_build` rows: ~0.25 load factor over
-    HJ_SLOTS-deep buckets, clamped to the VMEM table budget. Returns 0 when
-    the build cannot meet the load factor (too big — caller falls back)."""
-    want = 128
-    while want * HJ_SLOTS < 4 * max(n_build, 1) and want < 4096:
-        want *= 2
-    if want * HJ_SLOTS < 2 * n_build:   # >0.5 load: overflow too likely
-        return 0
-    return want

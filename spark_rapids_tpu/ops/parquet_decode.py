@@ -23,13 +23,11 @@ class EncodedPageSpec(typing.NamedTuple):
     same spec share one compiled program regardless of their byte content."""
     bit_width: int
     pcap: int          # present-value capacity bucket
-    bcap: int          # packed-byte capacity bucket (0 under pallas words)
+    bcap: int          # packed-byte capacity bucket
     capacity: int      # output row capacity bucket
     want: str          # decoded value dtype name (int32 codes for strings)
     is_string: bool
     default: object    # canonical fill for invalid slots
-    use_pallas: bool
-    n_present: int     # static present count (pallas tile shapes need it)
 
 
 def unpack_bits_device(packed: jnp.ndarray, bit_width: int, n: int,
@@ -154,18 +152,11 @@ def decode_page_cols(spec: EncodedPageSpec, packed_d, dict_d, dl_d,
     the standalone fused decode kernel (io/parquet_native.py) and the
     encoded-upload consumers (columnar/encoded.py, exec/aggregate.py) all
     trace THIS body, so encoded-vs-dense results are bit-identical by
-    construction. Device args: packed bytes (or pallas words), the device
+    construction. Device args: the packed bytes, the device
     dictionary, def-levels as bool (capacity,), and int32 scalars for the
     present/live counts."""
-    if spec.use_pallas:
-        from spark_rapids_tpu.ops import pallas_kernels as PK
-        # pallas tile shapes need the STATIC present count (part of the spec,
-        # hence part of every cache key that embeds the spec)
-        idx = PK.bitunpack128(packed_d, spec.bit_width, spec.n_present,
-                              spec.pcap)
-    else:
-        idx = unpack_bits_device(packed_d, spec.bit_width, n_present_t,
-                                 spec.pcap)
+    idx = unpack_bits_device(packed_d, spec.bit_width, n_present_t,
+                             spec.pcap)
     return _indices_to_rows(spec, idx, dict_d, dl_d, n_t)
 
 

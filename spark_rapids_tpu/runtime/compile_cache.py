@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule, shared by chip_smoke.py, bench.py, tests/conftest.py and
+One rule, shared by chip_smoke.py, benchmark/run.py, tests/conftest.py and
 __graft_entry__.py: the environment decides, and otherwise the path is fixed.
 The path is part of the cache key, so a directory that moves never hits.
 

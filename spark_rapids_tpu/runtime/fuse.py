@@ -208,7 +208,9 @@ _backend_tag_memo = None
 # the traced HLO, so a stale store replaying an old program would be a silent
 # wrong answer — the version tag turns it into a cache miss instead.
 # 2: programs carry their kernel's name (jit_srt_<name>) and operator scopes.
-KERNEL_CACHE_VERSION = 2
+# 3: the join probe, the join chain and the single-page decode take fewer
+# operands (no hash-table dummies, no static present count).
+KERNEL_CACHE_VERSION = 3
 
 
 def _backend_tag() -> str:

@@ -97,7 +97,8 @@ CLIENT_DISCONNECTS = "clientDisconnects"
 # memory observability plane (runtime/memory.py): catalog buffers a finished
 # query left behind, caught + reclaimed by the end-of-query leak detector.
 # Riding the resilience registry makes leak-freedom a standing CI invariant:
-# the no-faults bench gates already assert every counter here is zero
+# the no-faults phases of the chaos gates (tools/fleet_chaos.py) assert
+# every counter here is zero
 MEMORY_LEAKS = "memoryLeakedBuffers"
 # serving fleet (runtime/fleet.py): a survivor's sweeper adopted a dead
 # replica's expired lease — unlinked the membership record and reclaimed its
@@ -211,8 +212,8 @@ class MetricsRegistry:
 # -- process-wide resilience registry ----------------------------------------
 # Retry/split/fetch-failover counts outlive any one operator's registry (a
 # retry may span operator teardown), so they accumulate here; chaos tests
-# (tests/test_retry_faults.py) and bench.py's `resilience` JSON field read
-# whole-query totals from this registry.
+# (tests/test_retry_faults.py) and the STATS exposition
+# (`srt_resilience_total`) read whole-query totals from this registry.
 
 _global_registry: "MetricsRegistry | None" = None
 _global_lock = threading.Lock()
@@ -233,7 +234,7 @@ def reset_global_registry() -> None:
 
 
 def resilience_snapshot() -> dict:
-    """All resilience counters (zeros included) — the shape bench.py records."""
+    """All resilience counters (zeros included)."""
     g = global_registry()
     return {name: g.metric(name).value for name in RESILIENCE_METRICS}
 
@@ -558,7 +559,7 @@ class QueryMetricsCollector:
         self._shuffle_stats: list[dict] = []
         # per-query mirror of the movement ledger (runtime/movement.py):
         # (edge, link) -> [bytes, payload_bytes, transfers] — the query.end
-        # movement section and bench.py's movement summary read this
+        # movement section reads this
         self._movement: dict = {}
         # admission footprint info ({estimate, static, history_hit,
         # fingerprint, ...}) set at submit; plan.stats payload set at finish

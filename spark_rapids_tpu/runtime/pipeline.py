@@ -5,8 +5,8 @@ The reference engine gets stage overlap for free: CUDA kernel launches are
 asynchronous on streams and UCX runs an async progress thread (SURVEY.md L0),
 so its pull-based iterator chain still pipelines at the hardware level. Here
 XLA dispatch is synchronous per program and host arrow decode shares the
-query thread, so BENCH_r06 found the engine overhead-bound — parquet decode,
-device compute and exchange serialization run strictly sequentially
+query thread, so the engine was overhead-bound — parquet decode,
+device compute and exchange serialization ran strictly sequentially
 (an XLA:CPU profile). This module supplies the missing concurrency
 EXPLICITLY: physical plans are cut into segments at the existing pipeline
 breakers (scan, exchange map/reduce, join build, sort, final collect) and

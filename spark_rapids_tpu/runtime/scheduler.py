@@ -215,8 +215,7 @@ def check_cancel() -> None:
 
 # defaults when no conf reaches the estimator; the knobs are
 # scheduler.footprint.{decodeExpansion,floorBytes} (config.py). 3x is the
-# round-number decode expansion BASELINE.md's scan measurements showed for
-# TPC-H
+# round-number decode expansion that scans of TPC-H's Parquet showed
 _DECODE_EXPANSION = 3.0
 # every pipeline breaker (join build / agg / sort / exchange) holds an extra
 # working set of roughly one batch stream alongside the scan
@@ -489,8 +488,8 @@ class QueryScheduler:
                 raise
             waited = time.monotonic() - t.enqueue_t
             running = len(self._running)
-        # admission queue-wait distribution (STATS histograms / bench
-        # percentiles): observed once per admitted query
+        # admission queue-wait distribution (STATS histograms, which
+        # benchmark/run.py's admission_wait_ms reads): once per admitted query
         M.histogram("admission.wait").observe(waited)
         EL.emit("query.admitted", query=query_id,
                 estimate_bytes=t.estimate, priority=t.priority,

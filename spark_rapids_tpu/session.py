@@ -81,7 +81,7 @@ def _abort_execs(collector) -> None:
 def _finish_query_memory(collector, conf, leak_check: bool = True):
     """Memory-plane epilogue of one action (runtime/memory.py): pop the
     query's allocation-site accounting into ``collector.memory`` (peak +
-    per-site breakdown — bench.py and the query.end event embed it), run
+    per-site breakdown — the query.end event embeds it), run
     the end-of-query leak detector (event + resilience counter + reclaim)
     and emit a full heap snapshot into the event log. Idempotent per
     collector (success and error paths both call it; first wins) and a
@@ -393,7 +393,7 @@ class DataFrame:
                 # admission footprint: per-shape observed history when the
                 # store has seen this plan's fingerprint, else the static
                 # scan-bytes heuristic (stats plane; provenance kept on the
-                # collector for plan.stats / bench / explain(stats=True))
+                # collector for plan.stats / explain(stats=True))
                 collector.footprint = SCHED.estimate_footprint_ex(plan, conf)
                 if plan_span:
                     plan_span.set(**_plan_counts(hybrid))
@@ -770,13 +770,6 @@ class TpuSession:
         self._catalog_epoch = 0
         self.udf = UDFRegistration(self)
         from spark_rapids_tpu import config as CFG
-        from spark_rapids_tpu.ops import pallas_kernels as PK
-        # the Pallas dispatch is process-global (like the reference's
-        # executor-plugin init): only an EXPLICIT conf setting touches it, so
-        # constructing a default session never overrides another session's
-        # explicit choice
-        if CFG.PALLAS_ENABLED.key in self.conf.settings:
-            PK.set_mode(None if self.conf.get(CFG.PALLAS_ENABLED) else False)
         # plugin bootstrap: config fixup/version check once per process;
         # eager device acquisition when conf'd (reference Plugin.scala flow)
         from spark_rapids_tpu import plugin as PL
@@ -784,8 +777,8 @@ class TpuSession:
         # tracing (NVTX analog): profiler annotations around hot regions,
         # optional whole-session XProf capture (reference nvtx_profiling.md)
         from spark_rapids_tpu.runtime import tracing
-        # process-global like the Pallas switch: only an EXPLICIT setting
-        # touches it, so a default session never clobbers another's choice
+        # process-global: only an EXPLICIT setting touches it, so a default
+        # session never clobbers another's choice
         if CFG.TRACE_ENABLED.key in self.conf.settings:
             tracing.set_enabled(self.conf.get(CFG.TRACE_ENABLED))
         pdir = self.conf.get(CFG.PROFILE_DIR)

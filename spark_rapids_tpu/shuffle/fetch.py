@@ -16,8 +16,7 @@ Retries back off EXPONENTIALLY with jitter and a hard cap (a linear,
 jitter-free backoff synchronizes a fleet of failed fetchers into retry
 stampedes against a recovering peer), and every retry/failover/recompute is
 counted into the process-wide resilience registry
-(runtime/metrics.global_registry) so chaos tests and bench.py can assert on
-them.
+(runtime/metrics.global_registry) so chaos tests can assert on them.
 """
 
 from __future__ import annotations

@@ -19,8 +19,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-# persistent XLA compile cache (the same helper chip_smoke.py, bench.py and
-# the driver entry points use): a cold full suite on a small box is mostly
+# persistent XLA compile cache (the same helper chip_smoke.py, benchmark/run.py
+# and the driver entry points use): a cold full suite on a small box is mostly
 # LLVM compilation; repeated runs reload executables instead of re-compiling
 from spark_rapids_tpu.runtime import compile_cache  # noqa: E402
 

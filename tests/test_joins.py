@@ -413,12 +413,14 @@ def spans():
     tracing.drain()
 
 
-I32, I64 = np.iinfo(np.int32), np.iinfo(np.int64)
+NO_KEY = "dense_every_build_key_null"
+I16, I32, I64 = np.iinfo(np.int16), np.iinfo(np.int32), np.iinfo(np.int64)
 
 
 def _keys(vals, nulls, typ):
-    return pa.array([None if m else int(v) for v, m in zip(vals, nulls)],
-                    type=typ)
+    # the value stays in the buffer beneath a null: a probe for it must miss
+    return pa.array(np.asarray(vals, dtype=typ.to_pandas_dtype()),
+                    mask=np.asarray(nulls), type=typ)
 
 
 def _mode_case(name):
@@ -428,6 +430,7 @@ def _mode_case(name):
     r = np.random.default_rng(sum(map(ord, name)))
     nb, ns = 300, 700
     btype = stype = pa.int64()
+    b_nulls = 0.1
     if name == "dense_negative_far_from_zero":
         bk = -7_000_000_000 + r.permutation(900)[:nb]
     elif name == "dense_int32_build_int64_stream":
@@ -439,6 +442,23 @@ def _mode_case(name):
         bk = I64.max - r.permutation(900)[:nb]
     elif name == "one_over_the_budget":
         bk = -5 + r.permutation(900)[:nb] * (1 << 33)
+    elif name == "one_int32_build_sparse":
+        # unique over most of its dtype's range: no table fits the budget
+        bk = I32.min // 2 + r.permutation(900)[:nb] * (1 << 21)
+        btype = pa.int32()
+    elif name == "dense_int16_build_sparse":
+        # the whole of a 16-bit domain is under the budget: always a table
+        bk = I16.min + r.permutation(900)[:nb] * 72
+        btype = pa.int16()
+    elif name == "one_null_keys_probed_by_value":
+        # a third of the build is null, the rows of its capacity past
+        # n_build are padding; the stream asks for every value beneath
+        bk = r.permutation(900)[:nb] * (1 << 25)
+        b_nulls = 0.3
+    elif name == NO_KEY:
+        # rows and no key: nothing matches, whatever lies beneath
+        bk = 40 + r.permutation(900)[:nb]
+        b_nulls = 2.0
     elif name == "two_duplicate_keys":
         bk = 40 + r.integers(0, 90, nb)
     else:
@@ -447,9 +467,9 @@ def _mode_case(name):
     far = [I64.min, I64.max, I64.min + 1, int(bk.min()) - 1, 0, -1,
            int(bk.max() % (1 << 32)) + (1 << 32)]
     far += [int(bk.max()) + 1] if int(bk.max()) < I64.max else []
-    sk = np.concatenate([r.choice(bk, ns - len(far)),
+    sk = np.concatenate([bk, r.choice(bk, ns - nb - len(far)),
                          np.asarray(far, dtype=np.int64)])
-    bt = {"rk": _keys(bk, r.random(nb) < 0.1, btype),
+    bt = {"rk": _keys(bk, r.random(nb) < b_nulls, btype),
           "rv": pa.array(np.arange(nb), type=pa.int32())}
     st = {"lk": _keys(sk, r.random(ns) < 0.1, stype),
           "lv": pa.array(np.arange(ns), type=pa.int32())}
@@ -462,7 +482,9 @@ def _mode_case(name):
 
 
 MODE_CASES = ["dense_negative_far_from_zero", "dense_int32_build_int64_stream",
-              "dense_int64_max", "one_over_the_budget", "two_duplicate_keys",
+              "dense_int64_max", "one_over_the_budget", "one_int32_build_sparse",
+              "dense_int16_build_sparse", "one_null_keys_probed_by_value",
+              NO_KEY, "two_duplicate_keys",
               "rank_two_keys"]
 
 
@@ -506,12 +528,13 @@ def test_probe_mode_is_chosen_from_the_build_and_matches_numpy(
                      ArrowScanExec([bt], conf=conf))
     got = _sorted_rows(j.execute_collect().to_pylist())
     assert got == ref_join_rows(st, bt, lkeys, rkeys, how)
-    assert len(got) > 0
+    assert len(got) > 0 or (case == NO_KEY and how in ("inner", "leftsemi"))
     (prep,) = _span_counts(spans, "HashJoin.build_prep")
     assert prep["mode"] == mode, prep
     assert {p["mode"] for p in _span_counts(spans, "HashJoin.probe")} == {mode}
     if mode == "dense":
-        assert prep["table_slots"] >= prep["domain"] > 0
+        assert prep["table_slots"] >= prep["domain"]
+        assert (prep["domain"] > 0) == (case != NO_KEY)
     else:
         assert prep["table_slots"] == 0
         # over the budget the code has: max(4 x capacity, 4 Mi)
@@ -541,7 +564,7 @@ def _chain_reference(st, bt, b2):
     return ref_join_rows(first, b2, ["lv"], ["k2"], "inner")
 
 
-@pytest.mark.parametrize("case", MODE_CASES[:-1])
+@pytest.mark.parametrize("case", MODE_CASES[:-1])   # single keys
 def test_probe_modes_through_the_join_chain(case, chip_answers, spans):
     from spark_rapids_tpu.exec.joins import BroadcastHashJoinChainExec
     bt, st, lkeys, rkeys, mode = _mode_case(case)
@@ -549,7 +572,7 @@ def test_probe_modes_through_the_join_chain(case, chip_answers, spans):
     assert isinstance(chain, BroadcastHashJoinChainExec)
     got = _sorted_rows(chain.execute_collect().to_pylist())
     assert got == _chain_reference(st, bt, b2)
-    assert len(got) > 0
+    assert len(got) > 0 or case == NO_KEY
     assert [p["mode"] for p in _span_counts(spans, "HashJoin.build_prep")] \
         == [mode, "dense"]
     fused = _span_counts(spans, "HashJoinChain.probe")

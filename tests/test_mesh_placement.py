@@ -85,11 +85,10 @@ def compile_watch():
 
 def test_q3_text_over_the_mesh_equals_reference_and_one_device(paths,
                                                                mesh_q3):
-    from bench import CHECKS
     spark, rows = mesh_q3
     df = spark.sql(SQL_QUERIES["q3"])
     assert physical_tree(df).count("MeshExchangeExec") >= 5
-    CHECKS["q3"](rows, tpch.np_q3(tpch.load_np(paths)))
+    tpch.CHECKS["q3"](rows, tpch.np_q3(tpch.load_np(paths)))
     single = session_over(paths, {}).sql(SQL_QUERIES["q3"]).collect()
     assert len(rows) == single.num_rows == 10
     for got, want in zip(rows, single.to_pylist()):

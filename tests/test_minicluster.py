@@ -86,7 +86,6 @@ def test_cluster_global_sort(cluster, spark):
 
 def test_cluster_tpch_q3(cluster, spark, tmp_path_factory):
     from spark_rapids_tpu.benchmarks import tpch
-    import bench
     outdir = str(tmp_path_factory.mktemp("tpch_cluster"))
     paths = tpch.generate(0.01, outdir)
     dfs = tpch.load(spark, paths, files_per_partition=2)
@@ -94,7 +93,7 @@ def test_cluster_tpch_q3(cluster, spark, tmp_path_factory):
     df = tpch.QUERIES["q3"](dfs)
     got = cluster.collect(df).to_pylist()
     exp = tpch.np_q3(tb)
-    bench.CHECKS["q3"](got, exp)
+    tpch.CHECKS["q3"](got, exp)
 
 
 def test_cluster_union_scan_with_shuffle_parallelism(cluster, spark):

@@ -135,22 +135,40 @@ def test_device_decode_conf_off_matches(unc_file):
         assert on.column(name).to_pylist() == off.column(name).to_pylist()
 
 
-def test_unpack_bits_widths():
-    """Device bit-unpack against a numpy reference for every width 1..32."""
+def _pack_bits(vals, bw):
+    """Value i at bits [i*bw, (i+1)*bw), little-endian bit order."""
+    bits = np.zeros(len(vals) * bw, dtype=np.uint8)
+    for i, v in enumerate(vals):
+        for b in range(bw):
+            bits[i * bw + b] = (int(v) >> b) & 1
+    return np.packbits(bits, bitorder="little")
+
+
+@pytest.mark.parametrize("bw", range(1, 33))
+def test_unpack_bits_widths(bw):
+    """Device bit-unpack against a numpy reference, one case a width 1..32
+    (what the chip runs for every bit-packed page), 300 values into a
+    capacity of 512 whose tail is zero."""
     import jax.numpy as jnp
     from spark_rapids_tpu.ops.parquet_decode import unpack_bits_device
-    r = np.random.default_rng(0)
-    for bw in [1, 2, 3, 5, 7, 8, 12, 16, 20, 24, 31, 32]:
-        n = 256
-        vals = r.integers(0, 1 << min(bw, 31), n, dtype=np.int64)
-        bits = np.zeros(n * bw, dtype=np.uint8)
-        for i, v in enumerate(vals):
-            for b in range(bw):
-                bits[i * bw + b] = (int(v) >> b) & 1
-        packed = np.packbits(bits, bitorder="little")
-        got = np.asarray(unpack_bits_device(
-            jnp.asarray(packed), bw, n, 256))[:n]
-        assert (got == vals.astype(np.int32)).all(), bw
+    n, cap = 300, 512
+    vals = np.random.default_rng(bw).integers(0, 1 << bw, n, dtype=np.int64)
+    got = np.asarray(unpack_bits_device(
+        jnp.asarray(_pack_bits(vals, bw)), bw, n, cap))
+    want = np.zeros(cap, dtype=np.int64)
+    want[:n] = vals
+    assert (got.astype(np.uint32) == want.astype(np.uint32)).all()
+
+
+def test_unpack_bits_tiny_run():
+    """Fewer values than one byte-aligned group of any width: 8 of width 4."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.parquet_decode import unpack_bits_device
+    vals = np.array([3, 9, 15, 0, 7, 1, 2, 4], dtype=np.int64)
+    got = np.asarray(unpack_bits_device(
+        jnp.asarray(_pack_bits(vals, 4)), 4, len(vals), 16))
+    assert list(got[:8]) == list(vals)
+    assert (got[8:] == 0).all()
 
 
 def test_native_scanner_matches_python_parser(tmp_path, monkeypatch):

@@ -25,7 +25,7 @@ def _rows(df):
 
 # every official text maps to (oracle fn, float columns) — the SQL-only
 # queries (set ops, cross-channel, rollup forms) carry their own oracles;
-# the rest reuse the DataFrame suite's. Shared with bench.py's SQL sweep.
+# the rest reuse the DataFrame suite's.
 _ORACLES = tpcds.sql_suite_oracles()
 
 

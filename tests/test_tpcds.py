@@ -1,5 +1,5 @@
 """TPC-DS subset end-to-end through the session API vs independent NumPy
-oracles (BASELINE.md config-3; reference qa_nightly_select_test role)."""
+oracles (reference qa_nightly_select_test role)."""
 
 import pytest
 
@@ -20,7 +20,6 @@ def _rows(df):
 
 
 def _check(got, exp, float_cols):
-    # single source of truth with bench.py's recorded sweep
     tpcds.check_rows(got, exp, float_cols)
 
 

@@ -1,5 +1,5 @@
 """TPC-H q1/q3/q5 end-to-end through the session API vs independent NumPy
-oracles (BASELINE.md config-2; reference mortgage-app role)."""
+oracles (reference mortgage-app role)."""
 
 import pytest
 

@@ -3,7 +3,7 @@
 The TPU compiler is installed here and compiles for a v5e that is described,
 not attached. Each case lowers one Pallas kernel, or one jitted XLA program
 that only exists on a TPU, at the shapes the TPC-H SF 1 main path uses
-(1 Mi-row scan batches, join tables from `hash_join_buckets`) and compiles it
+(1 Mi-row scan batches, the radix kernel's lane cap) and compiles it
 for one v5e chip. Nothing runs: a pass says the compiler accepts the program
 and that it fits the device, not that its results are right (the interpret
 mode tests in test_pallas.py cover the arithmetic, chip_smoke.py the chip).
@@ -82,26 +82,13 @@ def _fits(compiled) -> bool:
 
 def _kernel_cases():
     """(kernel, fn, [(shape, dtype)...]) at SF 1 shapes, one per table row."""
-    buckets = PK.hash_join_buckets(8192)       # 4096: the VMEM table cap
-
-    def hashjoin(keys, elig, stream):
-        tk, tr, ok = PK.hash_join_build(keys, elig, buckets)
-        return PK.hash_join_probe(tk, tr, stream, buckets), ok
-
     return {
-        # dictionary index widths of the SF 1 lineitem columns
-        "bitunpack": [
-            (lambda w, bw=bw: PK.bitunpack128(w, bw, N, N),
-             [((N // 128 * 4 * bw,), jnp.int32)]) for bw in (1, 6, 12, 20)],
-        # exchange partition step (<= 200 partitions) and the hash-table
-        # build's bucket ranks (4096 lanes)
+        # exchange partition step (<= 200 partitions) and the lane cap
         "radix": [
             (lambda ids: PK.radix_partition_permutation(ids, 200),
              [((N,), jnp.int32)]),
-            (lambda ids: PK.radix_ranks(ids, buckets), [((N,), jnp.int32)])],
-        "hashjoin": [
-            (hashjoin, [((8192,), jnp.int64), ((8192,), jnp.bool_),
-                        ((N,), jnp.int64)])],
+            (lambda ids: PK.radix_ranks(ids, PK.RADIX_MAX_PARTS),
+             [((N,), jnp.int32)])],
         "onehot": [
             (lambda v, c: PK.onehot_sum_f32(v, c, 1000),
              [((N,), jnp.float32), ((N,), jnp.int32)])],
@@ -111,23 +98,10 @@ def _kernel_cases():
     }
 
 
-def _kernel_params():
-    out = []
-    for name, why_off in PK.KERNELS.items():
-        # off for what a chip run showed ("chip: ..."): it still compiles
-        refused = why_off and not why_off.startswith("chip:")
-        marks = ([pytest.mark.xfail(strict=True, reason=why_off)]
-                 if refused else [])
-        out.append(pytest.param(name, id=name, marks=marks))
-    return out
-
-
-@pytest.mark.parametrize("kernel", _kernel_params())
+@pytest.mark.parametrize("kernel", list(PK.KERNELS))
 def test_pallas_kernel_compiles_for_v5e(kernel, one_chip, as_on_tpu):
-    """Every kernel in the switch table: on = Mosaic accepts it at SF 1
-    shapes and the kernel is in the program; off by the compiler = strict
-    xfail quoting it (a later PR that repairs it must flip the table); off
-    for what the chip showed = it must still compile."""
+    """Every kernel in the switch table: Mosaic accepts it at SF 1 shapes
+    and the kernel is in the program."""
     for fn, shapes in _kernel_cases()[kernel]:
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
@@ -139,8 +113,7 @@ def test_pallas_kernel_compiles_for_v5e(kernel, one_chip, as_on_tpu):
 def test_switch_table_names_every_routed_kernel():
     """should_use() is the table: an unknown name is an error, and off the
     TPU backend nothing is routed unless a test forces it."""
-    assert set(PK.KERNELS) == {"bitunpack", "radix", "hashjoin", "onehot",
-                               "murmur3"}
+    assert set(PK.KERNELS) == {"radix", "onehot", "murmur3"}
     with pytest.raises(KeyError):
         PK.should_use("no_such_kernel")
     assert not any(PK.should_use(k) for k in PK.KERNELS)   # cpu backend
@@ -207,26 +180,20 @@ def test_dense_join_probe_compiles_without_a_loop(one_chip, as_on_tpu):
 
 
 def test_parquet_dictionary_decode_compiles(one_chip, as_on_tpu):
-    """One encoded lineitem page → rows: bit-unpack (the Pallas kernel when
-    the table routes it, else the jnp form), dictionary gather,
-    definition-level spread (ops/parquet_decode.decode_page_cols)."""
+    """One encoded lineitem page → rows: the jnp bit-unpack, dictionary
+    gather, definition-level spread (ops/parquet_decode.decode_page_cols);
+    no hand-written kernel is in the program."""
     from spark_rapids_tpu.ops import parquet_decode as PD
     bw = 6
-    pallas = PK.KERNELS["bitunpack"] is None
-    packed = (jax.ShapeDtypeStruct((N // 128 * 4 * bw,), jnp.int32,
-                                   sharding=one_chip) if pallas else
-              jax.ShapeDtypeStruct((N * bw // 8,), jnp.uint8,
-                                   sharding=one_chip))
-    spec = PD.EncodedPageSpec(bw, N, 0 if pallas else N * bw // 8, N,
-                              "float64", False, 0.0, pallas, N)
+    spec = PD.EncodedPageSpec(bw, N, N * bw // 8, N, "float64", False, 0.0)
     s32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = _compile(
         lambda w, d, dl, npres, n: PD.decode_page_cols(spec, w, d, dl,
                                                        npres, n),
-        packed,
+        jax.ShapeDtypeStruct((N * bw // 8,), jnp.uint8, sharding=one_chip),
         jax.ShapeDtypeStruct((50,), jnp.float64, sharding=one_chip),
         jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=one_chip), s32, s32)
-    assert ("tpu_custom_call" in compiled.as_text()) == pallas
+    assert "tpu_custom_call" not in compiled.as_text()
     assert _fits(compiled)
 
 

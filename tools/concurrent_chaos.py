@@ -170,6 +170,9 @@ def main(argv=None) -> int:
         check(o.get("rows") == solo[name], f"{name} rows differ from solo")
         check(not o.get("resilience"),
               f"{name} resilience leaked: {o.get('resilience')}")
+    # each query ran under a scope of its own
+    qids = [o["query_id"] for o in outcomes.values() if "query_id" in o]
+    check(len(set(qids)) == len(qids) >= 3, f"query ids not distinct: {qids}")
     # the OOM victim recovered bit-identically, recovery in ITS scope only
     o18 = outcomes.get("q18", {})
     check(o18.get("rows") == solo["q18"], "q18 rows differ from solo")

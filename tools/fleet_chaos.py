@@ -217,6 +217,10 @@ def main(argv=None) -> int:
     check(b_traces == 0,
           f"replica B retraced {b_traces} shapes replica A had compiled")
     report["b_traces"] = b_traces
+    # load spread over two live replicas is routing, not recovery
+    check(not any(M.resilience_snapshot().values()),
+          f"no-faults fleet load counted client-side resilience: "
+          f"{M.resilience_snapshot()}")
     for stats_text, tag in ((cli_a.stats(), "A"), (stats_b, "B")):
         for ln in stats_text.splitlines():
             if ln.startswith("srt_resilience_total"):

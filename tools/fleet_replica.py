@@ -4,8 +4,7 @@ Spawns a TpuSession + QueryEndpoint wired into a shared fleet directory
 (runtime/fleet.py) and the shared warm-state stores (compiled-stage cache,
 plan-history), prints ``READY <port>`` once the endpoint is listening, and
 serves until SIGTERM (graceful drain) — or SIGKILL, which is the point: the
-parent harness (tools/fleet_chaos.py, tests/test_fleet.py, bench.py
---replicas) kills replicas mid-stream to drive the failover/adoption
+parent harness (tools/fleet_chaos.py, tests/test_fleet.py) kills replicas mid-stream to drive the failover/adoption
 contracts.
 
 Data catalog, one of:
