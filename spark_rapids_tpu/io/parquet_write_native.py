@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.ops.windowing import cumsum
+from spark_rapids_tpu.ops.filtering import front_perm
 
 MAGIC = b"PAR1"
 
@@ -182,8 +182,8 @@ def supports_schema(schema: T.StructType) -> bool:
 @functools.lru_cache(maxsize=256)
 def _prep_kernel(cap: int, dt_name: str):
     """Per (capacity, dtype) jitted column prep: stable-compact non-null
-    values to the front (cumsum + searchsorted, same trick as
-    ops/filtering.compact_cols) and reduce min/max/null_count in one program."""
+    values to the front (ops/filtering.front_perm, as compact_cols does)
+    and reduce min/max/null_count in one program."""
     dt = jnp.dtype(dt_name)
     if jnp.issubdtype(dt, jnp.floating):
         lo, hi = -jnp.inf, jnp.inf
@@ -197,11 +197,7 @@ def _prep_kernel(cap: int, dt_name: str):
     def k(vals, valid, n):
         live = jnp.arange(cap) < n
         vl = valid & live
-        running = cumsum(vl.astype(jnp.int32))
-        cnt = running[-1]
-        j = jnp.arange(cap, dtype=jnp.int32)
-        perm = jnp.clip(jnp.searchsorted(running, j + 1, side="left"),
-                        0, cap - 1).astype(jnp.int32)
+        perm, cnt = front_perm(vl)
         comp = vals[perm]
         if dt == jnp.bool_:
             vmin = jnp.where(vl, vals, True).all()

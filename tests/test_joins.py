@@ -392,16 +392,7 @@ def test_join_ranks_are_tuple_equality(dtypes):
             assert (k1 == k2) == (t1 == t2) and (k1 < k2) == (t1 < t2)
 
 
-# -- probe modes of the sorted-build path, under the chip's answers -----------
-
-@pytest.fixture
-def chip_answers(monkeypatch):
-    """tests/test_tpu_compile.py's `as_on_tpu` steering of the backend
-    question that code running here asks: the probe mode must not hang on
-    it."""
-    from spark_rapids_tpu.runtime import hw
-    monkeypatch.setattr(hw, "scatters_cheap", lambda: False)
-
+# -- probe modes of the sorted-build path -------------------------------------
 
 @pytest.fixture
 def spans():
@@ -520,7 +511,7 @@ def _span_counts(tracing, name):
 @pytest.mark.parametrize("how", ["inner", "leftouter", "leftsemi", "leftanti"])
 @pytest.mark.parametrize("case", MODE_CASES)
 def test_probe_mode_is_chosen_from_the_build_and_matches_numpy(
-        case, how, chip_answers, spans):
+        case, how, spans):
     bt, st, lkeys, rkeys, mode = _mode_case(case)
     conf = RapidsConf()
     j = HashJoinExec(how, [col(c) for c in lkeys], [col(c) for c in rkeys],
@@ -565,7 +556,7 @@ def _chain_reference(st, bt, b2):
 
 
 @pytest.mark.parametrize("case", MODE_CASES[:-1])   # single keys
-def test_probe_modes_through_the_join_chain(case, chip_answers, spans):
+def test_probe_modes_through_the_join_chain(case, spans):
     from spark_rapids_tpu.exec.joins import BroadcastHashJoinChainExec
     bt, st, lkeys, rkeys, mode = _mode_case(case)
     chain, b2 = _chain_over(st, bt, RapidsConf())
@@ -597,7 +588,7 @@ def _ranged_tables(vmin, n_build, step):
 
 
 @pytest.mark.parametrize("through", ["join", "chain"])
-def test_a_builds_key_range_shapes_no_program(through, chip_answers):
+def test_a_builds_key_range_shapes_no_program(through):
     """Two builds of one capacity whose vmin and range differ inside one
     bucket of table slots run ONE set of compiled programs: the second
     traces nothing and adds no kernel."""

@@ -63,11 +63,9 @@ def one_chip(topo):
 
 @pytest.fixture
 def as_on_tpu(monkeypatch):
-    """Steer the backend questions the code asks (`jax.default_backend()` is
-    'cpu' here) to their TPU answers, in the test and not in the program."""
-    from spark_rapids_tpu.runtime import hw
+    """Steer the backend question the code asks (`jax.default_backend()` is
+    'cpu' here) to its TPU answer, in the test and not in the program."""
     monkeypatch.setattr(PK, "_interpret", lambda: False)
-    monkeypatch.setattr(hw, "scatters_cheap", lambda: False)
 
 
 def _compile(fn, *shapes):
@@ -131,8 +129,12 @@ def test_dense_f64_group_sum_compiles(one_chip, as_on_tpu):
     assert _fits(compiled)
 
 
-def test_gather_form_compaction_compiles(one_chip, as_on_tpu):
-    """Filter compaction on a TPU: cumsum + searchsorted + gathers."""
+@pytest.mark.parametrize("capacity", [N, 4 * N], ids=["batch", "merge"])
+def test_compaction_compiles_to_one_scatter(capacity, one_chip):
+    """Filter compaction (ops/filtering.compact_cols) at a 1 Mi-row batch
+    and at the 4 Mi received slots the mesh exchange merges: a prefix sum,
+    ONE scatter for the permutation and a gather a column, with no `while`
+    (the `searchsorted` it replaced was a 19-step loop over the capacity)."""
     from spark_rapids_tpu.ops.filtering import compact_cols
 
     def fn(k, kv, x, xv, keep):
@@ -140,10 +142,13 @@ def test_gather_form_compaction_compiles(one_chip, as_on_tpu):
                                keep)
         return [c.values for c in cols], [c.validity for c in cols], n
 
-    b = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((capacity,), jnp.bool_, sharding=one_chip)
     compiled = _compile(
-        fn, jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip), b,
-        jax.ShapeDtypeStruct((N,), jnp.float64, sharding=one_chip), b, b)
+        fn, jax.ShapeDtypeStruct((capacity,), jnp.int64, sharding=one_chip),
+        b, jax.ShapeDtypeStruct((capacity,), jnp.float64, sharding=one_chip),
+        b, b)
+    hlo = compiled.as_text()
+    assert "scatter" in hlo and "while" not in hlo
     assert _fits(compiled)
 
 
@@ -279,7 +284,10 @@ def test_mesh_exchange_program_of_q3_at_sf1_compiles_for_four_chips(
         jax.ShapeDtypeStruct((4,), jnp.int32,
                              sharding=NamedSharding(mesh, P("data")))
     ).compile()
-    assert "all-to-all" in compiled.as_text()
+    hlo = compiled.as_text()
+    # five compactions (one a destination, one over the 4 Mi received
+    # slots), each one scatter: no `searchsorted` loop is left in it
+    assert "all-to-all" in hlo and "while" not in hlo
     assert _fits(compiled)
     one = SingleDeviceSharding(topo.devices[2])
     cut = X._slice_kernel(N)._jit.lower(
