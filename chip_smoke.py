@@ -3,9 +3,11 @@
 
 One process, the entry points a user calls. Default phase (one TPU chip):
 TPC-H SF 1 parquet on disk -> TpuSession -> session.sql() for q1/q3/q5, each
-cold and hot, each checked against the NumPy oracle; then the same SQL through
-session.serve() and an EndpointClient, checked against the in-process result;
-then a graceful shutdown. The DataFrame form of q18 runs when --queries names
+cold and hot, each checked against the NumPy oracle; then q1 twice over
+lineitem cached on the device tier (df.cache()), the second run with no byte
+at a scan site; then the same SQL through session.serve() and an
+EndpointClient, checked against the in-process result; then a graceful
+shutdown. The DataFrame form of q18 runs when --queries names
 it: with an empty compile cache the chip's compiler needs longer over q18's
 programs than the 1200 s this script is given leave room for.
 
@@ -120,19 +122,45 @@ def _timed_collect(spark, df):
     return res, secs, sites, cm
 
 
-def _run_checked(spark, name, make_df, expected):
-    """One query cold then hot, both checked against the oracle's rows."""
+def _run_checked(spark, name, make_df, expected, phase="query",
+                 runs=("cold", "hot")):
+    """One query twice, both runs checked against the oracle's rows and
+    against each other: (the second run's result, its h2d bytes by site)."""
     from spark_rapids_tpu.benchmarks.tpch import CHECKS
     out = {}
-    for run in ("cold", "hot"):
+    for run in runs:
         res, secs, sites, cm = _timed_collect(spark, make_df())
         CHECKS[name](res.to_pylist(), expected)   # wrong answer -> raises
         out[run] = res
-        note(phase="query", query=name, run=run, seconds=secs,
+        note(phase=phase, query=name, run=run, seconds=secs,
              h2d_sites=sites, rows=res.num_rows, **cm)
-    if out["cold"].to_pylist() != out["hot"].to_pylist():
-        raise AssertionError(f"{name}: hot result differs from cold result")
-    return out["hot"]
+    first, second = (out[run] for run in runs)
+    if first.to_pylist() != second.to_pylist():
+        raise AssertionError(
+            f"{name}: {runs[1]} result differs from {runs[0]} result")
+    return second, sites
+
+
+def _resident_q1(spark, dfs, expected) -> None:
+    """``df.cache()`` on the device tier: lineitem cached and registered as
+    the view, Q1's text twice. The first run fills the cache from the scan;
+    the second has to match its rows and move no host-to-device byte at a
+    scan site. The view and the retained bytes go back afterwards."""
+    from spark_rapids_tpu.sql.tpch_queries import SQL_QUERIES
+    cached = dfs["lineitem"].cache()
+    spark.create_or_replace_temp_view("lineitem", cached)
+    try:
+        _res, sites = _run_checked(
+            spark, "q1", lambda: spark.sql(SQL_QUERIES["q1"]), expected,
+            phase="resident", runs=("fill", "resident"))
+        scanned = {k: v for k, v in sites.items()
+                   if k.startswith("scan.") or k == "batch.from_arrow"}
+        if scanned:
+            raise AssertionError(
+                f"q1 over the cached view moved bytes at a scan site: {scanned}")
+    finally:
+        spark.create_or_replace_temp_view("lineitem", dfs["lineitem"])
+        cached.unpersist()
 
 
 def single_chip(args) -> None:
@@ -162,7 +190,9 @@ def single_chip(args) -> None:
             make_df = lambda name=name: spark.sql(SQL_QUERIES[name])
         else:
             make_df = lambda name=name: tpch.QUERIES[name](dfs)
-        in_process[name] = _run_checked(spark, name, make_df, expected)
+        in_process[name], _ = _run_checked(spark, name, make_df, expected)
+    if "q1" in queries:
+        _resident_q1(spark, dfs, tpch.np_q1(tb))
     _pallas_note("pallas.after_queries")
     note(phase="memory", **_memory_stats(jax.devices()[0]))
 

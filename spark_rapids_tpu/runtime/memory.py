@@ -613,9 +613,17 @@ class BufferCatalog:
 
     # -- access --------------------------------------------------------------
     def acquire_batch(self, buffer_id: int) -> ColumnarBatch:
-        """Materialize the buffer on device. If it was spilled and unspill is enabled
-        it is re-registered in the device tier (reference unspill.enabled,
+        return self.acquire(buffer_id)[0]
+
+    def acquire(self, buffer_id: int,
+                unspill: "bool | None" = None) -> "tuple[ColumnarBatch, str]":
+        """Materialize the buffer on device: (batch, the tier it was found
+        in). If it was spilled and unspill is enabled (``unspill``, or the
+        catalog's memory.hbm.unspill.enabled where that is None) it is
+        re-registered in the device tier (reference unspill.enabled,
         RapidsBufferStore copy-back); otherwise the device copy is transient."""
+        if unspill is None:
+            unspill = self._unspill
         # (bytes, seconds) collected under the lock, metered after release:
         # a sample-interval crossing in MV.record emits event-log/tracing
         # I/O, which must not run under the hot buffer-catalog lock (same
@@ -628,8 +636,9 @@ class BufferCatalog:
                 except KeyError:
                     raise BufferClosedError(
                         f"buffer {buffer_id} removed") from None
-                if buf.tier == TierEnum.DEVICE:
-                    return buf._device
+                found = buf.tier
+                if found == TierEnum.DEVICE:
+                    return buf._device, found
                 hb = buf._host
                 if hb is None:
                     if buf._handle is not None:
@@ -651,7 +660,7 @@ class BufferCatalog:
                                 f"read {got:#x}, {len(payload)}B)")
                     hb = pickle.loads(payload)
                 batch = host_to_batch(hb)
-                if self._unspill:
+                if unspill:
                     if buf.tier == TierEnum.HOST:
                         self.host_bytes -= hb.nbytes()
                     elif buf._handle is not None:
@@ -670,7 +679,7 @@ class BufferCatalog:
                     self._account_device_delta(buf, buf.size)
                     self._ensure_device_budget(exclude=buffer_id)
                     self._maybe_sample()
-                return batch
+                return batch, found
         finally:
             if spill_read is not None:
                 from spark_rapids_tpu.runtime import movement as MV
@@ -913,14 +922,19 @@ class SpillableColumnarBatch:
         self._site = self.catalog.buffer_site(self.buffer_id)
         self.num_rows = batch.num_rows
         self.schema = batch.schema
+        self.capacity = batch.capacity
         self.size = batch.device_memory_size()
         self._closed = False
         self._leak = LeakTracker.track(f"SpillableColumnarBatch#{self.buffer_id}")
 
     def get_batch(self) -> ColumnarBatch:
+        return self.acquire()[0]
+
+    def acquire(self, unspill: "bool | None" = None):
+        """(batch, the tier that held it): ``BufferCatalog.acquire``."""
         if self._closed:
             raise BufferClosedError(f"buffer {self.buffer_id} used after close")
-        return self.catalog.acquire_batch(self.buffer_id)
+        return self.catalog.acquire(self.buffer_id, unspill)
 
     def set_priority(self, priority: float):
         self.catalog.update_priority(self.buffer_id, priority)

@@ -45,6 +45,11 @@ def test_rehearsal_single_chip(tmp_path):
     # every query ran cold and hot, the endpoint answered six requests
     assert phases.count("query") == 8 and phases.count("endpoint") == 6
     assert "endpoint.shutdown" in phases and "native" in phases
+    # q1 over lineitem cached on the device tier: the run that fills the
+    # cache scans, the run that reads it moves no byte at a scan site
+    fill, resident = [n for n in notes if n.get("phase") == "resident"]
+    assert (fill["run"], resident["run"]) == ("fill", "resident")
+    assert fill["h2d_sites"] and resident["h2d_sites"] == {}
 
 
 def test_rehearsal_four_chips_runs_only_the_mesh_path(tmp_path):
