@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import threading
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -121,32 +123,16 @@ def _emit_pairs(join_type, stream_is_left, condition, preproject,
         pos += out_cap
 
 
-def _dense_table(sorted_vals, n_valid, vmin, *, slots: int):
-    """The direct-address table of a sorted unique build: slot `key - vmin`
+def _dense_table(sorted_keys, n_valid, *, slots: int):
+    """The direct-address table of a sorted unique build: slot `packed key`
     holds the key's position in the sorted build, the rest -1. One scatter a
     build; the sorted distinct keys give ascending distinct slots, which XLA
     is told, and the tail past `n_valid` continues beyond the table and is
     dropped."""
-    i = jnp.arange(sorted_vals.shape[0], dtype=jnp.int32)
-    slot = jnp.where(i < n_valid,
-                     (sorted_vals.astype(jnp.int64) - vmin).astype(jnp.int32),
-                     slots + i)
+    i = jnp.arange(sorted_keys.shape[0], dtype=jnp.int32)
+    slot = jnp.where(i < n_valid, sorted_keys.astype(jnp.int32), slots + i)
     return jnp.full((slots,), -1, jnp.int32).at[slot].set(
         i, mode="drop", indices_are_sorted=True, unique_indices=True)
-
-
-def _dense_lookup(dense, svals):
-    """Direct-address probe `(table, vmin, vmax), stream key values ->
-    (sorted build position, hit)`: one gather a stream row. The table holds
-    the position in the sorted build of each key of [vmin, vmax], -1 where
-    the build has none; its length is that domain's bucket. The range test
-    compares and never subtracts first, so no stream key wraps into the
-    domain."""
-    table, vmin, vmax = dense
-    sv = svals.astype(jnp.int64)
-    in_dom = (sv >= vmin) & (sv <= vmax)
-    r = table[jnp.where(in_dom, sv - vmin, 0).astype(jnp.int32)]
-    return r, in_dom & (r >= 0)
 
 
 def _int_backed(dtype) -> bool:
@@ -157,15 +143,52 @@ def _int_backed(dtype) -> bool:
                               T.TimestampType, T.DecimalType))
 
 
+def _pack_keys(keys, lims):
+    """Integer key columns -> `(packed, ok)`: the tuple as ONE int64,
+    `(..(k_0 - vmin_0) * range_1 + (k_1 - vmin_1)..) * range_n + ..`, a
+    bijection from the build's key domain `prod [vmin_i, vmax_i]` onto
+    `[0, domain)` that keeps the tuples' order (the first key is the most
+    significant), and whether a row has every key valid and inside its
+    range. `lims` is an int64 operand, `lims[0]` the vmin and `lims[1]` the
+    vmax of each key: the ranges shape no program, and a single key is
+    `k - vmin` with no multiply (the compiler folds `0 * range`). Every key
+    widens to int64 (never the stream down to the build: that wraps values
+    and fabricates matches), and each range test compares and never
+    subtracts first, so no key wraps into the domain and no tuple outside
+    one key's range aliases a packed value inside it: such a row packs to
+    some in-domain value, safe as an index, under `ok` False."""
+    packed = jnp.zeros(keys[0].values.shape, jnp.int64)
+    ok = jnp.ones(keys[0].values.shape, jnp.bool_)
+    for i, k in enumerate(keys):
+        v = k.values.astype(jnp.int64)    # bool as 0 / 1
+        vmin, vmax = lims[0, i], lims[1, i]
+        inside = (v >= vmin) & (v <= vmax)
+        ok = ok & inside & k.validity
+        packed = packed * (vmax - vmin + 1) + jnp.where(inside, v - vmin, 0)
+    return packed, ok
+
+
+class _ProbeArgs(NamedTuple):
+    """Traced operands of a packed-key probe (`_JoinCore.chain_args`)."""
+    sorted_build: jax.Array     # the build's packed keys, ascending
+    n_valid: jax.Array          # how many of them are keys (the rest: tail)
+    perm: jax.Array             # sorted position -> build row
+    lims: jax.Array             # each key's vmin and vmax (`_pack_keys`)
+    table: tuple                # (the dense table,) in mode `dense`, else ()
+
+
 class _JoinCore:
     """Shared probe machinery over one materialized build batch.
 
-    Single fixed-point-key joins take a FAST path: the build side is sorted
-    ONCE (invalid/padding rows forced to the type max and clamped out via the
-    valid count), and each stream batch probes it by direct address or by
+    Joins whose keys are all fixed-point (one key or several) take a FAST
+    path: the key tuple is packed into one int64 (`_pack_keys`), the build
+    side is sorted ONCE by it (rows without a key sent past the valid
+    count), and each stream batch probes it by direct address or by
     searchsorted (`_prep_fast_build` picks the mode from the build) — no
-    per-batch re-sort of build+stream (the rank path pays a multi-key sort
-    over both sides per stream batch)."""
+    per-batch re-sort of build+stream. The rank path (ops/joining.py, a
+    multi-key sort over both sides per stream batch) keeps what cannot be
+    packed: a key that is no integer, a key that reads the batch's context,
+    a build whose key domain passes 2^62."""
 
     def __init__(self, build_batch: ColumnarBatch, build_key_exprs,
                  stream_key_exprs, join_type: str, stream_prefilter=None):
@@ -174,9 +197,9 @@ class _JoinCore:
         self.build_key_exprs = build_key_exprs
         self.stream_key_exprs = stream_key_exprs
         self.join_type = join_type
-        # hoisted stream-side filter (inner single-int-key joins only — the
-        # planner guarantees that): the predicate masks probe rows in-kernel,
-        # so filtered rows emit zero pairs without a separate FilterExec
+        # hoisted stream-side filter (inner joins only — the planner
+        # guarantees that): the predicate masks probe rows in-kernel, so
+        # filtered rows emit zero pairs without a separate FilterExec
         # dispatch + compaction (whole-stage-codegen role)
         self.stream_prefilter = stream_prefilter
         from spark_rapids_tpu.expr.misc import CONTEXT_SENSITIVE
@@ -198,35 +221,40 @@ class _JoinCore:
         # matched-build tracking for full outer (host accumulation across stream)
         self.build_matched_acc = (np.zeros(self.build_cap, dtype=bool)
                                   if join_type == J.FULL_OUTER else None)
-        self.fast = (len(self.build_keys_raw) == 1
-                     and _int_backed(self.build_keys_raw[0].dtype))
-        # the hoisting planner rule guarantees these; the eager and rank
-        # probe paths do not evaluate the prefilter
-        assert stream_prefilter is None or (self.fast
+        self.fast = all(_int_backed(b.dtype) and _int_backed(s.dtype)
+                        for b, s in zip(self.build_keys_raw, stream_key_exprs))
+        # the hoisting planner rule guarantees these; the eager probe path
+        # does not evaluate the prefilter, the rank path only for an inner
+        assert stream_prefilter is None or (join_type == J.INNER
                                             and not self.ctx_sensitive)
-        self.domain = self.table_slots = 0    # of the fast path's key range
+        self._probe_mode = "rank"
+        self.domain = self.table_slots = 0    # of the fast path's key domain
         with tracing.span("HashJoin.build_prep") as sp:
             if self.fast:
                 self._prep_fast_build()
-            sp.set(mode=self.mode, rows=self.n_build, capacity=self.build_cap,
+            sp.set(mode=self.mode, keys=len(self.build_keys_raw),
+                   rows=self.n_build, capacity=self.build_cap,
                    domain=self.domain, table_slots=self.table_slots)
 
     @property
     def mode(self) -> str:
         """How a stream batch finds its build rows: `dense` / `one` / `two`
-        over the build sorted once, `rank` for several keys,
-        keys that are no integers, or keys that read the batch's context."""
-        return (self._probe_mode if self.fast and not self.ctx_sensitive
-                else "rank")
+        over the build sorted once by its packed key, `rank` for keys that
+        are no integers, a key domain too wide to pack, or keys that read
+        the batch's context."""
+        return "rank" if self.ctx_sensitive else self._probe_mode
 
     def _prep_fast_build(self):
-        """Sort the single int build key once. Strategy picked from the key
-        RANGE (one cheap reduction + host sync per build, like the
-        reference's one-time build-table materialization):
+        """Pack the integer build keys into one and sort it once. Everything
+        is read from the build batch (one cheap reduction + host sync per
+        build, like the reference's one-time build-table materialization):
 
-        - range fits the packed budget → ONE-operand int64 sort of
-          ((val - vmin) << idx_bits | row_idx); ~8x cheaper than the
-          3-operand comparator sort (an XLA:CPU measurement).
+        - each key's [vmin, vmax] over the rows that have every key gives
+          the domain, the product of the ranges. A domain
+          that fits beside a row index in 62 bits is packed, and sorted by
+          ONE-operand int64 sort of (packed key << idx_bits | row_idx), ~8x
+          cheaper than a 3-operand comparator sort (an XLA:CPU
+          measurement); a wider one leaves the build on the rank path.
         - afterwards, uniqueness + compact domain decide the probe mode, on
           every backend: dense direct-address rank table (one gather per
           stream row), unique single-searchsorted, or the general
@@ -235,121 +263,86 @@ class _JoinCore:
           of 1 Mi stream keys 8.5 ms, the searchsorted it replaces 240 to
           480 ms."""
         from spark_rapids_tpu.runtime import fuse
-        k = self.build_keys_raw[0]
-        cap = k.values.shape[0]
+        keys = self.build_keys_raw
+        cap = self.build_cap
         idx_bits = max(int(cap - 1).bit_length(), 1)
 
-        def stats(k, n_build):
-            vals = k.values.astype(jnp.int8) if k.values.dtype == jnp.bool_ \
-                else k.values
-            eligible = k.validity & (jnp.arange(cap, dtype=jnp.int32) < n_build)
-            big = jnp.asarray(jnp.iinfo(vals.dtype).max, vals.dtype)
-            small = jnp.asarray(jnp.iinfo(vals.dtype).min, vals.dtype)
-            vmin = jnp.min(jnp.where(eligible, vals, big))
-            vmax = jnp.max(jnp.where(eligible, vals, small))
-            return (vmin.astype(jnp.int64), vmax.astype(jnp.int64),
-                    jnp.sum(eligible, dtype=jnp.int32))
+        def eligible_rows(keys, n_build):
+            eligible = jnp.arange(cap, dtype=jnp.int32) < n_build
+            for k in keys:
+                eligible = eligible & k.validity
+            return eligible
 
-        skey = ("join_build_stats", k.dtype, cap)
+        def stats(keys, n_build):
+            eligible = eligible_rows(keys, n_build)
+            i64 = jnp.iinfo(jnp.int64)
+            vals = [k.values.astype(jnp.int64) for k in keys]
+            lims = jnp.stack([
+                jnp.stack([jnp.min(jnp.where(eligible, v, i64.max))
+                           for v in vals]),
+                jnp.stack([jnp.max(jnp.where(eligible, v, i64.min))
+                           for v in vals])])
+            return lims, jnp.sum(eligible, dtype=jnp.int32)
+
+        dtypes = tuple(k.dtype for k in keys)
         n_build_t = jnp.asarray(self.n_build, jnp.int32)
-        vmin_t, vmax_t, n_valid = fuse.call_fused(
-            skey, "HashJoin.build_stats", lambda: stats, (k, n_build_t),
-            lambda: stats(k, n_build_t))
-        vmin, vmax = int(vmin_t), int(vmax_t)    # one host sync per build
-        rng = max(vmax - vmin, 0)
-        # vmax+1 (the ineligible-row sentinel) must stay representable in
-        # int64 — the packed path keeps sorted keys as int64 precisely so a
-        # dtype-max key can never collide with/overflow into the sentinel
-        packable = (self.n_build > 0 and rng < (1 << (62 - idx_bits))
-                    and vmax < (1 << 62))
-        # the direct-address table covers [vmin, vmax], its length rounded up
-        # to a bucket so the programs that take it are shaped by the bucket
-        # and not by the data; the budget is a power of two, so a domain
-        # under it has its bucket under it too
-        self.domain = domain = vmax - vmin + 1 if vmax >= vmin else 0
-        dense_budget = max(4 * cap, 1 << 22)
-        if packable:
-            def prep(k, n_build, vmin, tail_rel):
-                vals = k.values.astype(jnp.int8) \
-                    if k.values.dtype == jnp.bool_ else k.values
-                eligible = k.validity & (
-                    jnp.arange(cap, dtype=jnp.int32) < n_build)
-                rel = (vals.astype(jnp.int64) - vmin)
-                # ineligible rows above every real key (rng+1 relative)
-                rel = jnp.where(eligible, rel, tail_rel)
-                packed = (rel << idx_bits) | jnp.arange(cap, dtype=jnp.int64)
-                # one operand of distinct values: stability buys nothing
-                s = jax.lax.sort(packed, is_stable=False)
-                perm = (s & ((1 << idx_bits) - 1)).astype(jnp.int32)
-                # int64 ON PURPOSE: casting back to the key dtype would wrap
-                # the vmax+1 sentinel tail to INT_MIN when vmax == dtype max,
-                # breaking the sortedness searchsorted depends on (probe
-                # promotes both sides to a common type anyway)
-                sorted_vals = (s >> idx_bits) + vmin
-                nv = jnp.sum(eligible, dtype=jnp.int32)
-                same = (s[1:] >> idx_bits) == (s[:-1] >> idx_bits)
-                in_valid = (jnp.arange(cap - 1, dtype=jnp.int32) + 1) < nv
-                unique = ~jnp.any(same & in_valid)
-                return sorted_vals, perm, unique
+        lims_t, n_valid = fuse.call_fused(
+            ("join_build_stats", dtypes, cap), "HashJoin.build_stats",
+            lambda: stats, (keys, n_build_t), lambda: stats(keys, n_build_t))
+        lims = np.asarray(lims_t).tolist()    # one host sync per build
+        self.domain = domain = math.prod(
+            max(hi - lo + 1, 0) for lo, hi in zip(*lims))
+        # the packed key and the ineligible rows' tail (`domain`, above every
+        # real key) sit beside a row index in one non-negative int64
+        if domain >= 1 << (62 - idx_bits):
+            return                                       # mode `rank`
+        # vmin and vmax are operands: the key ranges of the data shape no
+        # program
+        self._lims = lims_t
 
-            # vmin and the range are operands: the key range of the data
-            # shapes no program
-            pkey = ("join_build_pack", k.dtype, cap)
-            args = (k, n_build_t, vmin_t, jnp.asarray(rng + 1, jnp.int64))
-            self._sorted_build, self._build_perm, uniq_t = fuse.call_fused(
-                pkey, "HashJoin.build_prep", lambda: prep, args,
-                lambda: prep(*args))
-        else:
-            def prep(k, n_build):
-                vals = k.values.astype(jnp.int8) \
-                    if k.values.dtype == jnp.bool_ else k.values
-                eligible = k.validity & (
-                    jnp.arange(cap, dtype=jnp.int32) < n_build)
-                masked = jnp.where(
-                    eligible, vals,
-                    jnp.asarray(jnp.iinfo(vals.dtype).max, vals.dtype))
-                # two sort keys: eligibility first so a LEGITIMATE max-valued
-                # key still lands inside [0, n_valid) against the sentinel
-                # the row index as last key = the stable order, without the
-                # index operand a stable sort would add beside it
-                _, sorted_vals, perm = jax.lax.sort(
-                    [(~eligible).astype(jnp.int8), masked,
-                     jnp.arange(cap, dtype=jnp.int32)], num_keys=3,
-                    is_stable=False)
-                nv = jnp.sum(eligible, dtype=jnp.int32)
-                same = sorted_vals[1:] == sorted_vals[:-1]
-                in_valid = (jnp.arange(cap - 1, dtype=jnp.int32) + 1) < nv
-                unique = ~jnp.any(same & in_valid)
-                return sorted_vals, perm, unique
+        def prep(keys, n_build, n_valid, lims, tail):
+            rel = jnp.where(eligible_rows(keys, n_build),
+                            _pack_keys(keys, lims)[0], tail)
+            packed = (rel << idx_bits) | jnp.arange(cap, dtype=jnp.int64)
+            # one operand of distinct values: stability buys nothing
+            s = jax.lax.sort(packed, is_stable=False)
+            perm = (s & ((1 << idx_bits) - 1)).astype(jnp.int32)
+            sorted_keys = s >> idx_bits
+            same = sorted_keys[1:] == sorted_keys[:-1]
+            in_valid = (jnp.arange(cap - 1, dtype=jnp.int32) + 1) < n_valid
+            return sorted_keys, perm, ~jnp.any(same & in_valid)
 
-            key = ("join_build_prep", k.dtype, cap)
-            args = (k, n_build_t)
-            self._sorted_build, self._build_perm, uniq_t = fuse.call_fused(
-                key, "HashJoin.build_prep", lambda: prep, args,
-                lambda: prep(*args))
+        args = (keys, n_build_t, n_valid, lims_t, np.int64(domain))
+        self._sorted_build, self._build_perm, uniq_t = fuse.call_fused(
+            ("join_build_pack", dtypes, cap), "HashJoin.build_prep",
+            lambda: prep, args, lambda: prep(*args))
         self._n_valid = n_valid
         # probe-mode choice, from what the build shows (uniqueness, key
-        # range, capacity) and on every backend — static per compiled probe
+        # domain, capacity) and on every backend — static per compiled probe
         # kernel. Different needs, not knobs: "two" for duplicate keys, "one"
         # for a unique build whose domain is over the budget, "dense" below it
         unique = bool(uniq_t) if self.n_build > 0 else True
         self._probe_mode = "two"
+        self._dense_table = ()
         if unique and self.build_matched_acc is None:
             self._probe_mode = "one"
+            # the direct-address table covers the domain, its length rounded
+            # up to a bucket so the programs that take it are shaped by the
+            # bucket and not by the data; the budget is a power of two, so a
+            # domain under it has its bucket under it too
             slots = bucket_capacity(domain)
             # slot numbers (the dropped tail's too) are int32
-            if domain <= dense_budget and slots + cap < (1 << 31):
+            if domain <= max(4 * cap, 1 << 22) and slots + cap < (1 << 31):
                 # direct-address rank table: ONE scatter a build, one gather
                 # a probe row where "one" pays a log2(capacity)+1-step
                 # searchsorted loop over 64-bit halves a stream batch
                 self._probe_mode = "dense"
-                self._vmin, self._vmax = vmin_t, vmax_t
                 self.table_slots = slots
                 mktable = functools.partial(_dense_table, slots=slots)
-                targs = (self._sorted_build, n_valid, vmin_t)
-                self._dense_table = fuse.call_fused(
+                targs = (self._sorted_build, n_valid)
+                self._dense_table = (fuse.call_fused(
                     ("join_dense_table", slots), "HashJoin.dense_table",
-                    lambda: mktable, targs, lambda: mktable(*targs))
+                    lambda: mktable, targs, lambda: mktable(*targs)),)
 
     def probe_batch(self, stream_batch: ColumnarBatch):
         from spark_rapids_tpu.runtime import fuse
@@ -359,9 +352,10 @@ class _JoinCore:
               else self.join_type)
         track_matched = self.build_matched_acc is not None
         stream_key_exprs = self.stream_key_exprs
+        stream_prefilter = self.stream_prefilter
         if self.ctx_sensitive:
             return self._probe_batch_eager(stream_batch, jt, track_matched)
-        if self.fast:
+        if self._probe_mode != "rank":
             return self._probe_batch_fast(stream_batch, jt, track_matched)
 
         def kernel(build_keys_raw, n_build, stream_cols, n_stream):
@@ -374,6 +368,9 @@ class _JoinCore:
                 build_keys, n_build, build_keys[0].values.shape[0],
                 stream_keys, n_stream, scap)
             build_perm, lo, hi = J.probe(b_ranks, s_ranks)
+            if stream_prefilter is not None:    # inner: no pair, no row
+                hi = jnp.where(selection_mask(stream_prefilter.eval(sctx),
+                                              n_stream, scap), hi, lo)
             counts = J.pair_counts(lo, hi, n_stream, scap, jt)
             total = J.total_pairs(counts)
             if track_matched:
@@ -425,100 +422,98 @@ class _JoinCore:
         return build_perm, lo, hi, counts, total
 
     def _probe_batch_fast(self, stream_batch, jt, track_matched):
-        """Pre-sorted-build probe. Modes (chosen at build, static per compiled
-        kernel): "dense" = O(1) direct-address rank-table gather (unique keys,
-        compact domain); "one" = single searchsorted + equality (unique
-        keys); "two" = general left+right searchsorted."""
+        """Pre-sorted-build probe over the packed key. Modes (chosen at
+        build, static per compiled kernel): "dense" = O(1) direct-address
+        rank-table gather (unique keys, compact domain); "one" = single
+        searchsorted + equality (unique keys); "two" = general left+right
+        searchsorted."""
         from spark_rapids_tpu.runtime import fuse
         stream_key_exprs = self.stream_key_exprs
         mode = self._probe_mode
         stream_prefilter = self.stream_prefilter
+        find = self._find()
 
-        def kernel(sorted_build, n_valid, n_build, build_keys_raw, stream_cols,
-                   n_stream, dense):
+        def kernel(cargs, n_build, build_keys_raw, stream_cols, n_stream):
             scap = stream_cols[0].values.shape[0]
             sctx = EvalContext(stream_cols, n_stream, scap)
-            k = stream_key_exprs[0].eval(sctx)
-            svals = (k.values.astype(jnp.int8)
-                     if k.values.dtype == jnp.bool_ else k.values)
-            # mixed-width keys (e.g. int64 probe vs int32 build): promote BOTH
-            # sides to the common dtype — casting the stream DOWN wraps values
-            # and fabricates matches. Integer widening is monotone, so the
-            # pre-sorted build array stays sorted and the n_valid clamp still
-            # masks the sentinel tail.
-            common = jnp.promote_types(svals.dtype, sorted_build.dtype)
-            svals = svals.astype(common)
-            sorted_common = sorted_build.astype(common)
+            packed, ok = _pack_keys([e.eval(sctx) for e in stream_key_exprs],
+                                    cargs.lims)
             if stream_prefilter is not None:
-                live = selection_mask(stream_prefilter.eval(sctx),
-                                      n_stream, scap)
+                ok = ok & selection_mask(stream_prefilter.eval(sctx),
+                                         n_stream, scap)
             else:
-                live = jnp.arange(scap, dtype=jnp.int32) < n_stream
-            if mode == "dense":
-                r, hit = _dense_lookup(dense, svals)
-                hit = hit & k.validity & live
-                lo = jnp.where(hit, r, 0).astype(jnp.int32)
-                hi = jnp.where(hit, r + 1, lo).astype(jnp.int32)
-            elif mode == "one":
-                bcap_ = sorted_common.shape[0]
-                lo = jnp.minimum(
-                    jnp.searchsorted(sorted_common, svals, side="left"),
-                    n_valid).astype(jnp.int32)
-                found = (sorted_common[jnp.clip(lo, 0, bcap_ - 1)] == svals) \
-                    & (lo < n_valid) & k.validity & live
-                hi = jnp.where(found, lo + 1, lo).astype(jnp.int32)
-            else:
-                lo = jnp.minimum(
-                    jnp.searchsorted(sorted_common, svals, side="left"),
-                    n_valid).astype(jnp.int32)
-                hi = jnp.minimum(
-                    jnp.searchsorted(sorted_common, svals, side="right"),
-                    n_valid).astype(jnp.int32)
-                hi = jnp.where(k.validity & live, hi, lo)
+                ok = ok & (jnp.arange(scap, dtype=jnp.int32) < n_stream)
+            lo, hi = find(cargs, packed, ok)
             counts = J.pair_counts(lo, hi, n_stream, scap, jt)
             total = J.total_pairs(counts)
             if track_matched:
                 # which eligible build rows matched: probe the sorted stream
-                bk = build_keys_raw[0]
-                bvals = (bk.values.astype(jnp.int8)
-                         if bk.values.dtype == jnp.bool_ else bk.values)
-                bvals = bvals.astype(common)  # same promotion, build→stream probe
-                s_eligible = k.validity & live
-                s_masked = jnp.where(
-                    s_eligible, svals,
-                    jnp.asarray(jnp.iinfo(svals.dtype).max, svals.dtype))
-                _, s_sorted = jax.lax.sort(
-                    [(~s_eligible).astype(jnp.int8), s_masked], num_keys=2,
-                    is_stable=False)   # every operand is a key
-                ns = jnp.sum(s_eligible, dtype=jnp.int32)
+                # by the build's packed keys (under the packed budget, so
+                # the sentinel of a row without a key is above them all)
+                s_sorted = jax.lax.sort(
+                    jnp.where(ok, packed, jnp.iinfo(jnp.int64).max),
+                    is_stable=False)
+                ns = jnp.sum(ok, dtype=jnp.int32)
+                bvals, b_ok = _pack_keys(build_keys_raw, cargs.lims)
                 blo = jnp.minimum(
                     jnp.searchsorted(s_sorted, bvals, side="left"), ns)
                 bhi = jnp.minimum(
                     jnp.searchsorted(s_sorted, bvals, side="right"), ns)
-                bcap = bvals.shape[0]
-                b_eligible = bk.validity & (
-                    jnp.arange(bcap, dtype=jnp.int32) < n_build)
-                return lo, hi, counts, total, (bhi > blo) & b_eligible
+                b_ok = b_ok & (jnp.arange(bvals.shape[0], dtype=jnp.int32)
+                               < n_build)
+                return lo, hi, counts, total, (bhi > blo) & b_ok
             return lo, hi, counts, total, None
 
-        # the dense table's vmin, vmax and length are operands: a build's
-        # key range shapes no program (jit specialises on the table's bucket)
+        # the key ranges and the dense table's length are operands: a
+        # build's key domain shapes no program (jit specialises on the
+        # table's bucket)
         key = ("join_probe_fast", jt, track_matched, mode,
                self._stream_key_key,
                fuse.schema_key(stream_batch.schema)
                if stream_batch.schema else None)
         stream_cols = [Col.from_vector(c) for c in stream_batch.columns]
         n_stream = jnp.asarray(stream_batch.lazy_num_rows, jnp.int32)
-        _, _, _, dense = self.chain_args()
-        args = (self._sorted_build, self._n_valid,
-                jnp.asarray(self.n_build, jnp.int32), self.build_keys_raw,
-                stream_cols, n_stream, dense)
+        args = (self.chain_args(), jnp.asarray(self.n_build, jnp.int32),
+                self.build_keys_raw, stream_cols, n_stream)
         lo, hi, counts, total, matched = fuse.call_fused(
             key, "HashJoin.probe", lambda: kernel, args,
             lambda: kernel(*args))
         if track_matched:
             self._sync_matched(matched)
         return self._build_perm, lo, hi, counts, total
+
+    def _find(self):
+        """Traceable `(chain_args, packed stream key, ok) -> (lo, hi)`: the
+        range of sorted-build positions a stream row matches, empty where
+        `ok` (every key valid and in the build's domain, the row live) is
+        False. One body a mode, for the probe kernel and the chain."""
+        mode = self._probe_mode
+
+        def find(cargs, packed, ok):
+            sorted_build, n_valid = cargs.sorted_build, cargs.n_valid
+            if mode == "dense":
+                # one gather a stream row; -1 where the build has no such key
+                lo = cargs.table[0][packed.astype(jnp.int32)]
+                found = ok & (lo >= 0)
+                # the position depends on the table's gather alone, not on
+                # the mask: with a select between them the chip's compiler
+                # left the chain's position->row table in HBM and its gather
+                # took 36 ms a 1 Mi rows for 9 (PERF.md section 6, PR 34)
+                lo = jnp.maximum(lo, 0)
+            else:
+                lo = jnp.minimum(
+                    jnp.searchsorted(sorted_build, packed, side="left"),
+                    n_valid).astype(jnp.int32)
+                if mode == "two":
+                    hi = jnp.minimum(
+                        jnp.searchsorted(sorted_build, packed, side="right"),
+                        n_valid).astype(jnp.int32)
+                    return lo, jnp.where(ok, hi, lo)
+                at = sorted_build[jnp.clip(lo, 0, sorted_build.shape[0] - 1)]
+                found = ok & (at == packed) & (lo < n_valid)
+            return lo, jnp.where(found, lo + 1, lo)
+
+        return find
 
     # -- whole-stage join-chain surface (BroadcastHashJoinChainExec) ---------
 
@@ -527,51 +522,34 @@ class _JoinCore:
         stream row through a shared compiled program — the property that lets
         a stack of joins fuse into one static-shape per-batch kernel (output
         rows <= stream rows, so stream capacity bounds every hop)."""
-        return (self.fast and not self.ctx_sensitive
-                and self.build_matched_acc is None
-                and self._probe_mode in ("dense", "one"))
+        return self.mode in ("dense", "one")
 
     def chain_static(self):
         """Kernel-key part: everything `chain_lookup` bakes into the trace
-        (the dense table's vmin, vmax and length are operands)."""
+        (the key ranges and the dense table's length are operands)."""
         return self._probe_mode
 
     def chain_args(self):
-        """Traced operands for `chain_lookup`: `dense` is the table with its
-        vmin and vmax (`_dense_lookup`'s operand), empty in mode `one`."""
-        dense = ((self._dense_table, self._vmin, self._vmax)
-                 if self._probe_mode == "dense" else ())
-        return (self._sorted_build, self._n_valid, self._build_perm, dense)
+        """Traced operands of the probe: the sorted packed build, its valid
+        count, the position->row permutation, the keys' ranges (`_pack_keys`'s
+        operand) and the dense table, empty outside mode `dense`."""
+        return _ProbeArgs(self._sorted_build, self._n_valid,
+                          self._build_perm, self._lims, self._dense_table)
 
     def chain_lookup(self):
-        """Traceable single-match probe `(chain_args, stream_key_col) ->
-        (build_row, hit)`: the unique-match mode branches of
-        `_probe_batch_fast`, with the position->row mapping through
-        `_build_perm` folded in (expand_pairs does that mapping on the
-        unfused path). Validity/liveness masking is the caller's job."""
-        mode = self._probe_mode
+        """Traceable single-match probe `(chain_args, stream key cols, live)
+        -> (build_row, hit)`: the unique-match modes of `_find`, with the
+        position->row mapping through `_build_perm` folded in (expand_pairs
+        does that mapping on the unfused path). `hit` holds every key's
+        validity and the domain test."""
+        find = self._find()
 
-        def lookup(cargs, k):
-            sorted_build, n_valid, perm, dense = cargs
-            pcap = perm.shape[0]
-            svals = (k.values.astype(jnp.int8)
-                     if k.values.dtype == jnp.bool_ else k.values)
-            if mode == "dense":
-                r, hit = _dense_lookup(dense, svals)
-                row = perm[jnp.clip(r, 0, pcap - 1)]
-                return jnp.where(hit, row, 0).astype(jnp.int32), hit
-            # mode == "one": single searchsorted + equality (same common-type
-            # promotion as the unfused fast probe — casting the stream DOWN
-            # would wrap values and fabricate matches)
-            common = jnp.promote_types(svals.dtype, sorted_build.dtype)
-            sc = sorted_build.astype(common)
-            sv = svals.astype(common)
-            bcap = sc.shape[0]
-            lo = jnp.minimum(jnp.searchsorted(sc, sv, side="left"),
-                             n_valid).astype(jnp.int32)
-            found = (sc[jnp.clip(lo, 0, bcap - 1)] == sv) & (lo < n_valid)
-            row = perm[jnp.clip(lo, 0, pcap - 1)]
-            return jnp.where(found, row, 0).astype(jnp.int32), found
+        def lookup(cargs, keys, live):
+            packed, ok = _pack_keys(keys, cargs.lims)
+            lo, hi = find(cargs, packed, ok & live)
+            hit = hi > lo
+            row = cargs.perm[jnp.clip(lo, 0, cargs.perm.shape[0] - 1)]
+            return jnp.where(hit, row, 0).astype(jnp.int32), hit
 
         return lookup
 
@@ -1003,11 +981,11 @@ class BroadcastHashJoinChainExec(TpuExec):
         output bucket. Returns the output batch or None (no survivors)."""
         from spark_rapids_tpu.runtime import fuse
         scap = stream_batch.capacity
-        specs = [(c.stream_key_exprs[0], c.stream_prefilter,
+        specs = [(c.stream_key_exprs, c.stream_prefilter,
                   h.stream_preproject, h.stream_is_left)
                  for h, c in zip(self.hops, cores)]
         spec_key = tuple(
-            (fuse.expr_key(sk),
+            (tuple(fuse.expr_key(e) for e in sk),
              fuse.expr_key(pf) if pf is not None else None,
              tuple(fuse.expr_key(e) for e in pp) if pp is not None else None,
              sil)
@@ -1034,7 +1012,7 @@ class BroadcastHashJoinChainExec(TpuExec):
                     cur = stream_cols
                     for hop, (lk, (cargs, b_cols), spec) in enumerate(
                             zip(lookups, hop_args, specs)):
-                        sk_expr, prefilter, preproject, sil = spec
+                        sk_exprs, prefilter, preproject, sil = spec
                         ctx = EvalContext(cur, n_stream, cap_in)
                         # each hop, and the filter and projection fused into
                         # it, under its own name in the op metadata
@@ -1044,9 +1022,9 @@ class BroadcastHashJoinChainExec(TpuExec):
                                     p = prefilter.eval(ctx)
                                     live = live & p.values & p.validity
                             with jax.named_scope("lookup"):
-                                k = sk_expr.eval(ctx)
-                                row, hit = lk(cargs, k)
-                                hit = hit & k.validity & live
+                                row, hit = lk(
+                                    cargs, [e.eval(ctx) for e in sk_exprs],
+                                    live)
                             bg = gather_cols(b_cols, jnp.where(hit, row, 0),
                                              hit)
                             if preproject is not None:

@@ -4,12 +4,14 @@ Reference (SURVEY.md component #16): GpuHashJoin.scala:289 calls cudf
 `innerJoinGatherMaps` / `leftJoinGatherMaps` etc — hash-table probes producing
 data-dependent-size gather maps, iterated out-of-core by JoinGatherer.scala.
 
-TPU-native design for the general case (several keys, keys that are no integers:
-sorts and searches are XLA-native, and rank equality is collision-free). A single
-integer key does not come here: exec/joins.py sorts that build once and probes it
-by direct address where the key domain is compact (one gather a stream row; on a
-v5e 8.5 ms a 1 Mi-row batch where the `searchsorted` below costs 240 to 480 ms,
-PERF.md section 6, PR 30), by `searchsorted` where it is not:
+TPU-native design for the general case (keys that are no integers, keys that read
+the batch's context, integer keys whose domain cannot be packed into 62 bits:
+sorts and searches are XLA-native, and rank equality is collision-free). Integer
+keys, one or several, do not come here: exec/joins.py packs the tuple into one
+int64, sorts that build once and probes it by direct address where the key domain
+is compact (one gather a stream row; on a v5e 8.5 ms a 1 Mi-row batch where the
+`searchsorted` below costs 240 to 480 ms, PERF.md section 6, PR 30), by
+`searchsorted` where it is not:
 
 1. **Dense ranks**: concatenate build+stream key rows and run ONE fused multi-key sort
    (ops.grouping.group_segments); equal key tuples — with Spark's NaN==NaN and

@@ -210,7 +210,8 @@ _backend_tag_memo = None
 # 2: programs carry their kernel's name (jit_srt_<name>) and operator scopes.
 # 3: the join probe, the join chain and the single-page decode take fewer
 # operands (no hash-table dummies, no static present count).
-KERNEL_CACHE_VERSION = 3
+# 4: the join programs probe by the packed key tuple (one key or several).
+KERNEL_CACHE_VERSION = 4
 
 
 def _backend_tag() -> str:

@@ -420,6 +420,8 @@ def _mode_case(name):
     its dtype and keys that match; every build holds null keys."""
     r = np.random.default_rng(sum(map(ord, name)))
     nb, ns = 300, 700
+    if name in MULTI_KEY_CASES:
+        return _multi_key_case(name, r, nb, ns)
     btype = stype = pa.int64()
     b_nulls = 0.1
     if name == "dense_negative_far_from_zero":
@@ -429,7 +431,7 @@ def _mode_case(name):
         bk = I32.max - r.permutation(900)[:nb]
         btype = pa.int32()
     elif name == "dense_int64_max":
-        # not packable beside a row index: the comparator sort builds it
+        # packed relative to its least key, the greatest cannot overflow
         bk = I64.max - r.permutation(900)[:nb]
     elif name == "one_over_the_budget":
         bk = -5 + r.permutation(900)[:nb] * (1 << 33)
@@ -450,11 +452,9 @@ def _mode_case(name):
         # rows and no key: nothing matches, whatever lies beneath
         bk = 40 + r.permutation(900)[:nb]
         b_nulls = 2.0
-    elif name == "two_duplicate_keys":
-        bk = 40 + r.integers(0, 90, nb)
     else:
-        assert name == "rank_two_keys", name
-        bk = -50 + r.integers(0, 60, nb)
+        assert name == "two_duplicate_keys", name
+        bk = 40 + r.integers(0, 90, nb)
     far = [I64.min, I64.max, I64.min + 1, int(bk.min()) - 1, 0, -1,
            int(bk.max() % (1 << 32)) + (1 << 32)]
     far += [int(bk.max()) + 1] if int(bk.max()) < I64.max else []
@@ -464,38 +464,142 @@ def _mode_case(name):
           "rv": pa.array(np.arange(nb), type=pa.int32())}
     st = {"lk": _keys(sk, r.random(ns) < 0.1, stype),
           "lv": pa.array(np.arange(ns), type=pa.int32())}
-    lkeys, rkeys = ["lk"], ["rk"]
-    if name == "rank_two_keys":
-        bt["rk2"] = pa.array(r.integers(0, 3, nb), type=pa.int64())
-        st["lk2"] = pa.array(r.integers(0, 3, ns), type=pa.int64())
-        lkeys, rkeys = ["lk", "lk2"], ["rk", "rk2"]
+    return pa.table(bt), pa.table(st), ["lk"], ["rk"], name.split("_")[0]
+
+
+SINGLE_KEY_CASES = [
+    "dense_negative_far_from_zero", "dense_int32_build_int64_stream",
+    "dense_int64_max", "one_over_the_budget", "one_int32_build_sparse",
+    "dense_int16_build_sparse", "one_null_keys_probed_by_value",
+    NO_KEY, "two_duplicate_keys"]
+
+# name: (build key types, stream key types, how many values a key takes,
+# the step between them, each key's least value, whether tuples repeat).
+# The packed domain is the product of the keys' ranges.
+DATE = pa.date32()
+MULTI_KEY_CASES = {
+    # the second build key ends at its dtype's maximum under a wider stream
+    "dense_two_keys": ([pa.int64(), pa.int32()], [pa.int64(), pa.int64()],
+                       (60, 25), (1, 1), (-7_000_000_000, I32.max - 24),
+                       False),
+    # 59 * 2**20 * 25 slots: no table fits the budget
+    "one_two_keys_over_the_budget": (
+        [pa.int64()] * 2, [pa.int64()] * 2, (60, 25), (1 << 20, 1),
+        (-5, 40), False),
+    "two_duplicate_tuples": ([pa.int64()] * 2, [pa.int64()] * 2, (60, 3),
+                             (1, 1), (-50, 0), True),
+    # bigint + int + date, as TPC-H Q5's keys are
+    "dense_three_keys_mixed_widths": (
+        [pa.int64(), pa.int32(), DATE], [pa.int64(), pa.int32(), DATE],
+        (20, 10, 8), (1, 1, 1), (10_000_000_000, -5, 10_000), False),
+    # each range fits, their product (2**46 x 2**35) does not
+    "rank_domain_product_past_2_62": (
+        [pa.int64()] * 2, [pa.int64()] * 2, (60, 25), (1 << 40, 1 << 30),
+        (-(1 << 44), 7), False),
+    # a string is ranked under the two sides' united dictionary
+    "rank_string_beside_int": ([pa.int64(), pa.string()],
+                               [pa.int64(), pa.string()], (60, 25), (1, 1),
+                               (3, 0), False),
+}
+MODE_CASES = SINGLE_KEY_CASES + list(MULTI_KEY_CASES)
+
+
+def _key_column(vals, nulls, typ):
+    if typ == pa.string():
+        return pa.array([None if m else f"s{v:03d}"
+                         for v, m in zip(vals, nulls)], type=typ)
+    if typ == DATE:
+        return _keys(vals, nulls, pa.int32()).cast(DATE)
+    return _keys(vals, nulls, typ)
+
+
+def _multi_key_case(name, r, nb, ns):
+    """Several keys a side. The build has nulls in each key alone; the
+    stream has them too, and beside the tuples that match: tuples outside
+    ONE key's range whose unguarded packed value is a build tuple's, the
+    tuples that would pack to the sentinel of the rows without a key and to
+    -1, and each key at the extremes of its stream dtype."""
+    btypes, stypes, radix, step, base, repeat = MULTI_KEY_CASES[name]
+    n = len(radix)
+    ids = (r.integers(0, np.prod(radix), nb) if repeat
+           else r.permutation(np.prod(radix))[:nb])
+    digits = np.stack(np.unravel_index(ids, radix), axis=1)
+    bk = digits * np.asarray(step) + np.asarray(base)          # (nb, n)
+    b_null = r.random((nb, n)) < 0.05
+    keyed = bk[~b_null.any(axis=1)]
+    vmin, vmax = keyed.min(axis=0), keyed.max(axis=0)
+    rng = vmax - vmin + 1
+    far = []
+    for j in range(n):
+        if stypes[j] == pa.string():
+            continue
+        # a date's extremes are datetime.date's (years 1 and 9999)
+        least, most = ((-719_162, 2_932_896) if stypes[j] == DATE else
+                       (I64.min, I64.max) if stypes[j] == pa.int64() else
+                       (I32.min, I32.max))
+        for t in keyed[:12]:
+            lo, hi = t.copy(), t.copy()
+            lo[j], hi[j] = least, most
+            far += [lo, hi]
+            if stypes[j] != btypes[j]:      # the build dtype would wrap it
+                wide = t.copy()
+                wide[j] += 1 << 32
+                far.append(wide)
+            if j > 0 and stypes[j - 1] != pa.string():
+                up, down = t.copy(), t.copy()
+                up[j - 1], up[j] = t[j - 1] - 1, t[j] + rng[j]
+                down[j - 1], down[j] = t[j - 1] + 1, t[j] - rng[j]
+                far += [up, down]
+    if stypes[0] != pa.string():
+        far += [np.concatenate([[vmax[0] + 1], vmin[1:]]),   # the sentinel
+                np.concatenate([[vmin[0] - 1], vmax[1:]])]   # -1
+    far = np.asarray(far, dtype=np.int64).reshape(-1, n)
+    sk = np.concatenate([bk, bk[r.integers(0, nb, ns - nb - len(far))], far])
+    s_null = r.random((ns, n)) < 0.05
+    s_null[-len(far):] = False
+    rkeys = [f"rk{j}" for j in range(n)]
+    lkeys = [f"lk{j}" for j in range(n)]
+    bt = {c: _key_column(bk[:, j], b_null[:, j], btypes[j])
+          for j, c in enumerate(rkeys)}
+    st = {c: _key_column(sk[:, j], s_null[:, j], stypes[j])
+          for j, c in enumerate(lkeys)}
+    bt["rv"] = pa.array(np.arange(nb), type=pa.int32())
+    st["lv"] = pa.array(np.arange(ns), type=pa.int32())
     return pa.table(bt), pa.table(st), lkeys, rkeys, name.split("_")[0]
 
 
-MODE_CASES = ["dense_negative_far_from_zero", "dense_int32_build_int64_stream",
-              "dense_int64_max", "one_over_the_budget", "one_int32_build_sparse",
-              "dense_int16_build_sparse", "one_null_keys_probed_by_value",
-              NO_KEY, "two_duplicate_keys",
-              "rank_two_keys"]
+def _key_domain(bt, rkeys):
+    """The product of the keys' ranges over the build rows that have every
+    key: what `HashJoin.build_prep` counts as `domain`."""
+    cols = [bt[c].cast(pa.int64()) if bt.schema.field(c).type != DATE
+            else bt[c].cast(pa.int32()).cast(pa.int64()) for c in rkeys]
+    rows = [t for t in zip(*(c.to_pylist() for c in cols)) if None not in t]
+    return int(np.prod([max(k) - min(k) + 1 for k in zip(*rows)] or [0],
+                       dtype=object))
 
 
 def ref_join_rows(st, bt, lkeys, rkeys, how):
     """NumPy/plain-Python reference with Spark's semantics (a null key
-    matches nothing), as sorted row tuples."""
+    matches nothing), as sorted row tuples. `st` streams and stands left;
+    a full outer adds the build rows no stream row matched."""
     by_key = {}
-    for b in bt.to_pylist():
+    for i, b in enumerate(bt.to_pylist()):
         k = tuple(b[c] for c in rkeys)
         if None not in k:
-            by_key.setdefault(k, []).append(b)
-    out = []
+            by_key.setdefault(k, []).append((i, b))
+    out, matched = [], set()
     for s in st.to_pylist():
         hits = by_key.get(tuple(s[c] for c in lkeys), [])
-        if how == "inner" or (how == "leftouter" and hits):
-            out += [{**s, **b} for b in hits]
-        elif how == "leftouter":
+        matched.update(i for i, _ in hits)
+        if how == "inner" or (how in ("leftouter", "fullouter") and hits):
+            out += [{**s, **b} for _, b in hits]
+        elif how in ("leftouter", "fullouter"):
             out.append({**s, **{c: None for c in bt.column_names}})
         elif (how == "leftsemi") == bool(hits):
             out.append(s)
+    if how == "fullouter":
+        out += [{**{c: None for c in st.column_names}, **b}
+                for i, b in enumerate(bt.to_pylist()) if i not in matched]
     return _sorted_rows(out)
 
 
@@ -521,8 +625,11 @@ def test_probe_mode_is_chosen_from_the_build_and_matches_numpy(
     assert got == ref_join_rows(st, bt, lkeys, rkeys, how)
     assert len(got) > 0 or (case == NO_KEY and how in ("inner", "leftsemi"))
     (prep,) = _span_counts(spans, "HashJoin.build_prep")
-    assert prep["mode"] == mode, prep
+    assert prep["mode"] == mode and prep["keys"] == len(rkeys), prep
     assert {p["mode"] for p in _span_counts(spans, "HashJoin.probe")} == {mode}
+    if case in MULTI_KEY_CASES and case != "rank_string_beside_int":
+        assert prep["domain"] == _key_domain(bt, rkeys)
+        assert mode != "rank" or prep["domain"] > 1 << 62
     if mode == "dense":
         assert prep["table_slots"] >= prep["domain"]
         assert (prep["domain"] > 0) == (case != NO_KEY)
@@ -555,7 +662,7 @@ def _chain_reference(st, bt, b2):
     return ref_join_rows(first, b2, ["lv"], ["k2"], "inner")
 
 
-@pytest.mark.parametrize("case", MODE_CASES[:-1])   # single keys
+@pytest.mark.parametrize("case", SINGLE_KEY_CASES)   # the chain's hops
 def test_probe_modes_through_the_join_chain(case, spans):
     from spark_rapids_tpu.exec.joins import BroadcastHashJoinChainExec
     bt, st, lkeys, rkeys, mode = _mode_case(case)
@@ -575,43 +682,138 @@ def test_probe_modes_through_the_join_chain(case, spans):
         assert {p["modes"] for p in fused} == {mode + "+dense"}
 
 
-def _ranged_tables(vmin, n_build, step):
+def _ranged_tables(vmin, n_build, step, second=None):
     """A unique build of `n_build` keys from `vmin` in steps of `step`, and a
-    stream over and around them."""
+    stream over and around them; with `second` = (vmin, range) a second key
+    beside each, of its own range."""
     bk = vmin + np.arange(n_build) * step
     sk = np.concatenate([bk[::2], bk[:50] - 1, [vmin - 9, int(bk[-1]) + 9]])
-    bt = pa.table({"rk": pa.array(bk, type=pa.int64()),
-                   "rv": pa.array(np.arange(n_build), type=pa.int32())})
-    st = pa.table({"lk": pa.array(sk, type=pa.int64()),
-                   "lv": pa.array(np.arange(len(sk)), type=pa.int32())})
-    return bt, st
+    bt = {"rk": pa.array(bk, type=pa.int64())}
+    st = {"lk": pa.array(sk, type=pa.int64())}
+    if second is not None:
+        vmin2, range2 = second
+        bt["rk2"] = pa.array(vmin2 + np.arange(n_build) % range2,
+                             type=pa.int32())
+        # the rows whose first key matches match in the second too; the
+        # rest lie one past its range
+        lk2 = np.full(len(sk), vmin2 + range2)
+        lk2[:len(bk[::2])] = vmin2 + np.arange(n_build)[::2] % range2
+        st["lk2"] = pa.array(lk2, type=pa.int32())
+    bt["rv"] = pa.array(np.arange(n_build), type=pa.int32())
+    st["lv"] = pa.array(np.arange(len(sk)), type=pa.int32())
+    return pa.table(bt), pa.table(st)
 
 
-@pytest.mark.parametrize("through", ["join", "chain"])
-def test_a_builds_key_range_shapes_no_program(through):
-    """Two builds of one capacity whose vmin and range differ inside one
-    bucket of table slots run ONE set of compiled programs: the second
-    traces nothing and adds no kernel."""
+@pytest.mark.parametrize("through", ["join", "chain", "join_two_keys"])
+def test_a_builds_key_range_shapes_no_program(through, spans):
+    """Two builds of one capacity whose key ranges (each key's vmin and
+    range, so the strides too) differ inside one bucket of table slots run
+    ONE set of compiled programs: the second traces nothing and adds no
+    kernel."""
     from spark_rapids_tpu.runtime import fuse
 
-    def run(vmin, n_build, step):
-        bt, st = _ranged_tables(vmin, n_build, step)
+    def run(vmin, n_build, step, second):
+        two = through == "join_two_keys"
+        bt, st = _ranged_tables(vmin, n_build, step, second if two else None)
+        lkeys, rkeys = (["lk", "lk2"], ["rk", "rk2"]) if two \
+            else (["lk"], ["rk"])
         conf = RapidsConf()
         if through == "chain":
             node, b2 = _chain_over(st, bt, conf)
             want = _chain_reference(st, bt, b2)
         else:
-            node = HashJoinExec("inner", [col("lk")], [col("rk")],
+            node = HashJoinExec("inner", [col(c) for c in lkeys],
+                                [col(c) for c in rkeys],
                                 ArrowScanExec([st], conf=conf),
                                 ArrowScanExec([bt], conf=conf))
-            want = ref_join_rows(st, bt, ["lk"], ["rk"], "inner")
+            want = ref_join_rows(st, bt, lkeys, rkeys, "inner")
         assert _sorted_rows(node.execute_collect().to_pylist()) == want
+        assert len(want) > 30
 
-    run(1000, 400, 3)                   # domain 1198: a table of 2048 slots
+    # domain 1198 (x 3): a table of 2048 (4096) slots
+    run(1000, 400, 3, (7, 3))
     traces = fuse.stage_metrics()["traces"]
     with fuse._lock:
         kernels = len(fuse._kernels)
-    run(-123_456_789, 390, 5)           # domain 1946: the same bucket
+    # domain 1946 (x 2): the same bucket
+    run(-123_456_789, 390, 5, (-40, 2))
     assert fuse.stage_metrics()["traces"] == traces
     with fuse._lock:
         assert len(fuse._kernels) == kernels
+    assert {p["mode"] for p in _span_counts(spans, "HashJoin.build_prep")} \
+        == {"dense"}
+
+
+@pytest.mark.parametrize("case", ["dense_two_keys", "two_duplicate_tuples",
+                                  "dense_three_keys_mixed_widths"])
+@pytest.mark.parametrize("how", ["fullouter", "rightouter"])
+def test_outer_joins_over_several_keys(how, case, spans):
+    """A full outer reads which build rows matched from the packed build
+    keys (mode `two` whatever the build, and a read of the mask a batch); a
+    right outer streams its right side against a build of the left."""
+    bt, st, lkeys, rkeys, mode = _mode_case(case)
+    conf = RapidsConf()
+    if how == "fullouter":
+        left, right, lk, rk = st, bt, lkeys, rkeys
+        want = ref_join_rows(st, bt, lkeys, rkeys, "fullouter")
+        mode = "two"
+    else:       # the left side builds: rows come build columns first
+        left, right, lk, rk = bt, st, rkeys, lkeys
+        want = _sorted_rows(
+            dict(zip(bt.column_names + st.column_names,
+                     row[st.num_columns:] + row[:st.num_columns]))
+            for row in ref_join_rows(st, bt, lkeys, rkeys, "leftouter"))
+    # the full outer over two stream partitions of one broadcast build: the
+    # masks of both are merged before the unmatched build rows go out
+    parts = ([left.slice(0, 350), left.slice(350)] if how == "fullouter"
+             else [left])
+    j = BroadcastHashJoinExec(
+        how, [col(c) for c in lk], [col(c) for c in rk],
+        ArrowScanExec(parts, conf=conf), ArrowScanExec([right], conf=conf))
+    assert _sorted_rows(j.execute_collect().to_pylist()) == want
+    for prep in _span_counts(spans, "HashJoin.build_prep"):   # a partition
+        assert prep["mode"] == mode and prep["keys"] == len(rkeys), prep
+        assert prep["domain"] == _key_domain(bt, rkeys)
+    reads = _span_counts(spans, "sync.matched")
+    assert len(reads) == (2 if how == "fullouter" else 0)
+
+
+@pytest.mark.parametrize("how", ["inner", "leftouter", "fullouter",
+                                 "leftanti"])
+def test_an_empty_build_of_two_keys(how, spans):
+    bt, st, lkeys, rkeys, _ = _mode_case("dense_two_keys")
+    bt = bt.slice(0, 0)
+    conf = RapidsConf()
+    j = HashJoinExec(how, [col(c) for c in lkeys], [col(c) for c in rkeys],
+                     ArrowScanExec([st], conf=conf),
+                     ArrowScanExec([bt], conf=conf))
+    got = _sorted_rows(j.execute_collect().to_pylist())
+    assert got == ref_join_rows(st, bt, lkeys, rkeys, how)
+    assert len(got) == (0 if how == "inner" else st.num_rows)
+    (prep,) = _span_counts(spans, "HashJoin.build_prep")
+    assert (prep["keys"], prep["rows"], prep["domain"]) == (2, 0, 0), prep
+    assert prep["mode"] == ("two" if how == "fullouter" else "dense")
+
+
+def test_a_hoisted_filter_masks_the_rank_path_too(spans):
+    """The planner hoists an inner join's stream filter into the probe
+    whatever the build turns out to hold; a single key whose range cannot
+    be packed takes the rank path, which has to apply the filter as the
+    packed path does."""
+    from spark_rapids_tpu.session import TpuSession
+    spark = TpuSession()
+    lk = [I64.min, 5, 5, 7, I64.max, None, 5] * 30
+    left = spark.create_dataframe({"k": pa.array(lk, pa.int64()),
+                                   "a": pa.array(range(210), pa.int64())})
+    right = spark.create_dataframe({"k": pa.array([I64.min, 5, I64.max],
+                                                  pa.int64()),
+                                    "b": pa.array([1, 2, 3], pa.int64())})
+    df = left.filter(col("a") > 100).join(right, on="k")
+    assert "Filter[stream]" in df.explain(fused=True)
+    out = df.collect()
+    b_of = {I64.min: 1, 5: 2, I64.max: 3}
+    want = sorted((k, a, b_of[k]) for a, k in enumerate(lk)
+                  if a > 100 and k in b_of)
+    assert sorted(zip(*(out[c].to_pylist() for c in "kab"))) == want
+    (prep,) = _span_counts(spans, "HashJoin.build_prep")
+    assert prep["mode"] == "rank" and prep["domain"] == 1 << 64, prep

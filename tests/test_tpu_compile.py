@@ -152,36 +152,109 @@ def test_compaction_compiles_to_one_scatter(capacity, one_chip):
     assert _fits(compiled)
 
 
-def test_dense_join_probe_compiles_without_a_loop(one_chip, as_on_tpu):
+@pytest.mark.parametrize("keys, slots, bcap, rows", [
+    ((jnp.int64,), 2 << 20, 1 << 18, N),
+    ((jnp.int64, jnp.int32), 4 << 20, 1 << 18, 1 << 19)])
+def test_dense_join_probe_compiles_without_a_loop(one_chip, as_on_tpu, keys,
+                                                  slots, bcap, rows):
     """The direct-address probe of a unique build over a compact key domain
-    (exec/joins.py mode `dense`) at q3's shapes: a 1 Mi-row lineitem batch
-    against the 2 Mi-slot table of the filtered orders (capacity 256 Ki).
-    The lookup is a gather, with no `while` (mode `one`'s `searchsorted` is
-    a 19-step loop over the 64-bit key's halves); the table is one scatter
-    a build."""
+    (exec/joins.py mode `dense`) at q3's shapes, a 1 Mi-row lineitem batch
+    against the 2 Mi-slot table of the filtered orders (capacity 256 Ki),
+    and at q5's two-key hop, a 512 Ki-row batch against a bigint and an int
+    key packed into a 4 Mi-slot table. Packing is elementwise and the lookup
+    a gather, with no `while` (mode `one`'s `searchsorted` is a 19-step loop
+    over the 64-bit key's halves, the rank path runs five of them a batch);
+    the table is one scatter a build."""
     import functools
     from spark_rapids_tpu.exec import joins as XJ
-    slots, bcap = 2 << 20, 1 << 18
-    s64 = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
     s32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    n = len(keys)
+
+    def lookup(table, lims, vals, valid):
+        cols = [Col(v, ok, T.LONG) for v, ok in zip(vals, valid)]
+        packed, ok = XJ._pack_keys(cols, lims)
+        return table[packed.astype(jnp.int32)], ok
+
     probe = _compile(
-        lambda table, vmin, vmax, keys: XJ._dense_lookup(
-            (table, vmin, vmax), keys),
-        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
-        s64, s64, jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip))
+        lookup, jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2, n), jnp.int64, sharding=one_chip),
+        [jax.ShapeDtypeStruct((rows,), k, sharding=one_chip) for k in keys],
+        [jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)] * n)
     hlo = probe.as_text()
     assert "while" not in hlo and "gather" in hlo
+    # a single key is `k - vmin`: packing multiplies from the second key on
+    assert (" multiply(" in hlo) == (n > 1)
     assert _fits(probe)
     searched = _compile(
         lambda build, keys: jnp.searchsorted(build, keys),
         jax.ShapeDtypeStruct((bcap,), jnp.int64, sharding=one_chip),
-        jax.ShapeDtypeStruct((N,), jnp.int64, sharding=one_chip))
+        jax.ShapeDtypeStruct((rows,), jnp.int64, sharding=one_chip))
     assert "while" in searched.as_text()     # what the probe no longer pays
     table = _compile(
         functools.partial(XJ._dense_table, slots=slots),
-        jax.ShapeDtypeStruct((bcap,), jnp.int64, sharding=one_chip), s32, s64)
+        jax.ShapeDtypeStruct((bcap,), jnp.int64, sharding=one_chip), s32)
     assert "scatter" in table.as_text() and "while" not in table.as_text()
     assert _fits(table)
+
+
+def test_join_chain_reads_its_lookup_tables_from_fast_memory(one_chip,
+                                                             as_on_tpu,
+                                                             monkeypatch):
+    """The fused two-hop chain at q5's shapes (a 1 Mi-row batch; a 256 Ki
+    build under a 2 Mi-slot table, then a 16 Ki build): the compiler moves
+    each hop's table and position->row permutation into the chip's fast
+    memory (`S(1)` in the layout) before the gathers that read them. A gather
+    of 1 Mi rows from a table left in HBM took 36 ms on a v5e where one from
+    fast memory took 9 (PERF.md section 6, PR 34): the permutation's gather
+    has to depend on the table's gather alone, or the second hop's stays
+    behind. The kernel is the one the operator builds, captured on the CPU."""
+    import re
+    import pyarrow as pa
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.exec import joins as XJ
+    from spark_rapids_tpu.exec.basic import ArrowScanExec
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.runtime import fuse
+    r = np.random.default_rng(1)
+    st = pa.table({"k1": pa.array(r.integers(1, 1_500_001, N), pa.int64()),
+                   "k2": pa.array(r.integers(1, 10_001, N), pa.int64()),
+                   "x": pa.array(r.random(N), pa.float64())})
+    b1 = pa.table({"a": pa.array(np.sort(r.permutation(1_500_000)[:240_000]),
+                                 pa.int64()),
+                   "av": pa.array(np.arange(240_000), pa.int64())})
+    b2 = pa.table({"b": pa.array(np.arange(1, 10_001), pa.int64()),
+                   "bv": pa.array(np.arange(10_000), pa.int32())})
+    conf = RapidsConf()
+    inner = XJ.BroadcastHashJoinExec(
+        "inner", [col("k1")], [col("a")], ArrowScanExec([st], conf=conf),
+        ArrowScanExec([b1], conf=conf))
+    chain = XJ.maybe_chain(XJ.BroadcastHashJoinExec(
+        "inner", [col("k2")], [col("b")], inner,
+        ArrowScanExec([b2], conf=conf)), conf)
+    captured = []
+    real = fuse.call_fused
+
+    def spy(key, name, build, args, eager):
+        if name == "HashJoinChain.probe" and not captured:
+            captured.append((build(), args))
+        return real(key, name, build, args, eager)
+
+    monkeypatch.setattr(fuse, "call_fused", spy)
+    assert chain.execute_collect().num_rows > 100_000
+    kernel, args = captured[0]
+    hlo = _compile(kernel, *jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip), args)).as_text()
+    assert "while" not in hlo
+    lookups = {m.group(1) for m in re.finditer(
+        r"^%(fused_computation[\w.]*) \(.*?\n(.*?)^\}", hlo, re.M | re.S)
+        if re.search(r" gather\(.*hop\d/lookup/", m.group(2))}
+    entry = hlo[hlo.index("ENTRY"):]
+    layout = dict(re.findall(r"^\s*(%[\w.\-]+) = (\S+) ", entry, re.M))
+    tables = [layout[m.group(1)] for m in re.finditer(
+        r"fusion\((%[\w.\-]+), .*calls=%(fused_computation[\w.]*)", entry)
+        if m.group(2) in lookups]
+    assert len(tables) == 4 and all("S(1)" in t for t in tables), tables
 
 
 def test_parquet_dictionary_decode_compiles(one_chip, as_on_tpu):
