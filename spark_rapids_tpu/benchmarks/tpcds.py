@@ -104,6 +104,8 @@ def generate(sf: float, outdir: str, files_per_table: int = 4) -> dict:
         "i_item_sk": pa.array(isk),
         "i_item_id": pa.array([f"ITEM{k:08d}" for k in isk]),
         "i_item_desc": pa.array([f"desc {k} words" for k in isk]),
+        # from the key, not drawn: the earlier columns stay byte-identical
+        "i_product_name": pa.array([f"product{k:06d}" for k in isk]),
         "i_brand_id": pa.array(brand_id.astype(np.int32)),
         "i_brand": pa.array([f"brand#{b}" for b in brand_id]),
         "i_class_id": pa.array(class_id.astype(np.int32)),
@@ -173,6 +175,8 @@ def generate(sf: float, outdir: str, files_per_table: int = 4) -> dict:
     write("store", pa.table({
         "s_store_sk": pa.array(np.arange(1, n_store + 1, dtype=np.int64)),
         "s_store_name": pa.array([f"store{k}" for k in range(n_store)]),
+        "s_store_id": pa.array([f"AAAAAAAA{k:08d}"
+                                for k in range(1, n_store + 1)]),
         "s_zip": pa.array([f"{z:05d}" for z in szips]),
         "s_city": pa.array(cities[rng.integers(0, len(cities), n_store)]),
         "s_county": pa.array(
@@ -2121,6 +2125,8 @@ def sql_suite_oracles():
         "q69": (np_q69, set()),
         # q22: inventory rollup; qoh average is float
         "q22": (np_q22, {4}),
+        # q67: eight-key rollup, rank() over exact int64 sums
+        "q67": (np_q67, set()),
     }
     from spark_rapids_tpu.sql.tpcds_queries import SQL_QUERIES
     out = {}
@@ -2269,4 +2275,54 @@ def np_q22(tb):
     rows = [key + (a[1] / a[0],) for key, a in acc.items()]
     rows.sort(key=lambda r: (r[4],) + tuple((v is not None, v)
                                             for v in r[:4]))
+    return rows[:100]
+
+
+# texts whose answer is exact only over exact money: they run over a view of
+# store_sales with ss_sales_price as int64 hundredths (the unscaled value of
+# the specification's DECIMAL(7,2)), and their oracles count in hundredths
+EXACT_MONEY = {"q67"}
+
+
+def hundredths(price):
+    """A float price column as int64 hundredths."""
+    return np.rint(np.asarray(price, dtype=np.float64) * 100).astype(np.int64)
+
+
+def np_q67(tb):
+    """Official q67: sales summed over the eight-key ROLLUP of the item
+    hierarchy, the date and the store for one year of month_seq, ranked
+    inside each i_category by the sum, the first hundred of each. Money in
+    int64 hundredths: in a ROLLUP ties are systematic (a product's total
+    equals its one year's), exact sums rank them as one, float sums do not
+    reliably. rank() = 1 + the rows of the partition with a greater sum."""
+    dd, it, st, ss = (tb[t] for t in ("date_dim", "item", "store",
+                                      "store_sales"))
+    when = {k: (int(y), int(q), int(m)) for k, y, q, m, seq in zip(
+        dd["d_date_sk"], dd["d_year"], dd["d_qoy"], dd["d_moy"],
+        dd["d_month_seq"]) if 1200 <= seq <= 1211}
+    what = {k: v for k, *v in zip(it["i_item_sk"], it["i_category"],
+                                  it["i_class"], it["i_brand"],
+                                  it["i_product_name"])}
+    where = dict(zip(st["s_store_sk"], st["s_store_id"]))
+    acc = {}
+    for dk, ik, sk, p, q in zip(ss["ss_sold_date_sk"], ss["ss_item_sk"],
+                                ss["ss_store_sk"],
+                                hundredths(ss["ss_sales_price"]),
+                                ss["ss_quantity"]):
+        if dk not in when or ik not in what or sk not in where:
+            continue
+        full = tuple(what[ik]) + when[dk] + (where[sk],)
+        for lvl in range(9):
+            key = full[:lvl] + (None,) * (8 - lvl)
+            acc[key] = acc.get(key, 0) + int(p) * int(q)
+    by_category = {}
+    for key, total in acc.items():
+        by_category.setdefault(key[0], []).append(total)
+    rows = []
+    for key, total in acc.items():
+        rk = 1 + sum(1 for t in by_category[key[0]] if t > total)
+        if rk <= 100:
+            rows.append(key + (total, rk))
+    rows.sort(key=lambda r: tuple((v is not None, v) for v in r))
     return rows[:100]

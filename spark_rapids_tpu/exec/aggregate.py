@@ -24,6 +24,7 @@ from spark_rapids_tpu.expr.aggregates import AggregateFunction
 from spark_rapids_tpu.ops import grouping as G
 from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.filtering import compact_cols, gather_cols
+from spark_rapids_tpu.ops.sorting import held_bits, words_for
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime import retry as R
 from spark_rapids_tpu.runtime import tracing
@@ -136,11 +137,14 @@ class HashAggregateExec(TpuExec):
         return T.StructType(fields)
 
     # ------------------------------------------------------------------
-    def _aggregate_batch(self, batch: ColumnarBatch, merge: bool) -> ColumnarBatch:
+    def _aggregate_batch(self, batch: ColumnarBatch, merge: bool,
+                         sp=tracing.NO_SPAN):
         """One fused update-or-merge aggregation, jit-compiled per shape bucket
         (runtime/fuse.py). In merge mode the batch is in keys+state layout; in
-        update mode it is raw child output. Returns a batch in keys+state
-        layout with one row per group."""
+        update mode it is raw child output. Returns (a batch in keys+state
+        layout with one row per group, the words the group sort folded its
+        keys into by what they hold, or None: what the chain predicts by).
+        ``sp``: the caller's span, for what the grouping did."""
         from spark_rapids_tpu.columnar.encoded import (EncodedColumnVector,
                                                        densify_cols)
         from spark_rapids_tpu.expr.core import Col
@@ -153,6 +157,7 @@ class HashAggregateExec(TpuExec):
             for e in (*self.group_exprs, *self.agg_exprs,
                       *([pre] if pre is not None else []),
                       *(prep or [])))
+        n_words, need = None, 0
         if batch.columns and not ctx_sensitive:
             # scan-side chain: still-encoded scan columns enter the kernel AS
             # ENCODED PAGES and expand inside this fused program (late
@@ -166,28 +171,26 @@ class HashAggregateExec(TpuExec):
                        and isinstance(c, EncodedColumnVector) else None)
                 in_cols.append(enc if enc is not None else Col.from_vector(c))
             nr = jnp.asarray(batch.lazy_num_rows, jnp.int32)
-            vmin_t, has_hint, presorted = self._key_range_hint(
-                batch, in_cols, nr, merge)
+            n_words, need, presorted = self._key_stats(batch, in_cols, nr,
+                                                       merge)
             key = ("agg", merge, fuse.schema_key(
                 self._partial_schema() if merge else self.child.output),
                 tuple(fuse.expr_key(e) for e in self.group_exprs),
                 tuple(fuse.expr_key(e) for e in self.agg_exprs),
                 fuse.expr_key(pre) if pre is not None else None,
                 tuple(fuse.expr_key(e) for e in prep) if prep is not None
-                else None, self.prefilter_on_projected, has_hint, presorted)
+                else None, self.prefilter_on_projected, n_words, presorted)
 
             def build():
-                def kernel(cols, num_rows, vmin):
+                def kernel(cols, num_rows):
                     cols = densify_cols(cols)
                     ctx = EvalContext(cols, num_rows, cols[0].values.shape[0])
-                    return self._agg_kernel(
-                        ctx, merge,
-                        range_hint=(vmin, True) if has_hint else None,
-                        presorted=presorted)
+                    return self._agg_kernel(ctx, merge, n_words=n_words,
+                                            presorted=presorted)
                 return kernel
 
-            compacted, n_groups = fuse.call_fused(
-                key, "HashAggregateExec", build, (in_cols, nr, vmin_t),
+            compacted, n_groups, _need, facts = fuse.call_fused(
+                key, "HashAggregateExec", build, (in_cols, nr),
                 lambda: self._agg_kernel(EvalContext.from_batch(batch), merge))
             # stage-boundary right-sizing: a high-reduction aggregation at a
             # big capacity stops dragging that capacity into downstream
@@ -199,13 +202,32 @@ class HashAggregateExec(TpuExec):
                 if resized is not None:
                     compacted, n_groups = resized
         else:
-            compacted, n_groups = self._agg_kernel(
+            compacted, n_groups, _need, facts = self._agg_kernel(
                 EvalContext.from_batch(batch), merge)
+        if sp:
+            self._count_grouping(sp, facts, need, batch.lazy_num_rows,
+                                 batch.capacity, n_groups)
         cols = [c.to_vector() for c in compacted]
-        return ColumnarBatch(cols, n_groups, self._partial_schema())
+        return (ColumnarBatch(cols, n_groups, self._partial_schema()),
+                n_words)
+
+    @staticmethod
+    def _count_grouping(sp, facts, need_bits, rows, capacity, groups):
+        """What the grouping did, on the caller's span: ``path`` (``dense`` /
+        ``sort`` / ``global``), ``keys``, ``sort_operands``, ``packed_bits``
+        (the bits the folded keys and the row index take), and the rows the
+        host already holds (no read is made for a count)."""
+        counts = dict(facts.items, capacity=capacity)
+        if need_bits:
+            counts["packed_bits"] = int(need_bits)
+        if isinstance(rows, int):
+            counts["rows"] = rows
+        if isinstance(groups, int):
+            counts["groups"] = groups
+        sp.set(**counts)
 
     def _chain_step(self, acc: ColumnarBatch, batch: ColumnarBatch,
-                    A: int, pred_P: int):
+                    A: int, pred_P: int, n_words=None, sp=tracing.NO_SPAN):
         """One fused update→concat→merge step of the group-by chain: aggregate
         the incoming batch, pad-concat the partial onto the accumulated
         partials, and merge-aggregate — ONE program per batch, like
@@ -222,8 +244,14 @@ class HashAggregateExec(TpuExec):
         mispredict the caller redoes the batch unchained (degraded, never
         wrong).
 
-        Returns ``(accepted, merged_batch, merged_groups, update_groups)``
-        or None when the shape cannot chain at all.
+        ``n_words``: the words the group sorts fold their keys into by what
+        the keys hold, PREDICTED from the last unchained batch; both sorts
+        say what they needed in the status the step reads anyway, and a key
+        that outgrew the prediction rejects the step like a capacity
+        mispredict does (ops/sorting.fold_keys).
+
+        Returns ``(accepted, merged_batch, merged_groups, update_groups,
+        words the keys needed)`` or None when the shape cannot chain at all.
         """
         from spark_rapids_tpu.columnar.encoded import (EncodedColumnVector,
                                                        densify_cols)
@@ -266,25 +294,29 @@ class HashAggregateExec(TpuExec):
                tuple(fuse.expr_key(e) for e in self.agg_exprs),
                fuse.expr_key(pre) if pre is not None else None,
                tuple(fuse.expr_key(e) for e in prep) if prep is not None
-               else None, self.prefilter_on_projected)
+               else None, self.prefilter_on_projected, n_words)
 
         def build():
             def kernel(a_cols, b_cols, acc_n, nr):
                 b_cols = densify_cols(b_cols)
                 uctx = EvalContext(b_cols, nr, bcap)
-                # no key-stats probe: skipping the range hint / presorted
-                # strategies is value-neutral (every sort embeds the row
-                # index, so all strategies produce the same total order)
-                upd_cols, upd_n = self._agg_kernel(uctx, merge=False)
+                # no key-stats probe: skipping the presorted strategy is
+                # value-neutral (every sort embeds the row index, so all
+                # strategies produce the same total order)
+                upd_cols, upd_n, need_u, _ = self._agg_kernel(
+                    uctx, merge=False, n_words=n_words)
                 counts_v = jnp.stack([acc_n, upd_n.astype(jnp.int32)])
                 per_col = [[a, u] for a, u in zip(a_cols, upd_cols)]
                 with jax.named_scope("concat"):
                     cat = concat_cols(per_col, counts_v, Cc, (acc_cap, bcap))
                 mctx = EvalContext(cat, acc_n + upd_n, Cc)
-                mg_cols, mg_n = self._agg_kernel(mctx, merge=True)
+                mg_cols, mg_n, need_m, facts = self._agg_kernel(
+                    mctx, merge=True, n_words=n_words)
                 status = jnp.stack([jnp.asarray(mg_n, jnp.int32),
-                                    jnp.asarray(upd_n, jnp.int32)])
-                return mg_cols, status
+                                    jnp.asarray(upd_n, jnp.int32),
+                                    jnp.maximum(need_u, need_m)
+                                    .astype(jnp.int32)])
+                return mg_cols, status, facts
             return kernel
 
         acc_n_t = jnp.asarray(acc.lazy_num_rows, jnp.int32)
@@ -294,99 +326,129 @@ class HashAggregateExec(TpuExec):
                               lambda: None)
         if out is None:
             return None   # uncacheable key or trace fallback → go unchained
-        mg_cols, status = out
-        with tracing.span("sync.status") as sp:
+        mg_cols, status, facts = out
+        with tracing.span("sync.status") as sync:
             st = np.asarray(status)   # the ONE host sync of the chained step
-            mg_n, upd_n = int(st[0]), int(st[1])
-            sp.set(rows=mg_n, capacity=Cc)
+            mg_n, upd_n, need = int(st[0]), int(st[1]), int(st[2])
+            sync.set(rows=mg_n, capacity=Cc)
+        if sp:
+            self._count_grouping(sp, facts, need, batch.lazy_num_rows, Cc,
+                                 mg_n)
         # accept only when the concat ran at the bucket the unchained loop's
         # concat_batches would have picked (bucket of the TRUE total): the
         # merge's f64 reduction order is capacity-sensitive, so an equal
         # bucket is exactly the bit-identity condition
-        accepted = bucket_capacity(max(A + upd_n, 1)) == Cc
+        accepted = (bucket_capacity(max(A + upd_n, 1)) == Cc
+                    and need <= (held_bits(n_words) if n_words else 0))
         if accepted and self.conf.stage_fusion_enabled:
             # same stage-boundary right-sizing the unchained merge applies —
-            # mg_n is already a host int, so this syncs nothing extra
+            # mg_n is already a host int, so this syncs nothing extra, and
+            # the accumulator lands at ITS bucket whenever that is smaller:
+            # the steps after it then share one program (the key holds the
+            # accumulator's capacity; q67's third step compiled two more
+            # two-operand sorts for an accumulator left at 2 Mi)
             from spark_rapids_tpu.ops.filtering import maybe_host_resize
-            resized = maybe_host_resize(mg_cols, mg_n)
+            resized = maybe_host_resize(mg_cols, mg_n, min_shrink=2)
             if resized is not None:
                 mg_cols, mg_n = resized
         merged = ColumnarBatch([c.to_vector() for c in mg_cols], mg_n,
                                self._partial_schema())
-        return accepted, merged, mg_n, upd_n
+        return (accepted, merged, mg_n, upd_n,
+                words_for(need) if need else None)
 
-    def _key_range_hint(self, batch, in_cols, nr, merge: bool):
-        """(vmin_traced, has_hint, presorted) for the single-wide-int-key
-        group-by: one cheap reduction + ONE host sync per batch decides
-        whether the key range fits the packed single-operand sort (the
-        join-build strategy-pick pattern, exec/joins._prep_fast_build) — a
-        statically 64-bit key (LONG/TIMESTAMP) otherwise forces the 2-operand
-        wide sort, ~3x the packed cost at 1M rows (measured on XLA:CPU). The
-        same probe now also checks whether the live rows already ARRIVE
+    def _key_stats(self, batch, in_cols, nr, merge: bool):
+        """(n_words, need_bits, presorted) for the sort-path group-by: one
+        cheap reduction program + ONE host read a batch say how many sort
+        operands the keys fold into BY WHAT THEY HOLD (ops/sorting.fold_keys:
+        an int64 key that holds a million values takes 20 bits, not an
+        operand pair of the comparator sort), which is static in the
+        aggregate's program; the ranges themselves stay traced there, so one
+        program serves every batch that needs as many words. For a single
+        key the same probe also checks whether the live rows already ARRIVE
         key-sorted with no nulls (clustered fact tables — TPC-H lineitem is
         physically ordered by l_orderkey): then the sort vanishes entirely
         and the segment path runs over the input order (the sorted-input
-        group-by; `presorted` wins over the hint). Gated to big capacities
-        (below, the comparator fallback is already cheap), keys with no
-        hoisted preprojection (the probe reads the raw batch), and int
-        dtypes too wide to pack statically."""
+        group-by; `presorted` wins over the fold). Gated to big capacities
+        (below, any sort is cheap), dense inputs (the probe would expand an
+        encoded column a second time) and key sets that hold an integer
+        whose type alone says too little: a 64-bit key, or one among
+        several. Every other key set folds by its types and dictionaries
+        with no read (n_words None), or cannot fold at all."""
+        from spark_rapids_tpu.columnar.encoded import EncodedCol
+        from spark_rapids_tpu.ops.sorting import (SortOrder, fold_keys,
+                                                  ranged_key)
         from spark_rapids_tpu.runtime import fuse
-        zero = jnp.zeros((), jnp.int64)
-        cap = batch.capacity
-        if (len(self.group_exprs) != 1 or cap < (1 << 17)
-                or (not merge and self.preproject is not None)):
-            return zero, False, False
-        e = self.group_exprs[0]
-        try:
-            kdt = e.dtype
-        except Exception:  # noqa: BLE001 — unresolvable dtype: no hint
-            return zero, False, False
-        if (not isinstance(kdt, (T.IntegralType, T.TimestampType))
-                or isinstance(kdt, T.BooleanType)
-                or jnp.iinfo(kdt.jnp_dtype).bits <= 32):
-            return zero, False, False   # narrow keys already pack statically
+        no = (None, 0, False)
+        if (not self.group_exprs or batch.capacity < (1 << 17)
+                or any(isinstance(c, EncodedCol) for c in in_cols)):
+            return no
+        ranged = False
+        for e in self.group_exprs:
+            try:
+                kdt = e.dtype
+            except Exception:  # noqa: BLE001 — unresolvable dtype: no fold
+                return no
+            if isinstance(kdt, (T.StringType, T.BooleanType)):
+                continue
+            if not ranged_key(kdt):
+                return no
+            ranged = ranged or len(self.group_exprs) > 1 or \
+                jnp.iinfo(kdt.jnp_dtype).bits > 32
+        if not ranged:
+            return no
+        single = len(self.group_exprs) == 1
+        prep = self.preproject if not merge else None
         skey = ("agg_key_stats", merge, fuse.schema_key(
             self._partial_schema() if merge else self.child.output),
-            fuse.expr_key(e))
+            tuple(fuse.expr_key(e) for e in self.group_exprs),
+            tuple(fuse.expr_key(e) for e in prep) if prep is not None
+            else None)
 
         def build():
             def kernel(cols, num_rows):
-                from spark_rapids_tpu.columnar.encoded import densify_cols
-                cols = densify_cols(cols)
                 cap_ = cols[0].values.shape[0]
                 ctx = EvalContext(cols, num_rows, cap_)
-                k = ctx.cols[0] if merge else e.eval(ctx)
-                vals = k.values.astype(jnp.int64)
-                live = jnp.arange(cap_, dtype=jnp.int32) < num_rows
-                eligible = k.validity & live
-                vmin = jnp.min(jnp.where(eligible, vals,
-                                         jnp.iinfo(jnp.int64).max))
-                vmax = jnp.max(jnp.where(eligible, vals,
-                                         jnp.iinfo(jnp.int64).min))
-                # sorted = every live row valid AND values nondecreasing over
-                # the live prefix (all-valid means validity boundaries cannot
-                # reorder groups, so input order == sorted group order)
-                all_valid = jnp.all(k.validity | ~live)
-                nondec = jnp.all(jnp.where(live[1:],
-                                           vals[1:] >= vals[:-1], True))
-                return vmin, vmax, all_valid & nondec
+                if prep is not None:
+                    ctx = EvalContext([e.eval(ctx) for e in prep], num_rows,
+                                      cap_)
+                keys = (ctx.cols[:len(self.group_exprs)] if merge
+                        else [e.eval(ctx) for e in self.group_exprs])
+                folded = fold_keys(keys, [SortOrder() for _ in keys],
+                                   num_rows, cap_, n_words=1)
+                need = 0 if folded is None else folded.need_bits
+                is_sorted = False
+                if single:
+                    # sorted = every live row valid AND values nondecreasing
+                    # over the live prefix (all-valid means validity
+                    # boundaries cannot reorder groups, so input order ==
+                    # sorted group order)
+                    k = keys[0]
+                    vals = k.values.astype(jnp.int64)
+                    live = jnp.arange(cap_, dtype=jnp.int32) < num_rows
+                    is_sorted = jnp.all(k.validity | ~live) & jnp.all(
+                        jnp.where(live[1:], vals[1:] >= vals[:-1], True))
+                return jnp.stack([jnp.asarray(need, jnp.int32),
+                                  jnp.asarray(is_sorted, jnp.int32)])
             return kernel
 
-        vmin_t, vmax_t, sorted_t = fuse.call_fused(
-            skey, "HashAggregateExec.key_stats", build, (in_cols, nr),
-            lambda: build()(in_cols, nr))
-        vmin, vmax = int(vmin_t), int(vmax_t)
-        presorted = bool(sorted_t) and self.conf.stage_fusion_enabled
-        w = 62 - max((cap - 1).bit_length(), 1) - 1
-        fits = vmax >= vmin and (vmax - vmin) < (1 << w) and not presorted
-        return jnp.asarray(vmin if fits else 0, jnp.int64), fits, presorted
+        with tracing.span("sync.key_stats") as sp:
+            need, is_sorted = (int(x) for x in fuse.call_fused(
+                skey, "HashAggregateExec.key_stats", build, (in_cols, nr),
+                lambda: build()(in_cols, nr)))
+            sp.set(rows=need, capacity=batch.capacity)
+        if bool(is_sorted) and self.conf.stage_fusion_enabled:
+            return None, 0, True
+        if not need:
+            return no
+        return words_for(need), need, False
 
-    def _agg_kernel(self, ctx: EvalContext, merge: bool, range_hint=None,
+    def _agg_kernel(self, ctx: EvalContext, merge: bool, n_words=None,
                     presorted: bool = False):
-        """Pure per-batch aggregation body (traceable). `presorted` asserts
-        the per-batch probe (_key_range_hint) PROVED the single key column
-        arrives sorted and null-free: the segment sort AND every row gather
-        collapse to identity."""
+        """Pure per-batch aggregation body (traceable): (columns, groups,
+        the bits the folded keys needed, fuse.Facts of the path taken).
+        `n_words`: see _key_stats. `presorted` asserts that probe PROVED the
+        single key column arrives sorted and null-free: the segment sort AND
+        every row gather collapse to identity."""
         cap = ctx.capacity
         keep = None
 
@@ -409,14 +471,18 @@ class HashAggregateExec(TpuExec):
                     keep = eval_keep(ctx)
         with jax.named_scope("HashAggregate.merge" if merge
                              else "HashAggregate.update"):
-            return self._agg_groups(ctx, keep, merge, range_hint, presorted)
+            return self._agg_groups(ctx, keep, merge, n_words, presorted)
 
-    def _agg_groups(self, ctx: EvalContext, keep, merge: bool, range_hint,
+    def _agg_groups(self, ctx: EvalContext, keep, merge: bool, n_words,
                     presorted: bool):
         """_agg_kernel after its fused-in filter and projection: group the
         rows (dense codes, or sort + segments) and reduce each aggregate."""
+        from spark_rapids_tpu.ops.filtering import front_perm
+        from spark_rapids_tpu.ops.sorting import SortOrder, unfold_keys
+        from spark_rapids_tpu.runtime.fuse import Facts
         cap = ctx.capacity
         nkeys = len(self.group_exprs)
+        gs, need = None, jnp.zeros((), jnp.int32)
         if nkeys:
             if merge:
                 key_cols = [ctx.cols[i] for i in range(nkeys)]
@@ -424,7 +490,8 @@ class HashAggregateExec(TpuExec):
                 key_cols = [e.eval(ctx) for e in self.group_exprs]
             dense = self._agg_dense(ctx, merge, key_cols, live_mask=keep)
             if dense is not None:
-                return dense
+                return (*dense, need,
+                        Facts(path="dense", keys=nkeys, sort_operands=0))
             if keep is not None:
                 # segment path sorts by key — masked rows must become padding,
                 # so compact first (still inside this one fused program)
@@ -432,18 +499,12 @@ class HashAggregateExec(TpuExec):
                 ctx = EvalContext(new_cols, cnt, cap)
                 key_cols = [e.eval(ctx) for e in self.group_exprs]
                 keep = None
-            combined = G.combine_compact_keys(key_cols)
-            presorted = presorted and combined is None and len(key_cols) == 1
-            perm, seg_ids, boundary, live = G.group_segments(
-                [combined] if combined is not None else key_cols,
-                ctx.num_rows, cap,
-                range_hint=(range_hint if combined is None
-                            and len(key_cols) == 1 else None),
-                presorted=presorted)
-            sorted_keys = ([Col(c.values, c.validity & live, c.dtype,
-                                c.dictionary) for c in key_cols]
-                           if presorted else
-                           gather_cols(key_cols, perm, live))
+            presorted = presorted and len(key_cols) == 1
+            gs = G.sorted_groups(key_cols, ctx.num_rows, cap, n_words=n_words,
+                                 presorted=presorted)
+            perm, seg_ids, boundary, live = gs[:4]
+            if gs.folded is not None and n_words is not None:
+                need = jnp.asarray(gs.folded.need_bits, jnp.int32)
         else:
             if keep is not None:
                 # segment kernels need contiguous runs — masked rows mid-run
@@ -456,7 +517,6 @@ class HashAggregateExec(TpuExec):
             seg_ids = jnp.where(live, 0, cap - 1).astype(jnp.int32)
             # global agg: always one output row, even on empty input (Spark)
             boundary = jnp.arange(cap, dtype=jnp.int32) == 0
-            sorted_keys = []
         segctx = G.segment_structure(seg_ids, cap)
 
         # aggregate states are PER-ROW (row i = aggregate of its whole
@@ -485,7 +545,25 @@ class HashAggregateExec(TpuExec):
                 outs = f.update(in_sorted, segctx)
             off += nstates
             state_cols.extend(outs)
-        return compact_cols(list(sorted_keys) + state_cols, boundary)
+        if gs is None:
+            return (*compact_cols(state_cols, boundary), need,
+                    Facts(path="global", keys=0, sort_operands=0))
+        facts = {"path": "sort", "keys": nkeys, "sort_operands": gs.operands}
+        if gs.folded is None:
+            out, count = compact_cols(list(gs.sorted_keys) + state_cols,
+                                      boundary)
+            return out, count, need, Facts(**facts)
+        if n_words is None:
+            facts["packed_bits"] = int(gs.folded.need_bits)
+        # the keys leave as they came: in the sort's own operands, moved to
+        # the front once and unfolded there, not a gather a key and validity
+        front, count = front_perm(boundary)
+        live_out = jnp.arange(cap, dtype=jnp.int32) < count
+        keys_out = unfold_keys(gs.folded, [w[front] for w in gs.words],
+                               key_cols, [SortOrder() for _ in key_cols],
+                               live_out)
+        return (keys_out + gather_cols(state_cols, front, live_out), count,
+                need, Facts(**facts))
 
     def _agg_dense(self, ctx: EvalContext, merge: bool, key_cols,
                    live_mask=None):
@@ -717,17 +795,21 @@ class HashAggregateExec(TpuExec):
             merge_input = self.mode == FINAL
 
             def agg_one(b, merge=merge_input):
-                with trace_range("HashAggregate.agg", self._agg_time):
-                    return self._aggregate_batch(b, merge=merge)
+                nonlocal n_words
+                with trace_range("HashAggregate.agg", self._agg_time) as sp:
+                    out, n_words = self._aggregate_batch(b, merge, sp)
+                    return out
 
             acc = None
             # group-by chain (host-side predictors): A = accumulated group
             # count, pred_P = predicted partial-group count of the next batch
-            # (last observed). Both are plain ints maintained WITHOUT extra
-            # syncs on chained iterations.
+            # (last observed), n_words = the words the last group sort folded
+            # its keys into. All plain values maintained WITHOUT extra syncs
+            # on chained iterations.
             chain_ok = (not merge_input and bool(self.group_exprs)
                         and self.conf.groupby_chain_enabled)
             A = pred_P = 0
+            n_words = None
             for batch in self.child.execute_partition(split):
                 self._in_rows.add_lazy(batch.lazy_num_rows)
                 # acquire only once data is ready for device work — acquiring before
@@ -735,25 +817,27 @@ class HashAggregateExec(TpuExec):
                 # stage and deadlock the semaphore (reference RapidsShuffleIterator
                 # acquires on data arrival, RapidsShuffleIterator.scala:300)
                 acquire_semaphore(self.metrics)
+                needed = None
                 if acc is not None and chain_ok:
-                    def chain_step(a=acc, b=batch, A=A, P=pred_P):
+                    def chain_step(a=acc, b=batch, A=A, P=pred_P, w=n_words):
                         with trace_range("HashAggregate.chain",
-                                         self._agg_time):
-                            return self._chain_step(a, b, A, P)
+                                         self._agg_time) as sp:
+                            return self._chain_step(a, b, A, P, w, sp)
                     try:
                         res = R.call_with_retry(chain_step, scope="agg.chain")
                     except R.DeviceOomError:
                         res = None   # fall back to the splittable update loop
                     if res is not None:
-                        accepted, merged, mg_n, upd_n = res
+                        accepted, merged, mg_n, upd_n, needed = res
                         if accepted:
                             acc, A, pred_P = merged, mg_n, upd_n
                             continue
-                        # capacity mispredict: DISCARD the chained result and
-                        # redo this batch unchained — never accept a result
-                        # whose concat bucket differs from the unchained one
-                        # (degraded, never wrong). The observed update count
-                        # still improves the next prediction.
+                        # capacity or key-width mispredict: DISCARD the
+                        # chained result and redo this batch unchained — never
+                        # accept a result whose concat bucket differs from the
+                        # unchained one, or whose keys outgrew their words
+                        # (degraded, never wrong). What was observed still
+                        # improves the next prediction.
                         pred_P = upd_n
                 # per-batch update aggregation under the OOM ladder: a split
                 # aggregates the halves into two partials, which the merge
@@ -772,12 +856,15 @@ class HashAggregateExec(TpuExec):
                                          self._concat_time):
                             both = concat_batches([a, p])
                         with trace_range("HashAggregate.merge",
-                                         self._agg_time):
-                            return self._aggregate_batch(both, merge=True)
+                                         self._agg_time) as sp:
+                            return self._aggregate_batch(both, True, sp)[0]
 
                     # the merge needs BOTH partials at once — unsplittable,
                     # so spill-only retry (withRetryNoSplit)
                     acc = R.call_with_retry(merge_acc, scope="agg.merge")
+                if needed and n_words:
+                    # the merge's keys span the accumulated batches' ranges
+                    n_words = max(n_words, needed)
                 if chain_ok and acc is not None:
                     # refresh predictors after an unchained batch (first batch
                     # or chain fallback): one count sync — the unchained loop
@@ -791,7 +878,7 @@ class HashAggregateExec(TpuExec):
                 acquire_semaphore(self.metrics)
                 empty = ColumnarBatch.empty(
                     self._partial_schema() if merge_input else self.child.output)
-                acc = self._aggregate_batch(empty, merge=merge_input)
+                acc = self._aggregate_batch(empty, merge_input)[0]
             if self.mode == PARTIAL:
                 yield acc
             else:

@@ -40,8 +40,16 @@ class ExpandExec(TpuExec):
         def it():
             for batch in self.child.execute_partition(split):
                 acquire_semaphore(self.metrics)
-                with trace_range("ExpandExec", self._op_time):
+                with trace_range("ExpandExec", self._op_time) as sp:
                     out = self._expand(batch, k)
+                    if sp:
+                        counts = {"projections": k,
+                                  "capacity": batch.capacity,
+                                  "capacity_out": out.capacity}
+                        if isinstance(batch.lazy_num_rows, int):
+                            counts.update(rows=batch.lazy_num_rows,
+                                          rows_out=batch.lazy_num_rows * k)
+                        sp.set(**counts)
                 yield out
         return self.wrap_output(it())
 

@@ -21,7 +21,9 @@ from spark_rapids_tpu.expr.windows import (DenseRank, Lag, Lead, Rank, RowNumber
 from spark_rapids_tpu.ops import windowing as W
 from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.filtering import gather_cols
-from spark_rapids_tpu.ops.sorting import SortOrder, sort_permutation
+from spark_rapids_tpu.ops.sorting import (SortOrder, fold_keys, sort_folded,
+                                          sort_permutation, unfold_keys,
+                                          unfolded_operands)
 from spark_rapids_tpu.runtime import metrics as M
 from spark_rapids_tpu.runtime.tracing import trace_range
 
@@ -92,33 +94,128 @@ class WindowExec(TpuExec):
             if not batches:
                 return
             acquire_semaphore(self.metrics)
-            with trace_range("WindowExec", self._win_time):
+            with trace_range("WindowExec", self._win_time) as sp:
                 batch = concat_batches(batches)
-                out = self._compute(batch)
+                out = self._compute(batch, sp)
             yield out
         return self.wrap_output(it())
 
-    def _compute(self, batch: ColumnarBatch) -> ColumnarBatch:
-        cap = batch.capacity
-        ctx = EvalContext.from_batch(batch)
+    def _compute(self, batch: ColumnarBatch, sp) -> ColumnarBatch:
+        """One partition through ONE fused program (``jit_srt_WindowExec`` in
+        a device trace), keyed by schema and expressions; jit keys it by
+        capacity. The eager body is what ``call_fused`` falls back to."""
+        from spark_rapids_tpu.expr.misc import CONTEXT_SENSITIVE
+        from spark_rapids_tpu.runtime import fuse
+        ctx_sensitive = any(
+            e.collect(lambda x: isinstance(x, CONTEXT_SENSITIVE))
+            for e in self.window_exprs)
+        in_cols = [Col.from_vector(c) for c in batch.columns]
+        nr = jnp.asarray(batch.lazy_num_rows, jnp.int32)
+        if in_cols and not ctx_sensitive:
+            n_words = self._sort_words(batch, in_cols, nr)
+            key = ("window", fuse.schema_key(self.child.output),
+                   tuple(fuse.expr_key(e) for e in self.window_exprs),
+                   n_words)
+
+            def build():
+                return lambda cols, num_rows: self._window_kernel(
+                    cols, num_rows, n_words)
+
+            out_cols, facts = fuse.call_fused(
+                key, "WindowExec", build, (in_cols, nr),
+                lambda: self._window_kernel(in_cols, nr))
+        else:
+            ctx = EvalContext.from_batch(batch)
+            out_cols, facts = self._window_kernel(ctx.cols, ctx.num_rows)
+        if sp:
+            counts = dict(facts.items, capacity=batch.capacity)
+            if isinstance(batch.lazy_num_rows, int):
+                counts["rows"] = batch.lazy_num_rows
+            sp.set(**counts)
+        return ColumnarBatch([c.to_vector() for c in out_cols],
+                             batch.lazy_num_rows, self.output)
+
+    def _sort_words(self, batch, in_cols, nr):
+        """The sort operands the partition's keys fold into BY WHAT THEY HOLD
+        (ops/sorting.fold_keys, words_for), from one reduction program and
+        one host read a partition; None where the keys' types already say
+        (no read), where a key cannot fold (a float) and below 2^17 slots
+        (any sort is cheap there). A sum to rank by is an int64 that holds
+        forty bits: folded with its partition key it sorts as three int32
+        operands, unfolded as six mixed ones, and the chip's compiler takes
+        its time by them (34 s against 167 for a described v5e)."""
+        from spark_rapids_tpu.ops.sorting import ranged_key, words_for
+        from spark_rapids_tpu.runtime import fuse, tracing
+        spec0 = _unalias(self.window_exprs[0]).spec
+        exprs = list(spec0.partition_by) + [e for e, _, _ in spec0.order_by]
+        wide = False
+        for e in exprs:
+            if isinstance(e.dtype, (T.StringType, T.BooleanType)):
+                continue
+            if not ranged_key(e.dtype):
+                return None
+            wide = wide or jnp.iinfo(e.dtype.jnp_dtype).bits > 32
+        if not wide or batch.capacity < (1 << 17):
+            return None
+        orders = self._orders(spec0)
+        skey = ("window_key_stats", fuse.schema_key(self.child.output),
+                tuple(fuse.expr_key(e) for e in exprs),
+                tuple(repr(o) for o in orders))
+
+        def build():
+            def kernel(cols, num_rows):
+                cap = cols[0].values.shape[0]
+                ctx = EvalContext(cols, num_rows, cap)
+                folded = fold_keys([e.eval(ctx) for e in exprs], orders,
+                                   num_rows, cap, n_words=1)
+                return jnp.asarray(0 if folded is None else folded.need_bits,
+                                   jnp.int32)
+            return kernel
+
+        with tracing.span("sync.key_stats") as sp:
+            need = int(fuse.call_fused(
+                skey, "WindowExec.key_stats", build, (in_cols, nr),
+                lambda: build()(in_cols, nr)))
+            sp.set(rows=need, capacity=batch.capacity)
+        return words_for(need) if need else None
+
+    @staticmethod
+    def _orders(spec0):
+        return ([SortOrder() for _ in spec0.partition_by]
+                + [SortOrder(asc, nf) for (_, asc, nf) in spec0.order_by])
+
+    def _window_kernel(self, cols, num_rows, n_words=None):
+        """Pure per-partition body (traceable): (output columns, fuse.Facts
+        of the expressions and the sort's operands). ``n_words``: see
+        _sort_words; the sorted keys are then read back out of the sort's
+        operands (ops/sorting.unfold_keys), not gathered a key."""
+        from spark_rapids_tpu.runtime.fuse import Facts
+        cap = cols[0].values.shape[0] if cols else 0
+        ctx = EvalContext(cols, num_rows, cap)
         spec0 = _unalias(self.window_exprs[0]).spec
         part_cols = [e.eval(ctx) for e in spec0.partition_by]
         order_cols = [e.eval(ctx) for (e, _, _) in spec0.order_by]
-        orders = ([SortOrder() for _ in part_cols]
-                  + [SortOrder(asc, nf) for (_, asc, nf) in spec0.order_by])
-        num_rows = ctx.num_rows
-        perm = sort_permutation(part_cols + order_cols, orders, num_rows, cap)
+        keys, orders = part_cols + order_cols, self._orders(spec0)
         live = jnp.arange(cap, dtype=jnp.int32) < num_rows
+        folded = fold_keys(keys, orders, num_rows, cap, n_words)
+        if folded is not None:
+            operands = len(folded.words)
+            perm, words = sort_folded(folded)
+            sorted_keys = unfold_keys(folded, words, keys, orders, live)
+        else:
+            operands = unfolded_operands(keys)
+            perm = sort_permutation(keys, orders, num_rows, cap)
+            sorted_keys = gather_cols(keys, perm, live)
         sorted_in = gather_cols(ctx.cols, perm, live)
-        sorted_part = gather_cols(part_cols, perm, live)
-        sorted_order = gather_cols(order_cols, perm, live)
+        sorted_part = sorted_keys[:len(part_cols)]
+        sorted_order = sorted_keys[len(part_cols):]
 
         part_boundary = self._boundaries(sorted_part, cap)
         order_boundary = part_boundary | self._boundaries(sorted_order, cap) \
             if sorted_order else part_boundary
         seg_ids = W.cumsum(part_boundary.astype(jnp.int32)) - 1
 
-        sctx = EvalContext(sorted_in, batch.lazy_num_rows, cap)
+        sctx = EvalContext(sorted_in, num_rows, cap)
         bounds_memo = {}  # per-batch: partitions run concurrently in threads
         out_cols = list(sorted_in)
         for e in self.window_exprs:
@@ -126,8 +223,8 @@ class WindowExec(TpuExec):
             out_cols.append(self._eval_window(
                 we, sctx, part_boundary, order_boundary, seg_ids, cap, live,
                 sorted_order, bounds_memo))
-        return ColumnarBatch([c.to_vector() for c in out_cols],
-                             batch.lazy_num_rows, self.output)
+        return out_cols, Facts(exprs=len(self.window_exprs),
+                               sort_operands=operands)
 
     @staticmethod
     def _boundaries(cols, cap) -> jnp.ndarray:

@@ -34,7 +34,9 @@ import numpy as np
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.expr.core import Col
 from spark_rapids_tpu.ops import windowing as W
-from spark_rapids_tpu.ops.sorting import sort_permutation, SortOrder
+from spark_rapids_tpu.ops.sorting import (SortOrder, fold_keys, sort_folded,
+                                          sort_permutation, unfold_keys,
+                                          unfolded_operands)
 from spark_rapids_tpu.ops.filtering import gather_cols, compact_cols
 
 
@@ -73,21 +75,6 @@ def compact_key_codes(key_cols, max_domain: int = 1 << 20):
         code = jnp.where(c.validity, code, jnp.int32(d - 1))
         combined = code if combined is None else combined * d + code
     return combined, strides
-
-
-def combine_compact_keys(key_cols):
-    """Fuse group keys with STATICALLY-known small domains (dictionary-coded
-    strings, booleans) into one int32 code column: sorts and boundary checks
-    then touch a single operand instead of one per key (~6x cheaper multi-key
-    group-by). Nulls get their own code (Spark groups nulls together).
-    Returns None when any key's domain is unknown or the product overflows."""
-    if len(key_cols) < 2:
-        return None  # single key is already one operand
-    ks = compact_key_codes(key_cols)
-    if ks is None:
-        return None
-    combined, _ = ks
-    return Col(combined, jnp.ones_like(combined, dtype=jnp.bool_), T.INT)
 
 
 @jax.named_scope("dense_group_sum")
@@ -169,16 +156,30 @@ def resolve_dense_group_sums(reqs, codes, n_domain: int, live):
     return outs
 
 
+class GroupSort(typing.NamedTuple):
+    """Rows sorted by their group keys (ops/grouping.sorted_groups)."""
+    perm: jnp.ndarray       # the sorting permutation
+    seg_ids: jnp.ndarray    # group index a sorted row (pad -> capacity-1)
+    boundary: jnp.ndarray   # True at the first row of each group
+    live: jnp.ndarray
+    sorted_keys: list       # the key columns in sorted order
+    folded: object          # sorting.Folded, or None (presorted, variadic)
+    words: list             # the sorted words of ``folded``
+    operands: int           # what the sort took (0: presorted)
+
+
 @jax.named_scope("group_segments")
-def group_segments(key_cols, num_rows, capacity: int, range_hint=None,
-                   presorted: bool = False):
+def sorted_groups(key_cols, num_rows, capacity: int, n_words=None,
+                  presorted: bool = False) -> GroupSort:
     """Sort by keys and compute segment structure.
 
-    Returns (perm, seg_ids, boundary, live) where perm is the sorting permutation,
-    seg_ids[i] is the group index of sorted row i (padding rows get group capacity-1
-    overflow bucket that is later discarded), boundary marks first row of each group.
-    `range_hint` forwards a caller's key-range probe to the packed sort
-    (ops/sorting._packed_key) for single statically-wide int keys.
+    The keys are folded into as few sort operands as they need
+    (ops/sorting.fold_keys; ``n_words`` as there: by what the keys hold, for
+    a caller that checks ``folded.need_bits``), and the sorted key columns
+    and the group boundaries are read back out of the sorted operands: no
+    gather a key and no compare a key. Keys that cannot be folded (a float,
+    an int64 with no observed range) take ``sort_permutation``'s wider
+    sorts and a gather a key.
     `presorted=True` asserts the caller PROVED the live rows already arrive
     key-sorted (exec/aggregate's per-batch key-stats probe): the sort and the
     key gather vanish — equal keys are contiguous by hypothesis, so segment
@@ -186,34 +187,53 @@ def group_segments(key_cols, num_rows, capacity: int, range_hint=None,
     Spark's sort-aware aggregate analog).
     """
     live = jnp.arange(capacity, dtype=jnp.int32) < num_rows
+    orders = [SortOrder() for _ in key_cols]
+    folded = words = None
     if presorted:
+        operands = 0
         perm = jnp.arange(capacity, dtype=jnp.int32)
         sorted_keys = [Col(c.values, c.validity & live, c.dtype, c.dictionary)
                        for c in key_cols]
     else:
-        orders = [SortOrder() for _ in key_cols]
-        perm = sort_permutation(key_cols, orders, num_rows, capacity,
-                                range_hint=range_hint)
-        sorted_keys = gather_cols(key_cols, perm, live)
-
-    neq = jnp.zeros((capacity,), jnp.bool_)
-    for c in sorted_keys:
-        prev_vals = jnp.roll(c.values, 1)
-        prev_valid = jnp.roll(c.validity, 1)
-        if isinstance(c.dtype, T.FractionalType):
-            # NaN == NaN for grouping (Spark), -0.0 == 0.0 (canonicalized already)
-            a, b = c.values, prev_vals
-            both_nan = jnp.isnan(a) & jnp.isnan(b)
-            differs = ~both_nan & ~(a == b)
-        else:
-            differs = c.values != prev_vals
-        neq = neq | differs | (c.validity != prev_valid)
+        folded = fold_keys(key_cols, orders, num_rows, capacity, n_words)
+    if folded is not None:
+        operands = len(folded.words)
+        perm, words = sort_folded(folded)
+        sorted_keys = unfold_keys(folded, words, key_cols, orders, live)
+        # everything above the row index is key: one compare a word
+        neq = (words[-1] >> folded.iota_bits) != (
+            jnp.roll(words[-1], 1) >> folded.iota_bits)
+        for w in words[:-1]:
+            neq = neq | (w != jnp.roll(w, 1))
+    else:
+        if not presorted:
+            operands = unfolded_operands(key_cols)
+            perm = sort_permutation(key_cols, orders, num_rows, capacity)
+            sorted_keys = gather_cols(key_cols, perm, live)
+        neq = jnp.zeros((capacity,), jnp.bool_)
+        for c in sorted_keys:
+            prev_vals = jnp.roll(c.values, 1)
+            prev_valid = jnp.roll(c.validity, 1)
+            if isinstance(c.dtype, T.FractionalType):
+                # NaN == NaN for grouping (Spark), -0.0 == 0.0 (canonicalized already)
+                a, b = c.values, prev_vals
+                both_nan = jnp.isnan(a) & jnp.isnan(b)
+                differs = ~both_nan & ~(a == b)
+            else:
+                differs = c.values != prev_vals
+            neq = neq | differs | (c.validity != prev_valid)
     first_live = jnp.arange(capacity) == 0
     boundary = (first_live | neq) & live
     seg_ids = W.cumsum(boundary.astype(jnp.int32)) - 1
     seg_ids = jnp.where(live, seg_ids, capacity - 1)
     seg_ids = jnp.clip(seg_ids, 0, capacity - 1)
-    return perm, seg_ids, boundary, live
+    return GroupSort(perm, seg_ids, boundary, live, sorted_keys, folded,
+                     words, operands)
+
+
+def group_segments(key_cols, num_rows, capacity: int):
+    """(perm, seg_ids, boundary, live) of ``sorted_groups``."""
+    return sorted_groups(key_cols, num_rows, capacity)[:4]
 
 
 @jax.named_scope("segment_structure")

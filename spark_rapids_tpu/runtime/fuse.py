@@ -23,6 +23,7 @@ replays); a healthy query does O(stages) traces and O(batches) dispatches.
 
 from __future__ import annotations
 
+import dataclasses
 import re
 import threading
 import types as _types
@@ -74,6 +75,23 @@ class _Unset:
 
 
 _UNSET = _Unset()
+
+
+@jax.tree_util.register_pytree_node_class
+class Facts:
+    """What a kernel decided while it was traced (the path it took, the
+    operands of its sort): no array, the facts as static aux data, so the
+    caller of a cached program reads what that program's trace decided."""
+
+    def __init__(self, **facts):
+        self.items = tuple(sorted(facts.items()))
+
+    def tree_flatten(self):
+        return (), self.items
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(**dict(aux))
 
 
 def program_name(name: str) -> str:
@@ -419,6 +437,10 @@ def _value_key(v):
         return v
     if isinstance(v, type):              # class-valued fields (strategy
         return ("class", v.__module__, v.__qualname__)  # selectors etc.)
+    if dataclasses.is_dataclass(v):      # a window's spec and frame
+        return (type(v).__module__, type(v).__qualname__) + tuple(
+            (f.name, _value_key(getattr(v, f.name)))
+            for f in dataclasses.fields(v))
     if isinstance(v, _types.CodeType):   # nested function consts
         return ("code", v.co_code, tuple(_value_key(c) for c in v.co_consts),
                 v.co_names)

@@ -1204,3 +1204,22 @@ select i_item_id,
 order by qoh, i_item_id, i_brand, i_class, i_category
 limit 100
 """
+
+# q67: the specification's text whole (query67.tpl, DMS = 1200). rank() over
+# sums is an exact answer only over exact money: run it over a store_sales
+# whose ss_sales_price is int64 hundredths (benchmarks/tpcds.EXACT_MONEY,
+# tests/test_sql_tpcds.py), as the specification's DECIMAL(7,2) is
+SQL_QUERIES["q67"] = """
+select * from (
+  select i_category, i_class, i_brand, i_product_name, d_year, d_qoy, d_moy, s_store_id, sumsales,
+         rank() over (partition by i_category order by sumsales desc) rk
+  from (select i_category, i_class, i_brand, i_product_name, d_year, d_qoy, d_moy, s_store_id,
+               sum(coalesce(ss_sales_price * ss_quantity, 0)) sumsales
+        from store_sales, date_dim, store, item
+        where ss_sold_date_sk = d_date_sk and ss_item_sk = i_item_sk and ss_store_sk = s_store_sk
+          and d_month_seq between 1200 and 1200 + 11
+        group by rollup(i_category, i_class, i_brand, i_product_name, d_year, d_qoy, d_moy, s_store_id)) dw1) dw2
+where rk <= 100
+order by i_category, i_class, i_brand, i_product_name, d_year, d_qoy, d_moy, s_store_id, sumsales, rk
+limit 100
+"""

@@ -369,3 +369,55 @@ def test_mesh_exchange_program_of_q3_at_sf1_compiles_for_four_chips(
         tuple(jax.ShapeDtypeStruct((1, 4 * N), jnp.bool_, sharding=one)
               for _ in dtypes), 760000).compile()
     assert _fits(cut)
+
+
+def test_rollup_group_sort_at_q67_shapes_folds_into_three_operands(one_chip):
+    """The aggregate's update program over TPC-DS q67's expanded batch (nine
+    keys: four item strings, three int32 dates, the store id, the grouping
+    id; 8 Mi slots), its keys folded by what they hold (87 bits at SF 1,
+    three 31-bit words, as the key-stats probe finds): the widest sort the
+    program holds takes three int32 operands where the comparator sort took
+    20 (the chip's compiler takes its time by them), and the program fits
+    the chip."""
+    import re
+    import pyarrow as pa
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.exec.aggregate import HashAggregateExec
+    from spark_rapids_tpu.exec.basic import ArrowScanExec
+    from spark_rapids_tpu.expr.aggregates import Sum
+    from spark_rapids_tpu.expr.core import Alias, EvalContext, col
+    cap = 8 << 20
+    strings = {"i_category": 10, "i_class": 100, "i_brand": 1000,
+               "i_product_name": 18000, "s_store_id": 12}
+    names = ["i_category", "i_class", "i_brand", "i_product_name", "d_year",
+             "d_qoy", "d_moy", "s_store_id", "spark_grouping_id"]
+    tiny = pa.table(
+        {n: pa.array(["x"]) if n in strings else pa.array([1], pa.int32())
+         for n in names} | {"sales": pa.array([1], pa.int64())})
+    conf = RapidsConf()
+    agg = HashAggregateExec([col(n) for n in names],
+                            [Alias(Sum(col("sales")), "sumsales")],
+                            ArrowScanExec([tiny], conf=conf), conf=conf)
+
+    def shape(dtype):
+        return jax.ShapeDtypeStruct((cap,), dtype, sharding=one_chip)
+
+    cols = [Col(shape(jnp.int32), shape(jnp.bool_),
+                T.STRING if n in strings else T.INT,
+                pa.array([f"{i:05d}" for i in range(strings[n])])
+                if n in strings else None) for n in names]
+    cols.append(Col(shape(jnp.int64), shape(jnp.bool_), T.LONG))
+    rows = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def kernel(cols, num_rows):
+        out, n, need, _facts = agg._agg_kernel(
+            EvalContext(cols, num_rows, cap), merge=False, n_words=3)
+        return out, n, need
+
+    lowered = jax.jit(kernel).lower(cols, rows)
+    sorts = [len(m.group(1).split(",")) for m in re.finditer(
+        r'"?stablehlo\.sort"?\(([^)]*)\)', lowered.as_text())]
+    assert sorts and max(sorts) == 3, sorts
+    assert "xi64>" not in "".join(re.findall(
+        r'stablehlo\.sort.*?\n', lowered.as_text()))
+    assert _fits(lowered.compile())

@@ -382,3 +382,82 @@ def test_range_sum_tree_matches_segment_totals(cap):
     got = np.asarray(G._seg_sum_tree(jnp.asarray(data), ctx))
     want = np.bincount(seg_ids, weights=data)[seg_ids]
     np.testing.assert_allclose(got, want, rtol=1e-12)
+
+
+# -- the fused program (PR 35) -------------------------------------------------
+
+def rollup_table(n=600, seed=67):
+    """q67's window input in small: a string partition key with NULLs (the
+    rolled-up category), an int64 order key past 32 bits with ties."""
+    r = np.random.default_rng(seed)
+    cat = r.integers(0, 5, n)
+    sums = (1 << 33) + r.integers(0, 40, n)
+    return pa.table({
+        "g": pa.array([None if c == 4 else f"cat{c}" for c in cat]),
+        "o": pa.array(sums, pa.int64()),
+        "v": pa.array(r.integers(0, 1000, n), pa.int64()),
+    })
+
+
+def _by_sums_desc(frame=DEFAULT_FRAME):
+    return WindowSpec((col("g"),), ((col("o"), False, False),), frame)
+
+
+@pytest.mark.parametrize("name,func", [
+    ("rank", Rank), ("dense_rank", DenseRank),
+    ("row_number", RowNumber), ("sum", lambda: Sum(col("v"))),
+    ("avg", lambda: Average(col("v")))])
+def test_fused_window_program_matches_host_window(name, func):
+    """WindowExec's partition goes through ONE fused program
+    (`srt_WindowExec`), not the eager body it falls back to, and agrees with
+    plan/host_window.py; ties in the order key get one rank, and a RANGE
+    frame sums over them (row_number over ties depends on the physical
+    order, so it runs over the order key made unique)."""
+    from spark_rapids_tpu.runtime import fuse
+    t = rollup_table()
+    if name == "row_number":
+        t = t.set_column(1, "o", pa.array(
+            (1 << 33) + np.random.default_rng(1).permutation(t.num_rows),
+            pa.int64()))
+    node = WindowNode([Alias(WindowExpression(func(), _by_sums_desc()), name)],
+                      ScanNode([t]))
+    before = fuse.stage_metrics()["dispatches"]
+    hybrid = check(node, approx=name == "avg")
+    assert isinstance(hybrid, TpuExec)
+    kernels = [k for key, k in fuse._kernels.items() if key[0] == "window"]
+    assert kernels and all(k is not fuse._EAGER for k in kernels)
+    assert fuse.stage_metrics()["dispatches"] > before
+
+
+def test_window_sort_folds_a_wide_order_key_by_what_it_holds():
+    """From 2^17 slots on, one key-stats read a partition folds the sort's
+    keys by the ranges they hold: a string and an int64 past 32 bits (here
+    5 bits of span) sort as ONE int64 operand (six unfolded), and rank() and
+    a running RANGE sum agree with pandas."""
+    from spark_rapids_tpu.runtime import tracing
+    t = rollup_table(n=140_000)
+    node = WindowNode([Alias(WindowExpression(Rank(), _by_sums_desc()), "rk"),
+                       Alias(WindowExpression(Sum(col("v")), _by_sums_desc()),
+                             "running")], ScanNode([t]))
+    tracing.drain()
+    tracing.set_enabled(True)
+    try:
+        got = execute_hybrid(TpuOverrides(RapidsConf()).apply(node))
+        spans = tracing.drain()
+    finally:
+        tracing.set_enabled(False)
+    (counts,) = [s["counts"] for s in spans if s["name"] == "WindowExec"]
+    assert counts["rows"] == 140_000 and counts["capacity"] == 1 << 18
+    assert counts["exprs"] == 2 and counts["sort_operands"] == 1
+    df = t.to_pandas()
+    df["g"] = df["g"].fillna("<null>")
+    by = df.groupby("g")
+    df["rk"] = by["o"].rank(method="min", ascending=False).astype(int)
+    tie_sums = df.groupby(["g", "o"])["v"].sum().sort_index(
+        ascending=[True, False]).groupby(level=0).cumsum()
+    df["running"] = [tie_sums[(g, o)] for g, o in zip(df["g"], df["o"])]
+    want = sorted(zip(df["g"], df["o"], df["v"], df["rk"], df["running"]))
+    have = got.to_pandas()
+    have["g"] = have["g"].fillna("<null>")
+    assert sorted(zip(have["g"], have["o"], have["v"], have["rk"],
+                      have["running"])) == want
