@@ -896,9 +896,14 @@ class BroadcastHashJoinChainExec(TpuExec):
     row per hop, so stream capacity statically bounds every intermediate —
     probe -> gather -> probe -> gather -> compact runs as one dispatch per
     batch instead of (project + probe + emit) per hop. The output lands at a
-    PREDICTED capacity bucket (last batch's survivor count): steady-state
-    batches pay exactly one dispatch, a mispredicted batch pays one retry at
-    full capacity. Non-unique / context-sensitive builds degrade per batch
+    PREDICTED capacity bucket, kept per stream-batch capacity: the largest
+    survivor bucket a batch of that capacity has needed in this partition
+    (the stream's own capacity until one has been seen). A batch that
+    needs the predicted bucket pays exactly one dispatch; one that needs a
+    smaller bucket pays one more small program that cuts the compacted
+    output to it (`HashJoinChain.land`); only one that needs a LARGER bucket
+    lost rows to the cut inside the program and runs the chain again.
+    Non-unique / context-sensitive builds degrade per batch
     to the classic sequential probe+emit path — degraded, never wrong."""
 
     stream_child_index = 0   # the fused pipeline continues into children[0]
@@ -944,15 +949,18 @@ class BroadcastHashJoinChainExec(TpuExec):
                 fused_ok = all(c.chain_capable() for c in cores)
                 out_schema = self.output
                 in_rows = self.metrics.metric(M.NUM_INPUT_ROWS, M.ESSENTIAL)
-                pred_cap = [None]   # survivor-count capacity prediction
+                # stream-batch capacity -> the largest output bucket a batch
+                # of that capacity has needed: too large costs a slice, too
+                # small a second run of the chain
+                pred_cap = {}
 
                 modes = "+".join(c.mode for c in cores)    # a hop each
 
                 def probe(b):
                     with trace_range("HashJoinChain.probe", self._join_time,
-                                     modes=modes):
+                                     modes=modes) as sp:
                         return self._fused_probe(b, cores, sbs, pred_cap,
-                                                 out_schema)
+                                                 out_schema, sp)
 
                 for stream_batch in self.children[0].execute_partition(split):
                     in_rows.add_lazy(stream_batch.lazy_num_rows)
@@ -974,11 +982,13 @@ class BroadcastHashJoinChainExec(TpuExec):
                         h._shared.close()
         return self.wrap_output(it())
 
-    def _fused_probe(self, stream_batch, cores, sbs, pred_cap, out_schema):
+    def _fused_probe(self, stream_batch, cores, sbs, pred_cap, out_schema,
+                     probe_span):
         """One fused program per (stream shape, output bucket): every hop's
         key eval + prefilter + unique-match lookup + build gather + stream
         preproject, then a single front-compaction, sliced to the predicted
-        output bucket. Returns the output batch or None (no survivors)."""
+        output bucket. Returns the output batch or None (no survivors).
+        `probe_span` counts how the output landed at its bucket."""
         from spark_rapids_tpu.runtime import fuse
         scap = stream_batch.capacity
         specs = [(c.stream_key_exprs, c.stream_prefilter,
@@ -1045,23 +1055,40 @@ class BroadcastHashJoinChainExec(TpuExec):
             return fuse.call_fused(key, "HashJoinChain.probe", build, args,
                                    lambda: build()(*args))
 
-        cap = min(pred_cap[0], scap) if pred_cap[0] is not None else scap
+        def land(cols, cap, tgt):
+            # survivors lie at the front and the rest reads defaults
+            # (compact_cols), so the first `tgt` slots of a run at a larger
+            # capacity are the bits a run at `tgt` returns
+            key = ("join_chain_land", cap, tgt, fuse.schema_key(out_schema))
+            return fuse.call_fused(
+                key, "HashJoinChain.land",
+                lambda: lambda cols: slice_to_capacity(cols, None, tgt),
+                (cols,), lambda: slice_to_capacity(cols, None, tgt))
+
+        cap = min(pred_cap.get(scap, scap), scap)
         cols, count = run(cap)
         with tracing.span("sync.count") as sp:
             # one host sync per batch (the emit-total analog)
             count = int(count)
             sp.set(rows=count, capacity=cap)
-        if count == 0:
-            pred_cap[0] = bucket_capacity(1)
+        if count == 0:      # nothing to land, and nothing learned
+            probe_span.set(capacity_pred=cap, capacity_out=0)
             return None
         # output capacity must be bucket_capacity(count) EXACTLY — the
         # unfused emit's chunk capacity — or downstream float reductions see
-        # a different XLA tree shape and bit-identity breaks. Steady state
-        # predicts the right bucket (1 dispatch); a miss pays one rerun.
+        # a different XLA tree shape and bit-identity breaks. A prediction
+        # that was too large is cut to the bucket, one that was too small
+        # cut survivors off inside the program: only that runs again.
         tgt = bucket_capacity(count)
-        pred_cap[0] = tgt
-        if tgt != cap:
+        pred_cap[scap] = max(pred_cap.get(scap, 0), tgt)
+        landed = "hit"
+        if tgt > cap:
             cols, _ = run(tgt)
+            landed = "rerun"
+        elif tgt < cap:
+            cols = land(cols, cap, tgt)
+            landed = "sliced"
+        probe_span.set(landed=landed, capacity_pred=cap, capacity_out=tgt)
         return ColumnarBatch([c.to_vector() for c in cols], count, out_schema)
 
     def _fallback(self, stream_batch, cores, sbs):

@@ -682,6 +682,129 @@ def test_probe_modes_through_the_join_chain(case, spans):
         assert {p["modes"] for p in fused} == {mode + "+dense"}
 
 
+class _BatchesExec(ArrowScanExec):
+    """One partition that hands out each table as a batch of its own, so a
+    stream's capacities can be chosen batch by batch."""
+
+    num_partitions = 1
+
+    def execute_partition(self, split):
+        return self.wrap_output(
+            ColumnarBatch.from_arrow(t, self._schema) for t in self.tables)
+
+
+def _stream_batch(rows, survivors, first):
+    """`rows` stream rows of which `survivors` pass both hops of
+    `_landing_stack`; the rest miss the first build, every third of them
+    with a NULL key."""
+    i = first + np.arange(rows)
+    keep = np.arange(rows) % max(rows // max(survivors, 1), 1) == 0
+    keep &= np.cumsum(keep) <= survivors
+    assert keep.sum() == survivors
+    return pa.table({
+        "lk": pa.array(np.where(keep, i % 500, 900 + i % 50), pa.int64(),
+                       mask=~keep & (i % 3 == 0)),
+        "lv": pa.array(2 * (i % 300), pa.int32()),
+        "lf": pa.array(i / 7.0, pa.float64(), mask=i % 5 == 0),
+        "ls": pa.array([["x", "y", None, "z"][j % 4] for j in i])})
+
+
+def _landing_stack(batches, conf):
+    """Two stacked inner broadcast joins over a stream of `batches`."""
+    bt = pa.table({"rk": pa.array(np.arange(500), pa.int64()),
+                   "rv": pa.array(np.arange(500) * 1.5, pa.float64())})
+    b2 = pa.table({"k2": pa.array(np.arange(0, 700, 2), pa.int32()),
+                   "w": pa.array([None if k % 7 == 0 else f"w{k % 11}"
+                                  for k in range(350)])})
+    inner = BroadcastHashJoinExec("inner", [col("lk")], [col("rk")],
+                                  _BatchesExec(batches, conf=conf),
+                                  ArrowScanExec([bt], conf=conf))
+    return BroadcastHashJoinExec("inner", [col("lv")], [col("k2")], inner,
+                                 ArrowScanExec([b2], conf=conf))
+
+
+# (stream rows, survivors) a batch; how each output lands at its bucket
+# (None: no survivor, no output); runs of the chain program
+LANDING_CASES = {
+    # two capacities, two buckets, in turn: one prediction a capacity
+    "alternating_capacities": (
+        [(64, 20), (30, 5), (60, 17), (32, 8), (64, 30), (20, 6)],
+        ["sliced", "sliced", "hit", "hit", "hit", "hit"], 6),
+    "shrinking": ([(64, 40), (64, 10), (64, 3)],
+                  ["hit", "sliced", "sliced"], 3),
+    "growing": ([(64, 3), (64, 10), (64, 40)],
+                ["sliced", "rerun", "rerun"], 5),
+    "no_survivor_between": ([(64, 10), (64, 0), (64, 12)],
+                            ["sliced", None, "hit"], 3),
+    # the largest bucket seen stands, not the last
+    "smaller_then_larger_again": ([(64, 20), (64, 5), (64, 20)],
+                                  ["sliced", "sliced", "hit"], 3),
+}
+
+
+@pytest.mark.parametrize("case", LANDING_CASES)
+def test_the_chains_output_lands_at_its_bucket(case, spans, monkeypatch):
+    """A predicted output bucket that was too large costs a slice of the
+    first run's output, one that was too small a second run; either way the
+    batches are the unfused stack's bit for bit, padding included."""
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
+    from spark_rapids_tpu.exec.joins import (BroadcastHashJoinChainExec,
+                                             maybe_chain)
+    from spark_rapids_tpu.runtime import fuse
+    shape, landed, chain_runs = LANDING_CASES[case]
+    batches, first = [], 0
+    for rows, kept in shape:
+        batches.append(_stream_batch(rows, kept, first))
+        first += rows
+    conf = RapidsConf()
+    want = [b for b in _landing_stack(batches, conf).execute_partition(0)
+            if b.num_rows]
+    spans.drain()
+    calls = []
+    call_fused = fuse.call_fused
+    monkeypatch.setattr(
+        fuse, "call_fused",
+        lambda key, name, *a: calls.append(name) or call_fused(key, name, *a))
+    chain = maybe_chain(_landing_stack(batches, conf), conf)
+    assert isinstance(chain, BroadcastHashJoinChainExec)
+    got = list(chain.execute_partition(0))
+
+    probes = _span_counts(spans, "HashJoinChain.probe")
+    assert [p.get("landed") for p in probes] == landed
+    assert calls.count("HashJoinChain.probe") == chain_runs
+    assert calls.count("HashJoinChain.land") == landed.count("sliced")
+    survivors = [n for _, n in shape]
+    assert [p["capacity_out"] for p in probes] == \
+        [bucket_capacity(n) if n else 0 for n in survivors]
+    for p in probes:
+        # a hit ran at its bucket, a slice above it, a rerun below it
+        assert {"hit": p["capacity_pred"] == p["capacity_out"],
+                "sliced": p["capacity_pred"] > p["capacity_out"],
+                "rerun": p["capacity_pred"] < p["capacity_out"],
+                None: p["capacity_out"] == 0}[p.get("landed")], p
+    # the read of the count stands inside the probe, at the run's capacity
+    by_id = {s["id"]: s for s in spans.recorded()}
+    assert [(by_id[s["parent"]]["name"], s["counts"]["capacity"],
+             s["counts"]["rows"])
+            for s in spans.recorded() if s["name"] == "sync.count"] == \
+        [("HashJoinChain.probe", p["capacity_pred"], n)
+         for p, n in zip(probes, survivors)]
+
+    assert [b.num_rows for b in got] == [n for n in survivors if n]
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.capacity == w.capacity == bucket_capacity(g.num_rows)
+        assert g.num_rows == w.num_rows
+        for gc, wc in zip(g.columns, w.columns):
+            assert gc.dtype == wc.dtype
+            np.testing.assert_array_equal(np.asarray(gc.validity),
+                                          np.asarray(wc.validity))
+            # every slot, the padding too, and -0.0 / NaN by their bits
+            assert np.asarray(gc.data).tobytes() == \
+                np.asarray(wc.data).tobytes()
+        assert g.to_arrow().equals(w.to_arrow())
+
+
 def _ranged_tables(vmin, n_build, step, second=None):
     """A unique build of `n_build` keys from `vmin` in steps of `step`, and a
     stream over and around them; with `second` = (vmin, range) a second key

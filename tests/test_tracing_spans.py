@@ -303,7 +303,8 @@ def test_a_join_says_how_each_build_is_probed(traced):
     """A chain of two joins and a join on duplicate keys through the
     session: every build's
     `HashJoin.build_prep` is a child of its build span and carries the probe
-    mode with what decided it; every probe span carries its hops' modes."""
+    mode with what decided it; every probe span carries its hops' modes, and
+    the chain's how its output landed at its bucket."""
     spark = TpuSession()
     n = 3000
     r = np.random.default_rng(4)
@@ -336,6 +337,15 @@ def test_a_join_says_how_each_build_is_probed(traced):
                                           "BroadcastHashJoin.build")
     chain = by_name(spans, "HashJoinChain.probe")
     assert chain and {s["counts"]["modes"] for s in chain} == {"dense+dense"}
+    # how the chain's output came to its bucket: the one stream batch runs
+    # at its own capacity, and its 2,367 survivors need that bucket
+    (probe,) = chain
+    (read,) = [s for s in by_name(spans, "sync.count")
+               if s["parent"] == probe["id"]]
+    assert read["counts"] == {"rows": 2367, "capacity": 4096}
+    assert {k: probe["counts"][k]
+            for k in ("landed", "capacity_pred", "capacity_out")} == {
+        "landed": "hit", "capacity_pred": 4096, "capacity_out": 4096}
     single = by_name(spans, "HashJoin.probe")
     assert single and {s["counts"]["mode"] for s in single} == {"two"}
 
