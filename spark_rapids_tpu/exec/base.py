@@ -99,6 +99,15 @@ class TpuExec:
         parent_span = tracing.current_span()
         pipe_on = P.enabled(self.conf)
 
+        def to_arrow(b):
+            # one span a result batch: the device-to-host reads of its
+            # columns and their Arrow arrays
+            with tracing.span("collect.to_arrow", columns=b.num_cols) as sp:
+                t = b.to_arrow()
+                if sp:
+                    sp.set(rows=t.num_rows, bytes=b.device_memory_size())
+            return t
+
         def run(split):
             # re-enter the driving action's query scope on the pool thread so
             # metrics/events fired by operators attribute to this query
@@ -113,7 +122,7 @@ class TpuExec:
                         it, edge="collect", conf=self.conf,
                         registry=self.metrics, node_id=self._node_id,
                         spillable=True)
-                return [b.to_arrow() for b in it]
+                return [to_arrow(b) for b in it]
 
         if self.num_partitions == 1:
             parts = [run(0)]

@@ -224,6 +224,19 @@ _FIXED = {"INT32": ("<i4", 4), "INT64": ("<i8", 8),
           "FLOAT": ("<f4", 4), "DOUBLE": ("<f8", 8)}
 
 
+class OutOfScope(NotImplementedError):
+    """A column chunk the fused decode does not take. ``reason`` is one fixed
+    word a cause, which the ``scan.fallback`` span counts: ``codec``,
+    ``encodings`` (no dictionary encoding at all), ``type``, ``nested``,
+    ``no_dictionary`` (the chunk has no dictionary page), ``page`` (a data
+    page that is not dictionary-encoded, as a writer makes once its
+    dictionary outgrows its limit, or one the page walk cannot read)."""
+
+    def __init__(self, reason: str, message: str):
+        super().__init__(message)
+        self.reason = reason
+
+
 def _decode_plain_dictionary(physical_type: str, raw: bytes, n: int):
     if physical_type in _FIXED:
         dt, _ = _FIXED[physical_type]
@@ -236,20 +249,35 @@ def _decode_plain_dictionary(physical_type: str, raw: bytes, n: int):
             out.append(raw[pos:pos + ln].decode("utf-8"))
             pos += ln
         return out
-    raise NotImplementedError(physical_type)
+    raise OutOfScope("type", physical_type)
 
 
 def read_chunk_pages(path: str, row_group: int, column: int,
                      md=None) -> ChunkPages:
     """Parse one dictionary-encoded column chunk (UNCOMPRESSED, or
     SNAPPY/GZIP/ZSTD with page bodies decompressed on host) into its raw
-    device-ready pieces. Raises NotImplementedError when out of scope
-    (caller falls back to arrow decode). `md` avoids re-parsing the
-    footer per chunk (wide-table footers are MBs)."""
+    device-ready pieces. Raises NotImplementedError (an ``OutOfScope`` with
+    its reason) when out of scope (caller falls back to arrow decode). `md`
+    avoids re-parsing the footer per chunk (wide-table footers are MBs).
+
+    One ``scan.read`` span: the chunk's bytes read, its pages scanned and
+    its dictionary decoded; counts ``bytes`` (the chunk's compressed size),
+    ``pages`` (data pages) and ``native`` (1 where one native call scanned
+    the chunk, 0 for the page walk)."""
     if md is None:
         import pyarrow.parquet as pq
         md = pq.ParquetFile(path).metadata
     col = md.row_group(row_group).column(column)
+    with tracing.span("scan.read", bytes=col.total_compressed_size) as sp:
+        pages = _chunk_pages(path, col, md.schema.column(column), sp)
+        if sp:
+            sp.set(pages=len(pages.index_segments))
+    return pages
+
+
+def _chunk_pages(path: str, col, leaf, span) -> ChunkPages:
+    """``read_chunk_pages`` for the chunk ``col`` (its footer entry) of the
+    leaf column ``leaf`` (its schema entry)."""
     dec = None
     if col.compression != "UNCOMPRESSED":
         # stage 1.5: page bodies decompress on host via arrow's C codecs
@@ -257,21 +285,21 @@ def read_chunk_pages(path: str, row_group: int, column: int,
         # the bulk bit work — still runs on device either way)
         import pyarrow as pa
         if col.compression not in ("SNAPPY", "GZIP", "ZSTD"):
-            raise NotImplementedError(f"codec {col.compression}")
+            raise OutOfScope("codec", f"codec {col.compression}")
         try:
             dec = pa.Codec(col.compression.lower())
         except Exception as e:
-            raise NotImplementedError(f"codec {col.compression}: {e}")
+            raise OutOfScope("codec", f"codec {col.compression}: {e}")
     if "RLE_DICTIONARY" not in col.encodings and \
             "PLAIN_DICTIONARY" not in col.encodings:
-        raise NotImplementedError(f"encodings {col.encodings}")
+        raise OutOfScope("encodings", f"encodings {col.encodings}")
     if col.physical_type not in _FIXED and \
             col.physical_type != "BYTE_ARRAY":
-        raise NotImplementedError(f"type {col.physical_type}")
+        raise OutOfScope("type", f"type {col.physical_type}")
 
-    max_def = md.schema.column(column).max_definition_level
-    if md.schema.column(column).max_repetition_level:
-        raise NotImplementedError("nested (repeated) columns")
+    max_def = leaf.max_definition_level
+    if leaf.max_repetition_level:
+        raise OutOfScope("nested", "nested (repeated) columns")
 
     with open(path, "rb") as f:
         start = col.dictionary_page_offset or col.data_page_offset
@@ -297,6 +325,7 @@ def read_chunk_pages(path: str, row_group: int, column: int,
         except NotImplementedError:
             pass  # e.g. v2 data pages: the Python parser below handles them
     if raw_pages is not None:
+        span.set(native=1)
         d_off, d_len, d_n = dict_info
         dict_vals = _decode_plain_dictionary(
             col.physical_type, buf[d_off:d_off + d_len], d_n)
@@ -307,6 +336,7 @@ def read_chunk_pages(path: str, row_group: int, column: int,
             pages.append((nv, dl, bw, page_bytes, values_off, segs))
         return ChunkPages(col.physical_type, dict_vals, pages, col.num_values)
 
+    span.set(native=0)
     pos = 0
     dict_vals = None
     pages = []
@@ -323,7 +353,7 @@ def read_chunk_pages(path: str, row_group: int, column: int,
                 col.physical_type, page_body, ph.num_values)
         elif ph.page_type == 0:                     # data page v1
             if ph.encoding not in (8, 2):           # RLE_DICT / PLAIN_DICT
-                raise NotImplementedError(f"page encoding {ph.encoding}")
+                raise OutOfScope("page", f"page encoding {ph.encoding}")
             page_body = (raw_body if dec is None else
                          bytes(dec.decompress(raw_body,
                                               ph.uncompressed_size)))
@@ -349,9 +379,9 @@ def read_chunk_pages(path: str, row_group: int, column: int,
             values_seen += ph.num_values
         elif ph.page_type == 3:                     # data page v2
             if ph.encoding not in (8, 2):
-                raise NotImplementedError(f"page encoding {ph.encoding}")
+                raise OutOfScope("page", f"page encoding {ph.encoding}")
             if ph.rep_len:
-                raise NotImplementedError("repeated (nested) v2 page")
+                raise OutOfScope("nested", "repeated (nested) v2 page")
             # levels ride UNCOMPRESSED ahead of the (optionally compressed)
             # values section; def levels have NO length prefix in v2
             levels = raw_body[:ph.def_len]
@@ -370,10 +400,10 @@ def read_chunk_pages(path: str, row_group: int, column: int,
             pages.append((ph.num_values, def_levels, bw, data, 0, segs))
             values_seen += ph.num_values
         else:
-            raise NotImplementedError(f"page type {ph.page_type}")
+            raise OutOfScope("page", f"page type {ph.page_type}")
         pos = body + ph.compressed_size
     if dict_vals is None:
-        raise NotImplementedError("no dictionary page")
+        raise OutOfScope("no_dictionary", "no dictionary page")
     return ChunkPages(col.physical_type, dict_vals, pages, col.num_values)
 
 
@@ -430,37 +460,77 @@ def chunk_to_device(pages: ChunkPages, spark_type, capacity: int,
     decode, which ``encoded`` may defer into the consumer; RLE runs, or pages
     of different bit widths → the segment-table decode, always dense.
     ``span`` is the caller's ``scan.column`` span: it learns which of the two
-    the chunk took and what the chunk was made of."""
+    the chunk took and what the chunk was made of.
+
+    The host preparation and the upload are one ``scan.stage`` span (counts
+    ``values``, and ``arrays`` put with their ``bytes``), which closes where
+    the decode is dispatched, or where ``encoded`` defers it.
+
+    Cached via the fuse kernel cache like every exec stage: one program per
+    spec (bit width or widest width, shape buckets, output type). Under
+    ``encoded`` a single-page chunk's buffers are wrapped in an
+    EncodedColumnVector and its first consumer runs the same decode body,
+    fused into its own program when it can, standalone otherwise."""
     import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.vector import TpuColumnVector
+    from spark_rapids_tpu.ops import parquet_decode as PD
+    from spark_rapids_tpu.runtime import fuse
 
-    if pages.physical_type == "BYTE_ARRAY":
-        # parquet dictionary == the engine's string dictionary, sorted for
-        # order-preserving codes (columnar/arrow.py design)
-        from spark_rapids_tpu.ops.strings import sorted_dict_and_rank
-        sorted_dict, dict_host = sorted_dict_and_rank(pages.dict_values)
-    else:                                   # dict_host: parquet idx -> value
-        sorted_dict, dict_host = None, np.asarray(pages.dict_values)
+    with tracing.span("scan.stage", values=pages.num_values) as stage:
+        if pages.physical_type == "BYTE_ARRAY":
+            # parquet dictionary == the engine's string dictionary, sorted
+            # for order-preserving codes (columnar/arrow.py design)
+            from spark_rapids_tpu.ops.strings import sorted_dict_and_rank
+            sorted_dict, dict_host = sorted_dict_and_rank(pages.dict_values)
+        else:                               # dict_host: parquet idx -> value
+            sorted_dict, dict_host = None, np.asarray(pages.dict_values)
 
-    if span:
-        segs = sum(len(p[5]) for p in pages.index_segments)
-        packed = sum(int(p[5][:, RUN_KIND].sum())
-                     for p in pages.index_segments)
-        span.set(pages=len(pages.index_segments), packed=packed,
-                 rle=segs - packed,
-                 encoded_bytes=sum(len(p[3]) for p in pages.index_segments))
-    pages = _merge_packed_pages(pages)
-    span.set(path="fused")
-    if len(pages.index_segments) == 1:
-        (num_values, def_levels, bw, page_bytes, _off, segs) = \
-            pages.index_segments[0]
-        if len(segs) and segs[:, RUN_KIND].all():
+        if span:
+            segs = sum(len(p[5]) for p in pages.index_segments)
+            packed = sum(int(p[5][:, RUN_KIND].sum())
+                         for p in pages.index_segments)
+            span.set(pages=len(pages.index_segments), packed=packed,
+                     rle=segs - packed, encoded_bytes=sum(
+                         len(p[3]) for p in pages.index_segments))
+        pages = _merge_packed_pages(pages)
+        span.set(path="fused")
+        segs = pages.index_segments[0][5] \
+            if len(pages.index_segments) == 1 else None
+        packed_page = segs is not None and len(segs) > 0 \
+            and bool(segs[:, RUN_KIND].all())
+        if packed_page:
+            (num_values, def_levels, bw, page_bytes, _off, segs) = \
+                pages.index_segments[0]
             span.set(decode="packed", segments=1)
-            return _decode_single_page_fused(
+            spec, st, args = _page_spec_and_args(
                 _packed_bytes(page_bytes, segs), bw, def_levels,
                 jnp.asarray(dict_host), num_values, capacity, pages,
-                spark_type, sorted_dict, encoded=encoded)
-    return _decode_runs_fused(pages, dict_host, capacity, spark_type,
-                              sorted_dict, span)
+                spark_type)
+        else:
+            spec, st, args = _runs_spec_and_args(
+                pages, dict_host, capacity, spark_type,
+                sorted_dict is not None, span)
+        if stage:
+            stage.set(arrays=len(args), bytes=sum(a.nbytes for a in args))
+    if packed_page and encoded:
+        from spark_rapids_tpu.columnar.encoded import (EncodedCol,
+                                                       EncodedColumnVector)
+        return EncodedColumnVector(EncodedCol(*args, spec, st, sorted_dict))
+    if packed_page:
+        key, name, decode = (("pq_page_decode", spec), "ParquetScan.decode",
+                             PD.decode_page_cols)
+    else:
+        key, name, decode = (("pq_runs_decode", spec),
+                             "ParquetScan.decode_runs", PD.decode_runs_cols)
+
+    def build():
+        def kernel(*operands):
+            return decode(spec, *operands)
+        return kernel
+
+    v, m = fuse.call_fused(key, name, build, args, lambda: build()(*args))
+    cv = TpuColumnVector(st, v, m)
+    return cv.with_dictionary(sorted_dict) if spec.is_string else cv
 
 
 def _segment_table(pages: ChunkPages):
@@ -521,19 +591,18 @@ def _padded(a: np.ndarray, cap: int) -> np.ndarray:
     return out
 
 
-def _decode_runs_fused(pages: ChunkPages, dict_host, capacity: int,
-                       spark_type, sorted_dict, span):
-    """One jitted program per (widest bit width, shape buckets, output
-    type) for a chunk with RLE runs or pages of different bit widths:
-    segment lookup → per-element bit-unpack → dictionary gather →
-    definition-level spread → canonical nulls
-    (ops/parquet_decode.decode_runs_cols). Table, bytes and dictionary pad
-    to their buckets so chunks share programs."""
+def _runs_spec_and_args(pages: ChunkPages, dict_host, capacity: int,
+                        spark_type, is_string: bool, span):
+    """Host prep of the segment-table decode, for a chunk with RLE runs or
+    pages of different bit widths: static EncodedRunsSpec (widest bit width,
+    shape buckets, output type) + the device argument tuple (packed, table,
+    dict, def-levels, n) of ops/parquet_decode.decode_runs_cols (segment
+    lookup → per-element bit-unpack → dictionary gather → definition-level
+    spread → canonical nulls). Table, bytes and dictionary pad to their
+    buckets so chunks share programs."""
     import jax.numpy as jnp
-    from spark_rapids_tpu.columnar.vector import (TpuColumnVector,
-                                                  bucket_capacity)
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
     from spark_rapids_tpu.ops import parquet_decode as PD
-    from spark_rapids_tpu.runtime import fuse
 
     packed, table, max_bw = _segment_table(pages)
     def_levels = (np.concatenate([p[1] for p in pages.index_segments])
@@ -544,7 +613,7 @@ def _decode_runs_fused(pages: ChunkPages, dict_host, capacity: int,
     scap = bucket_capacity(table.shape[1])
     spec = PD.EncodedRunsSpec(
         max_bw, scap, pcap, bucket_capacity(max(len(packed), 1)), capacity,
-        str(want), sorted_dict is not None, default)
+        str(want), is_string, default)
     span.set(decode="runs", segments=table.shape[1])
     table_h = _padded(table, scap)
     # rows past the last segment start beyond every position, each at its own
@@ -559,24 +628,15 @@ def _decode_runs_fused(pages: ChunkPages, dict_host, capacity: int,
             # 0-d array: a Python or NumPy scalar is converted by an eager
             # program of its own
             jnp.asarray(np.asarray(n, np.int32)))
-
-    def build():
-        def kernel(packed_d, table_d, dict_d, dl_d, n_t):
-            return PD.decode_runs_cols(spec, packed_d, table_d, dict_d, dl_d,
-                                       n_t)
-        return kernel
-
-    key = ("pq_runs_decode", spec)
-    v, m = fuse.call_fused(key, "ParquetScan.decode_runs", build, args,
-                           lambda: build()(*args))
-    cv = TpuColumnVector(st, v, m)
-    return cv.with_dictionary(sorted_dict) if spec.is_string else cv
+    return spec, st, args
 
 
 def _page_spec_and_args(packed: bytes, bw: int, def_levels, dict_dev,
                         num_values: int, capacity: int, pages, spark_type):
-    """Host prep shared by the standalone fused decode and the encoded-upload
-    vector: static EncodedPageSpec + the device argument tuple
+    """Host prep of the single-page decode, shared by the standalone fused
+    decode and the encoded-upload vector (ops/parquet_decode.decode_page_cols:
+    bit-unpack → dictionary gather → definition-level spread → canonical
+    nulls): static EncodedPageSpec + the device argument tuple
     (packed, dict, def-levels, n_present, n). The ONE place page bytes become
     device buffers, so both paths upload identical payloads."""
     import jax.numpy as jnp
@@ -600,43 +660,6 @@ def _page_spec_and_args(packed: bytes, bw: int, def_levels, dict_dev,
             jnp.asarray(np.asarray(n_present, np.int32)),
             jnp.asarray(np.asarray(n, np.int32)))
     return spec, st, args
-
-
-def _decode_single_page_fused(packed: bytes, bw: int, def_levels, dict_dev,
-                              num_values: int, capacity: int, pages,
-                              spark_type, sorted_dict, encoded: bool = False):
-    """One jitted program per (bit width, shape bucket, output type):
-    bit-unpack → dictionary gather → definition-level spread → canonical
-    nulls (ops/parquet_decode.decode_page_cols). Cached via the fuse kernel
-    cache like every exec stage. Under ``encoded`` the expansion is DEFERRED:
-    the encoded buffers are wrapped in an EncodedColumnVector and the first
-    consumer runs the same decode body — fused into its own program when it
-    can, standalone otherwise."""
-    from spark_rapids_tpu.columnar.vector import TpuColumnVector
-    from spark_rapids_tpu.columnar.encoded import (EncodedCol,
-                                                   EncodedColumnVector)
-    from spark_rapids_tpu.ops import parquet_decode as PD
-    from spark_rapids_tpu.runtime import fuse
-
-    spec, st, args = _page_spec_and_args(packed, bw, def_levels, dict_dev,
-                                         num_values, capacity, pages,
-                                         spark_type)
-    if encoded:
-        enc = EncodedCol(*args, spec, st,
-                         sorted_dict if spec.is_string else None)
-        return EncodedColumnVector(enc)
-
-    def build():
-        def kernel(packed_d, dict_d, dl_d, n_present_t, n_t):
-            return PD.decode_page_cols(spec, packed_d, dict_d, dl_d,
-                                       n_present_t, n_t)
-        return kernel
-
-    key = ("pq_page_decode", spec)
-    v, m = fuse.call_fused(key, "ParquetScan.decode", build, args,
-                           lambda: build()(*args))
-    cv = TpuColumnVector(st, v, m)
-    return cv.with_dictionary(sorted_dict) if spec.is_string else cv
 
 
 def read_row_group_device(path: str, row_group: int, schema,
@@ -675,11 +698,13 @@ def read_row_group_device(path: str, row_group: int, schema,
     for name in want:
         sf = schema[name] if schema is not None else None
         # one span a column chunk, with the path it took: fused (one
-        # program, of which `decode` says which) or fallback (pyarrow)
+        # program, of which `decode` says which) or fallback (pyarrow); its
+        # children scan.read, then scan.stage or scan.fallback, hold the
+        # chunk's host work
         with tracing.span("scan.column", column=name) as sp:
             try:
                 if name not in leaf_of:
-                    raise NotImplementedError(f"nested column {name}")
+                    raise OutOfScope("nested", f"nested column {name}")
                 pages = read_chunk_pages(path, row_group, leaf_of[name],
                                          md=md)
                 cv = chunk_to_device(pages, sf.data_type if sf else None,
@@ -690,9 +715,17 @@ def read_row_group_device(path: str, row_group: int, schema,
                 else:
                     _MV.record_h2d(cv.device_memory_size(),
                                    site="scan.device")
-            except NotImplementedError:
-                arr = pf.read_row_group(row_group, columns=[name]).column(0)
-                cv = array_to_device(arr, sf.data_type if sf else None, cap)
+            except NotImplementedError as e:
+                # the page walk's own parsers (thrift headers, hybrid runs)
+                # raise it plainly: a page it cannot read
+                with tracing.span("scan.fallback", rows=n_rows,
+                                  reason=getattr(e, "reason", "page")) as fb:
+                    arr = pf.read_row_group(row_group,
+                                            columns=[name]).column(0)
+                    cv = array_to_device(arr, sf.data_type if sf else None,
+                                         cap)
+                    if fb:
+                        fb.set(bytes=cv.device_memory_size())
                 _MV.record_h2d(cv.device_memory_size(), site="scan.fallback")
                 if sp:
                     sp.set(path="fallback", encoded_bytes=(

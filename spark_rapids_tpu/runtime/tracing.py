@@ -24,6 +24,9 @@ The buffer is only read after the fact (``recorded()``, ``drain()``,
 ``MAX_RECORDS`` spans; ``dropped()`` counts what fell off, and a reader that
 needs whole queries treats any drop as "no reading". With tracing off every
 span site costs one module-level check and the shared no-op ``NO_SPAN``.
+With it on, each Python garbage collection is a ``gc`` span as well (a
+``gc.callbacks`` hook that exists only while tracing is on), so a pause
+is named where it lands and not charged to the work it interrupted.
 
   (c) Distributed spans (spark.rapids.tpu.trace.dir): the reference views
 whole-cluster execution in Nsight because NVTX ranges from every process land
@@ -60,6 +63,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import datetime
+import gc
 import itertools
 import json
 import os
@@ -163,7 +167,7 @@ class SpanWriter:
         self.path = path
         self.process = process
         self._f = open(path, "a", encoding="utf-8")
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()   # reentrant, as _records_lock is
 
     def write(self, rec: dict) -> None:
         line = json.dumps(rec, separators=(",", ":"), default=str)
@@ -240,7 +244,9 @@ def _emit_span(name: str, ph: str, ts: float, dur: "float | None",
 
 MAX_RECORDS = 1 << 18
 _records: "collections.deque" = collections.deque(maxlen=MAX_RECORDS)
-_records_lock = threading.Lock()
+# reentrant: a collection that starts while this thread holds the lock
+# closes its ``gc`` span inside it
+_records_lock = threading.RLock()
 _dropped = 0
 _span_ids = itertools.count(1)
 
@@ -527,10 +533,34 @@ def clear_events() -> None:
     _events.clear()
 
 
+_gc_span = None    # the collection in progress: one runs at a time
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook while tracing is on: each Python collection is
+    one ``gc`` span on the thread that runs it, a child of the span open
+    there; counts ``generation``, ``collected``, ``uncollectable``."""
+    global _gc_span
+    if phase == "start":
+        _gc_span = _Span("gc", None, None,
+                         {"generation": info["generation"]}).__enter__()
+    elif _gc_span is not None:
+        sp, _gc_span = _gc_span, None
+        sp.set(collected=info["collected"],
+               uncollectable=info["uncollectable"])
+        sp.__exit__(None, None, None)
+
+
 def set_enabled(v: bool):
+    """Tracing on or off; on, the ``gc`` hook is registered, off it is not."""
     global _enabled
     _enabled = bool(v)
     _set_active()
+    hooked = _on_gc in gc.callbacks
+    if _enabled and not hooked:
+        gc.callbacks.append(_on_gc)
+    elif not _enabled and hooked:
+        gc.callbacks.remove(_on_gc)
 
 
 _profiling = False

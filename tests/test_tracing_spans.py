@@ -3,6 +3,7 @@ memory on ``perf_counter_ns`` and, under a profiler capture, as host ranges
 of the same names on the capture's clock; and the names that reach the
 device programs (runtime/fuse.py)."""
 
+import gc
 import glob
 import threading
 import time
@@ -29,11 +30,18 @@ from spark_rapids_tpu.sql.tpch_queries import SQL_QUERIES
 
 @pytest.fixture
 def traced():
+    """Tracing on, and Python's automatic collections held off: with tracing
+    on each collection is a ``gc`` span, and a test counts the spans it made
+    itself (a test of the ``gc`` span collects explicitly)."""
+    automatic = gc.isenabled()
+    gc.disable()
     tracing.drain()
     tracing.set_enabled(True)
     yield
     tracing.set_enabled(False)
     tracing.drain()
+    if automatic:
+        gc.enable()
 
 
 def by_name(spans, name):
@@ -97,7 +105,8 @@ def test_a_child_on_another_thread_is_not_taken_off_self_time(traced):
     assert table["parent"]["self_s"] == table["parent"]["total_s"]
 
 
-def test_off_path_allocates_nothing_and_records_nothing():
+def test_off_path_allocates_nothing_and_records_nothing(monkeypatch,
+                                                        tmp_path):
     tracing.set_enabled(False)
     tracing.shutdown_spans()
     tracing.drain()
@@ -105,6 +114,30 @@ def test_off_path_allocates_nothing_and_records_nothing():
     assert tracing.trace_range("x") is tracing.NO_SPAN
     assert tracing.child_of(tracing.current_span()) is tracing.NO_SPAN
     assert not tracing.NO_SPAN and tracing.NO_SPAN.id is None
+    # the gc hook exists only while tracing is on, and once
+    assert tracing._on_gc not in gc.callbacks
+    tracing.set_enabled(True)
+    tracing.set_enabled(True)
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    tracing.set_enabled(False)
+    assert tracing._on_gc not in gc.callbacks
+    tracing.drain()
+    # the scan's and the collect's sites get the shared no-op
+    made = []
+    span = tracing.span
+    monkeypatch.setattr(tracing, "span", lambda name, **kw: made.append(
+        (name, span(name, **kw))) or made[-1][1])
+    f = str(tmp_path / "off.parquet")
+    pq.write_table(pa.table({"coded": np.arange(64) % 3,
+                             "plain": np.arange(64) * 0.5}), f,
+                   compression="NONE", use_dictionary=["coded"])
+    session = TpuSession({
+        "spark.rapids.tpu.sql.parquet.deviceDecode.enabled": "true"})
+    assert session.read_parquet(f).collect().num_rows == 64
+    assert {"scan.column", "scan.read", "scan.stage", "scan.fallback",
+            "collect.to_arrow"} <= {name for name, _ in made}
+    assert all(sp is tracing.NO_SPAN for _, sp in made)
+    monkeypatch.undo()
 
     def sites(n):
         for _ in range(n):
@@ -141,6 +174,31 @@ def test_the_buffer_is_bounded_and_counts_what_it_drops(traced, monkeypatch):
     assert tracing.dropped() == 2
     assert len(tracing.drain()) == 4
     assert tracing.recorded() == [] and tracing.dropped() == 0
+
+
+def test_a_collection_is_a_gc_span_under_the_span_it_interrupts(traced):
+    with tracing.span("work") as work:
+        gc.collect()
+    (g,) = by_name(tracing.recorded(), "gc")
+    assert g["parent"] == work.id
+    assert g["thread"] == threading.current_thread().name
+    assert g["counts"]["generation"] == 2
+    assert set(g["counts"]) == {"generation", "collected", "uncollectable"}
+    assert g["counts"]["collected"] >= 0 and g["counts"]["uncollectable"] >= 0
+
+
+def test_a_collection_inside_the_buffer_lock_does_not_deadlock(traced):
+    """A collection can start at any bytecode, among them the ones where
+    its thread holds the buffer's lock: its span closes inside it."""
+    def collect_holding_the_lock():
+        with tracing._records_lock:
+            gc.collect()
+
+    t = threading.Thread(target=collect_holding_the_lock)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive()
+    assert len(by_name(tracing.recorded(), "gc")) == 1
 
 
 def test_threads_share_the_buffer_and_keep_their_own_trees(traced):
@@ -297,6 +355,90 @@ def test_a_planted_rle_run_shows_as_one_column_decoded_by_runs(
     assert not by_name(spans, "scan.page")
 
 
+def test_a_chunk_s_host_work_is_its_read_then_its_stage_or_fallback(
+        traced, tmp_path):
+    """Under each ``scan.column``: ``scan.read`` (file read, page scan,
+    dictionary), then ``scan.stage`` (host preparation and puts, up to the
+    decode's dispatch) for a chunk the fused decode takes, or
+    ``scan.fallback`` (pyarrow) with the reason it left the fused path."""
+    n = 4096
+    r = np.random.default_rng(37)
+    t = pa.table({"coded": r.integers(0, 9, n),    # nine values: 4 bits
+                  "plain": r.random(n)})
+    f = str(tmp_path / "phases.parquet")
+    pq.write_table(t, f, compression="NONE", use_dictionary=["coded"])
+    md = pq.ParquetFile(f).metadata
+    got = PN.read_row_group_device(
+        f, 0, T.StructType.from_arrow(t.schema)).to_arrow()
+    assert got.to_pylist() == t.to_pylist()
+    spans = tracing.recorded()
+    cols = {s["counts"]["column"]: s for s in by_name(spans, "scan.column")}
+    # the chunk's own span counts what it counted before
+    assert {c: s["counts"]["path"] for c, s in cols.items()} == {
+        "coded": "fused", "plain": "fallback"}
+    assert set(cols["coded"]["counts"]) == {
+        "column", "path", "decode", "segments", "pages", "packed", "rle",
+        "encoded_bytes", "decoded_bytes"}
+    assert set(cols["plain"]["counts"]) == {
+        "column", "path", "encoded_bytes", "decoded_bytes"}
+    kids = {c: {k["name"]: k for k in spans if k["parent"] == s["id"]}
+            for c, s in cols.items()}
+    assert set(kids["coded"]) == {"scan.read", "scan.stage"}
+    assert set(kids["plain"]) == {"scan.read", "scan.fallback"}
+    assert kids["coded"]["scan.read"]["counts"] == {
+        "bytes": md.row_group(0).column(0).total_compressed_size,
+        "pages": 1, "native": 1}
+    # the page walk raised before its first page: the read has its bytes
+    assert kids["plain"]["scan.read"]["counts"] == {
+        "bytes": md.row_group(0).column(1).total_compressed_size}
+    # five puts: the 2,048 packed bytes, the nine int64 dictionary values,
+    # 4,096 definition levels, the present and the row count
+    assert kids["coded"]["scan.stage"]["counts"] == {
+        "values": n, "arrays": 5, "bytes": 2048 + 9 * 8 + n + 4 + 4}
+    assert kids["plain"]["scan.fallback"]["counts"] == {
+        "rows": n, "reason": "encodings",
+        "bytes": cols["plain"]["counts"]["decoded_bytes"]}
+    for c, s in cols.items():
+        first, second = sorted(kids[c].values(), key=lambda k: k["t0"])
+        assert first["name"] == "scan.read"
+        assert s["t0"] <= first["t0"] <= first["t1"] <= second["t0"] \
+            <= second["t1"] <= s["t1"]
+        assert first["thread"] == second["thread"] == s["thread"]
+
+
+@pytest.mark.parametrize("codec, options, reason", [
+    ("BROTLI", {}, "codec"),
+    # a dictionary past its page's limit: the writer goes on in PLAIN pages
+    ("NONE", {"dictionary_pagesize_limit": 256, "data_page_size": 256,
+              "write_batch_size": 64}, "page")])
+def test_a_fallback_counts_why_the_chunk_left_the_fused_path(
+        traced, tmp_path, codec, options, reason):
+    t = pa.table({"coded": np.arange(1000)})
+    f = str(tmp_path / "why.parquet")
+    pq.write_table(t, f, compression=codec, use_dictionary=True, **options)
+    got = PN.read_row_group_device(f, 0, T.StructType.from_arrow(t.schema))
+    assert got.to_arrow().to_pylist() == t.to_pylist()
+    (fb,) = by_name(tracing.recorded(), "scan.fallback")
+    assert fb["counts"]["reason"] == reason
+
+
+def test_a_collect_is_one_span_a_result_batch_under_its_query(
+        traced, tmp_path):
+    for i in range(2):
+        pq.write_table(pa.table({"a": np.arange(100 * i, 100 * i + 60)}),
+                       str(tmp_path / f"part-{i}.parquet"))
+    out = TpuSession().read_parquet(str(tmp_path),
+                                    files_per_partition=1).collect()
+    assert out.num_rows == 120
+    spans = tracing.recorded()
+    got = by_name(spans, "collect.to_arrow")
+    assert len(got) == 2
+    assert sum(s["counts"]["rows"] for s in got) == out.num_rows
+    for s in got:
+        assert ancestors(spans, s)[-1] == "query"
+        assert s["counts"]["columns"] == 1 and s["counts"]["bytes"] > 0
+
+
 # -- the joins' spans ---------------------------------------------------------
 
 def test_a_join_says_how_each_build_is_probed(traced):
@@ -359,6 +501,8 @@ def q1_capture(tmp_path_factory):
     names of the programs that ran)."""
     from jax.profiler import ProfileData
     paths = tpch.generate(0.01, str(tmp_path_factory.mktemp("tpch")))
+    automatic = gc.isenabled()
+    gc.disable()        # one collection, in the capture, below
     tracing.set_enabled(True)
     try:
         # the device decode is the chip's scan path; the CPU platform only
@@ -374,12 +518,15 @@ def q1_capture(tmp_path_factory):
         options.python_tracer_level = 0
         jax.profiler.start_trace(logdir, profiler_options=options)
         try:
+            gc.collect()
             rows = spark.sql(SQL_QUERIES["q1"]).collect().num_rows
         finally:
             jax.profiler.stop_trace()
         spans = tracing.drain()
     finally:
         tracing.set_enabled(False)
+        if automatic:
+            gc.enable()
     assert rows == 4
     xplane, = glob.glob(logdir + "/plugins/profile/*/*.xplane.pb")
     events, modules = [], set()
@@ -410,7 +557,8 @@ def test_every_span_is_a_host_range_of_the_capture_on_one_clock(q1_capture):
         starts.setdefault(name, []).append(start)
     assert len(spans) > 20
     for want in ("sql.parse", "query.plan", "query.admission",
-                 "FileScan.devdecode", "scan.column", "HashAggregate.agg"):
+                 "FileScan.devdecode", "scan.column", "scan.read",
+                 "scan.stage", "HashAggregate.agg", "collect.to_arrow", "gc"):
         assert by_name(spans, want), want
     for s in spans:
         nearest = min(abs(x - (s["t0"] + offset))
