@@ -35,10 +35,13 @@ from spark_rapids_tpu.columnar.batch import (ColumnarBatch, batch_device,
 from spark_rapids_tpu.columnar.vector import TpuColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import TpuExec, TaskContext, acquire_semaphore
 from spark_rapids_tpu.exec.coalesce import concat_all
-from spark_rapids_tpu.expr.core import Col, EvalContext, Expression, bind_references
+from spark_rapids_tpu.expr.core import (Alias, BoundReference, Col,
+                                        EvalContext, Expression,
+                                        bind_references)
 from spark_rapids_tpu.ops import joining as J
 from spark_rapids_tpu.ops.filtering import (
-    gather_cols, selection_mask, compact_cols, slice_to_capacity)
+    gather_cols, selection_mask, compact_cols, compact_cols_to,
+    slice_to_capacity)
 from spark_rapids_tpu.ops.strings import union_dictionaries
 from spark_rapids_tpu.runtime import faults as F
 from spark_rapids_tpu.runtime import memory as mem
@@ -865,6 +868,40 @@ def _chainable(node) -> bool:
                  or is_context_free(*node.stream_preproject)))
 
 
+def _plain_ref(e):
+    """The ordinal a projection term passes through unchanged (a column
+    reference, aliased or not), else None."""
+    while isinstance(e, Alias):
+        e = e.child
+    return e.ordinal if isinstance(e, BoundReference) else None
+
+
+def _chain_plan(specs, n_stream, widths):
+    """Where the fused chain gathers each build column: (at_hop, late).
+
+    `at_hop` is the set of (hop, column) that a later hop reads, through its
+    key, its prefilter, or a preproject term other than a plain reference:
+    those are gathered at their hop, over the stream's capacity. `late`
+    lists, in output order, the (hop, column) that only reach the output:
+    gathered once after the compaction, at the output bucket. A column that
+    a preproject drops is in neither and is never gathered."""
+    cur = [None] * n_stream            # None: a stream or computed column
+    at_hop = set()
+    for hop, ((sk, pf, pp, sil), width) in enumerate(zip(specs, widths)):
+        reads = [*sk, *([] if pf is None else [pf]),
+                 *(e for e in pp or () if _plain_ref(e) is None)]
+        at_hop.update(cur[r.ordinal] for e in reads for r in e.collect(
+            lambda x: isinstance(x, BoundReference))
+            if cur[r.ordinal] is not None)
+        s = cur if pp is None else [
+            None if _plain_ref(e) is None else cur[_plain_ref(e)]
+            for e in pp]
+        b = [(hop, j) for j in range(width)]
+        cur = (s + b) if sil else (b + s)
+    late = [c for c in dict.fromkeys(cur) if c is not None and c not in at_hop]
+    return at_hop, late
+
+
 def maybe_chain(join, conf=None):
     """Collapse `BHJ(stream=BHJ(...))` stacks into one
     BroadcastHashJoinChainExec (planner hook, bottom-up: the stream child is
@@ -894,8 +931,16 @@ class BroadcastHashJoinChainExec(TpuExec):
     turns out unique-keyed at run time (`_JoinCore.chain_capable`: dense /
     one probe modes), a stream row matches at most one build
     row per hop, so stream capacity statically bounds every intermediate —
-    probe -> gather -> probe -> gather -> compact runs as one dispatch per
-    batch instead of (project + probe + emit) per hop. The output lands at a
+    probe -> probe -> compact -> gather runs as one dispatch per batch
+    instead of (project + probe + emit) per hop.
+
+    What runs at the stream's capacity: each hop's lookup (two gathers: the
+    table, then the position->row permutation) and the gathers of the build
+    columns a later hop reads (`_chain_plan`). A hop otherwise carries only
+    its build row. What runs at the output bucket: the compaction's gathers
+    of the carried columns and build rows, through the first `cap` slots of
+    its permutation (`compact_cols_to`), then one gather a remaining build
+    column through its hop's compacted row. The output lands at a
     PREDICTED capacity bucket, kept per stream-batch capacity: the largest
     survivor bucket a batch of that capacity has needed in this partition
     (the stream's own capacity until one has been seen). A batch that
@@ -985,10 +1030,11 @@ class BroadcastHashJoinChainExec(TpuExec):
     def _fused_probe(self, stream_batch, cores, sbs, pred_cap, out_schema,
                      probe_span):
         """One fused program per (stream shape, output bucket): every hop's
-        key eval + prefilter + unique-match lookup + build gather + stream
-        preproject, then a single front-compaction, sliced to the predicted
-        output bucket. Returns the output batch or None (no survivors).
-        `probe_span` counts how the output landed at its bucket."""
+        key eval + prefilter + unique-match lookup + stream preproject, then
+        a single front-compaction at the predicted output bucket and the
+        build columns' gathers behind it. Returns the output batch or None
+        (no survivors). `probe_span` counts how the output landed at its
+        bucket, and which build columns were gathered where."""
         from spark_rapids_tpu.runtime import fuse
         scap = stream_batch.capacity
         specs = [(c.stream_key_exprs, c.stream_prefilter,
@@ -1007,6 +1053,9 @@ class BroadcastHashJoinChainExec(TpuExec):
             (c.chain_args(), [Col.from_vector(x)
                               for x in sb.get_batch().columns])
             for c, sb in zip(cores, sbs))
+        at_hop, late = _chain_plan(specs, len(stream_cols),
+                                   [len(b) for _, b in hop_args])
+        probe_span.set(deferred_cols=len(late), hop_cols=len(at_hop))
 
         def run(cap):
             key = ("join_chain", cap, statics, spec_key,
@@ -1019,7 +1068,12 @@ class BroadcastHashJoinChainExec(TpuExec):
                 def kernel(stream_cols, n_stream, hop_args):
                     cap_in = stream_cols[0].values.shape[0]
                     live = jnp.arange(cap_in, dtype=jnp.int32) < n_stream
-                    cur = stream_cols
+                    at_hop, late = _chain_plan(
+                        specs, len(stream_cols), [len(b) for _, b in hop_args])
+                    # a carried column is a Col, or (hop, column) for a build
+                    # column not gathered yet
+                    cur = list(stream_cols)
+                    rows = []
                     for hop, (lk, (cargs, b_cols), spec) in enumerate(
                             zip(lookups, hop_args, specs)):
                         sk_exprs, prefilter, preproject, sil = spec
@@ -1035,19 +1089,36 @@ class BroadcastHashJoinChainExec(TpuExec):
                                 row, hit = lk(
                                     cargs, [e.eval(ctx) for e in sk_exprs],
                                     live)
-                            bg = gather_cols(b_cols, jnp.where(hit, row, 0),
-                                             hit)
+                            rows.append(row)
+                            now = [j for j in range(len(b_cols))
+                                   if (hop, j) in at_hop]
+                            got = dict(zip(now, gather_cols(
+                                [b_cols[j] for j in now], row, hit)))
+                            bg = [got.get(j, (hop, j))
+                                  for j in range(len(b_cols))]
                             if preproject is not None:
+                                # a plain reference hands a build column not
+                                # gathered yet on as it is
                                 with jax.named_scope("ProjectExec"):
                                     s_cols = [e.eval(ctx) for e in preproject]
                             else:
                                 s_cols = cur
                         cur = (s_cols + bg) if sil else (bg + s_cols)
                         live = hit
-                    out, count = compact_cols(cur, live)
-                    if cap != cap_in:
-                        out = slice_to_capacity(out, None, cap)
-                    return out, count
+                    # every gather from here on runs at the output bucket
+                    hops = sorted({h for h, _ in late})
+                    carried, rows_out, count = compact_cols_to(
+                        [c for c in cur if isinstance(c, Col)], live, cap,
+                        [rows[h] for h in hops])
+                    valid = jnp.arange(cap, dtype=jnp.int32) < count
+                    with jax.named_scope("deferred"):
+                        at = dict(zip(hops, rows_out))
+                        built = {(h, j): gather_cols([hop_args[h][1][j]],
+                                                     at[h], valid)[0]
+                                 for h, j in late}
+                    carried = iter(carried)
+                    return [next(carried) if isinstance(c, Col) else built[c]
+                            for c in cur], count
 
                 return kernel
 
@@ -1057,7 +1128,7 @@ class BroadcastHashJoinChainExec(TpuExec):
 
         def land(cols, cap, tgt):
             # survivors lie at the front and the rest reads defaults
-            # (compact_cols), so the first `tgt` slots of a run at a larger
+            # (compact_cols_to), so the first `tgt` slots of a run at a larger
             # capacity are the bits a run at `tgt` returns
             key = ("join_chain_land", cap, tgt, fuse.schema_key(out_schema))
             return fuse.call_fused(

@@ -50,21 +50,42 @@ def front_perm(keep_mask):
     return perm, count
 
 
-@jax.named_scope("compact_cols")
 def compact_cols(cols, keep_mask):
     """Stable-move surviving rows to the front. Returns (new_cols, new_count).
 
     One permutation (`front_perm`), then a gather a column through it; slots
     at and past the count read the dtype's default with validity false, which
     `maybe_host_resize` and the chain's `slice_to_capacity` rely on."""
+    out, _, count = compact_cols_to(cols, keep_mask, keep_mask.shape[0])
+    return out, count
+
+
+@jax.named_scope("compact_cols")
+def compact_cols_to(cols, keep_mask, cap: int, rows=()):
+    """`compact_cols` landed at `cap` slots: the gathers run through the
+    first `cap` slots of the permutation, so each costs `cap` indices, not
+    the mask's capacity (a gather costs by its index count). Survivors past
+    `cap` are dropped; `count` is taken over the whole mask, so a caller
+    that predicted `cap` sees when it was too small. Only the join chain
+    passes a `cap` below the mask's capacity: no other caller knows its
+    output bucket inside its program.
+
+    `rows` are int32 index arrays into another table (a join's build rows),
+    moved with the kept rows and given no validity: the caller gathers
+    through them with the same `arange(cap) < count`.
+    Returns (new_cols, new_rows, count)."""
     perm, count = front_perm(keep_mask)
-    live = jnp.arange(keep_mask.shape[0], dtype=jnp.int32) < count
-    return gather_cols(cols, perm, live), count
+    perm = perm[:cap]
+    live = jnp.arange(cap, dtype=jnp.int32) < count
+    return gather_cols(cols, perm, live), [r[perm] for r in rows], count
 
 
 @jax.named_scope("gather_cols")
 def gather_cols(cols, indices, valid_out):
-    """Gather rows by index (join/sort output). valid_out masks output slots."""
+    """Gather rows by index (join/sort output). valid_out masks output
+    slots. Each column costs two gathers (values, validity) of
+    `len(indices)` rows, whatever the source's length: a caller that knows
+    its output is short gathers at that length (`compact_cols_to`)."""
     out = []
     for c in cols:
         vals = c.values[indices]

@@ -709,55 +709,88 @@ def _stream_batch(rows, survivors, first):
         "ls": pa.array([["x", "y", None, "z"][j % 4] for j in i])})
 
 
-def _landing_stack(batches, conf):
-    """Two stacked inner broadcast joins over a stream of `batches`."""
-    bt = pa.table({"rk": pa.array(np.arange(500), pa.int64()),
-                   "rv": pa.array(np.arange(500) * 1.5, pa.float64())})
+def _landing_stack(batches, conf, hops=2, chained=False):
+    """Two stacked inner broadcast joins over a stream of `batches`. With
+    `hops=3` a third joins on `rg`, a column of the first hop's build,
+    under a Project hoisted into its probe (as `plan/overrides.py` hoists
+    one) that reorders the columns, drops the first build's `rv` and
+    renames the second build's `w`. `chained`: each join goes through
+    `maybe_chain` as the planner builds it, bottom-up."""
+    from spark_rapids_tpu.exec.basic import ProjectExec
+    from spark_rapids_tpu.exec.joins import maybe_chain
+    plan = (lambda j: maybe_chain(j, conf)) if chained else (lambda j: j)
+    bt = {"rk": pa.array(np.arange(500), pa.int64()),
+          "rv": pa.array(np.arange(500) * 1.5, pa.float64())}
+    if hops == 3:
+        bt["rg"] = pa.array(np.arange(500) % 40, pa.int32())
     b2 = pa.table({"k2": pa.array(np.arange(0, 700, 2), pa.int32()),
                    "w": pa.array([None if k % 7 == 0 else f"w{k % 11}"
                                   for k in range(350)])})
-    inner = BroadcastHashJoinExec("inner", [col("lk")], [col("rk")],
-                                  _BatchesExec(batches, conf=conf),
-                                  ArrowScanExec([bt], conf=conf))
-    return BroadcastHashJoinExec("inner", [col("lv")], [col("k2")], inner,
-                                 ArrowScanExec([b2], conf=conf))
+    inner = plan(BroadcastHashJoinExec(
+        "inner", [col("lk")], [col("rk")], _BatchesExec(batches, conf=conf),
+        ArrowScanExec([pa.table(bt)], conf=conf)))
+    mid = plan(BroadcastHashJoinExec("inner", [col("lv")], [col("k2")], inner,
+                                     ArrowScanExec([b2], conf=conf)))
+    if hops == 2:
+        return mid
+    b3 = pa.table({"g3": pa.array(np.arange(40), pa.int32()),
+                   "gv": pa.array([None if g % 6 == 0 else g * 1.25
+                                   for g in range(40)], pa.float64())})
+    proj = ProjectExec([col("w").alias("w_name"), col("lk"), col("lf"),
+                        col("ls"), col("lv"), col("rk"), col("rg"),
+                        col("k2")], mid, conf=conf)
+    return plan(BroadcastHashJoinExec(
+        "inner", [col("rg")], [col("g3")], mid, ArrowScanExec([b3], conf=conf),
+        stream_preproject=proj.project_list, stream_schema=proj.output))
 
 
 # (stream rows, survivors) a batch; how each output lands at its bucket
-# (None: no survivor, no output); runs of the chain program
+# (None: no survivor, no output); runs of the chain program; hops
 LANDING_CASES = {
     # two capacities, two buckets, in turn: one prediction a capacity
     "alternating_capacities": (
         [(64, 20), (30, 5), (60, 17), (32, 8), (64, 30), (20, 6)],
-        ["sliced", "sliced", "hit", "hit", "hit", "hit"], 6),
+        ["sliced", "sliced", "hit", "hit", "hit", "hit"], 6, 2),
     "shrinking": ([(64, 40), (64, 10), (64, 3)],
-                  ["hit", "sliced", "sliced"], 3),
+                  ["hit", "sliced", "sliced"], 3, 2),
     "growing": ([(64, 3), (64, 10), (64, 40)],
-                ["sliced", "rerun", "rerun"], 5),
+                ["sliced", "rerun", "rerun"], 5, 2),
     "no_survivor_between": ([(64, 10), (64, 0), (64, 12)],
-                            ["sliced", None, "hit"], 3),
+                            ["sliced", None, "hit"], 3, 2),
     # the largest bucket seen stands, not the last
     "smaller_then_larger_again": ([(64, 20), (64, 5), (64, 20)],
-                                  ["sliced", "sliced", "hit"], 3),
+                                  ["sliced", "sliced", "hit"], 3, 2),
+    "three_hops_alternating_capacities": (
+        [(64, 20), (30, 5), (60, 17), (32, 8), (64, 30), (20, 6)],
+        ["sliced", "sliced", "hit", "hit", "hit", "hit"], 6, 3),
+    "three_hops_every_landing": (
+        [(64, 20), (64, 5), (64, 40), (64, 30), (64, 40)],
+        ["sliced", "sliced", "rerun", "sliced", "hit"], 6, 3),
 }
+
+# hops -> (deferred_cols, hop_cols): the build columns gathered after the
+# compaction, and those gathered at their hop because a later hop reads
+# them. Three hops: `rg` is the third hop's key; `rv` is dropped, so it is
+# gathered nowhere.
+LANDING_PLACES = {2: (4, 0), 3: (5, 1)}
 
 
 @pytest.mark.parametrize("case", LANDING_CASES)
 def test_the_chains_output_lands_at_its_bucket(case, spans, monkeypatch):
     """A predicted output bucket that was too large costs a slice of the
     first run's output, one that was too small a second run; either way the
-    batches are the unfused stack's bit for bit, padding included."""
+    batches are the unfused stack's bit for bit, padding included, with the
+    build columns gathered behind the compaction."""
     from spark_rapids_tpu.columnar.vector import bucket_capacity
-    from spark_rapids_tpu.exec.joins import (BroadcastHashJoinChainExec,
-                                             maybe_chain)
+    from spark_rapids_tpu.exec.joins import BroadcastHashJoinChainExec
     from spark_rapids_tpu.runtime import fuse
-    shape, landed, chain_runs = LANDING_CASES[case]
+    shape, landed, chain_runs, hops = LANDING_CASES[case]
     batches, first = [], 0
     for rows, kept in shape:
         batches.append(_stream_batch(rows, kept, first))
         first += rows
     conf = RapidsConf()
-    want = [b for b in _landing_stack(batches, conf).execute_partition(0)
+    want = [b for b in _landing_stack(batches, conf, hops).execute_partition(0)
             if b.num_rows]
     spans.drain()
     calls = []
@@ -765,12 +798,15 @@ def test_the_chains_output_lands_at_its_bucket(case, spans, monkeypatch):
     monkeypatch.setattr(
         fuse, "call_fused",
         lambda key, name, *a: calls.append(name) or call_fused(key, name, *a))
-    chain = maybe_chain(_landing_stack(batches, conf), conf)
+    chain = _landing_stack(batches, conf, hops, chained=True)
     assert isinstance(chain, BroadcastHashJoinChainExec)
+    assert len(chain.hops) == hops
     got = list(chain.execute_partition(0))
 
     probes = _span_counts(spans, "HashJoinChain.probe")
     assert [p.get("landed") for p in probes] == landed
+    assert {(p["deferred_cols"], p["hop_cols"]) for p in probes} == \
+        {LANDING_PLACES[hops]}
     assert calls.count("HashJoinChain.probe") == chain_runs
     assert calls.count("HashJoinChain.land") == landed.count("sliced")
     survivors = [n for _, n in shape]

@@ -197,18 +197,14 @@ def test_dense_join_probe_compiles_without_a_loop(one_chip, as_on_tpu, keys,
     assert _fits(table)
 
 
-def test_join_chain_reads_its_lookup_tables_from_fast_memory(one_chip,
-                                                             as_on_tpu,
-                                                             monkeypatch):
-    """The fused two-hop chain at q5's shapes (a 1 Mi-row batch; a 256 Ki
-    build under a 2 Mi-slot table, then a 16 Ki build): the compiler moves
-    each hop's table and position->row permutation into the chip's fast
-    memory (`S(1)` in the layout) before the gathers that read them. A gather
-    of 1 Mi rows from a table left in HBM took 36 ms on a v5e where one from
-    fast memory took 9 (PERF.md section 6, PR 34): the permutation's gather
-    has to depend on the table's gather alone, or the second hop's stays
-    behind. The kernel is the one the operator builds, captured on the CPU."""
-    import re
+@pytest.fixture(scope="module")
+def q5_chain_kernels():
+    """The fused two-hop chain at q5's shapes (two 1 Mi-row batches of one
+    partition; a 256 Ki build under a 2 Mi-slot table, then a 16 Ki build),
+    run on the CPU with each kernel the operator builds captured: the first
+    batch runs at the stream's capacity, the second at the bucket the first
+    needed, 256 Ki (about 16 % of a batch survives, as q5's first chain
+    keeps of lineitem). Returns {output capacity: (kernel, args)}."""
     import pyarrow as pa
     from spark_rapids_tpu.config import RapidsConf
     from spark_rapids_tpu.exec import joins as XJ
@@ -216,9 +212,9 @@ def test_join_chain_reads_its_lookup_tables_from_fast_memory(one_chip,
     from spark_rapids_tpu.expr.core import col
     from spark_rapids_tpu.runtime import fuse
     r = np.random.default_rng(1)
-    st = pa.table({"k1": pa.array(r.integers(1, 1_500_001, N), pa.int64()),
-                   "k2": pa.array(r.integers(1, 10_001, N), pa.int64()),
-                   "x": pa.array(r.random(N), pa.float64())})
+    st = pa.table({"k1": pa.array(r.integers(1, 1_500_001, 2 * N), pa.int64()),
+                   "k2": pa.array(r.integers(1, 10_001, 2 * N), pa.int64()),
+                   "x": pa.array(r.random(2 * N), pa.float64())})
     b1 = pa.table({"a": pa.array(np.sort(r.permutation(1_500_000)[:240_000]),
                                  pa.int64()),
                    "av": pa.array(np.arange(240_000), pa.int64())})
@@ -226,34 +222,82 @@ def test_join_chain_reads_its_lookup_tables_from_fast_memory(one_chip,
                    "bv": pa.array(np.arange(10_000), pa.int32())})
     conf = RapidsConf()
     inner = XJ.BroadcastHashJoinExec(
-        "inner", [col("k1")], [col("a")], ArrowScanExec([st], conf=conf),
+        "inner", [col("k1")], [col("a")],
+        ArrowScanExec([st], conf=conf, batch_rows=N),
         ArrowScanExec([b1], conf=conf))
     chain = XJ.maybe_chain(XJ.BroadcastHashJoinExec(
         "inner", [col("k2")], [col("b")], inner,
         ArrowScanExec([b2], conf=conf)), conf)
-    captured = []
+    captured = {}
     real = fuse.call_fused
 
     def spy(key, name, build, args, eager):
-        if name == "HashJoinChain.probe" and not captured:
-            captured.append((build(), args))
+        if name == "HashJoinChain.probe":      # key: ("join_chain", cap, ...)
+            captured.setdefault(key[1], (build(), args))
         return real(key, name, build, args, eager)
 
-    monkeypatch.setattr(fuse, "call_fused", spy)
-    assert chain.execute_collect().num_rows > 100_000
-    kernel, args = captured[0]
-    hlo = _compile(kernel, *jax.tree_util.tree_map(
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fuse, "call_fused", spy)
+        assert chain.execute_collect().num_rows > 200_000
+    assert sorted(captured) == [N // 4, N]
+    return captured
+
+
+def _chain_hlo(captured, one_chip):
+    kernel, args = captured
+    return _compile(kernel, *jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
                                        sharding=one_chip), args)).as_text()
-    assert "while" not in hlo
+
+
+def _lookup_tables(hlo):
+    """The layout of each operand the chain's lookup fusions read (a hop's
+    table and its position->row permutation)."""
+    import re
     lookups = {m.group(1) for m in re.finditer(
         r"^%(fused_computation[\w.]*) \(.*?\n(.*?)^\}", hlo, re.M | re.S)
         if re.search(r" gather\(.*hop\d/lookup/", m.group(2))}
     entry = hlo[hlo.index("ENTRY"):]
     layout = dict(re.findall(r"^\s*(%[\w.\-]+) = (\S+) ", entry, re.M))
-    tables = [layout[m.group(1)] for m in re.finditer(
+    return [layout[m.group(1)] for m in re.finditer(
         r"fusion\((%[\w.\-]+), .*calls=%(fused_computation[\w.]*)", entry)
         if m.group(2) in lookups]
+
+
+def test_join_chain_reads_its_lookup_tables_from_fast_memory(
+        one_chip, as_on_tpu, q5_chain_kernels):
+    """The chain's first run of a stream capacity, at the batch's 1 Mi
+    rows: the compiler moves each hop's table and position->row permutation
+    into the chip's fast memory (`S(1)` in the layout) before the gathers
+    that read them. A gather of 1 Mi rows from a table left in HBM took
+    36 ms on a v5e where one from fast memory took 9 (PERF.md section 6):
+    the permutation's gather has to depend on the table's gather alone, or
+    the second hop's stays behind."""
+    hlo = _chain_hlo(q5_chain_kernels[N], one_chip)
+    assert "while" not in hlo
+    tables = _lookup_tables(hlo)
+    assert len(tables) == 4 and all("S(1)" in t for t in tables), tables
+
+
+def test_join_chain_gathers_at_its_output_bucket(one_chip, as_on_tpu,
+                                                 q5_chain_kernels):
+    """The chain predicted at 256 Ki for a 1 Mi-row batch: only the lookups
+    (and the build columns a later hop reads, `hop<i>/gather_cols`; none in
+    this chain) gather 1 Mi rows. The compaction gathers the stream's
+    columns and the hops' build rows through the first 256 Ki slots of its
+    permutation, and each build column is gathered once behind it, at
+    256 Ki (`deferred`). The four lookup tables still lie in `S(1)`."""
+    import re
+    hlo = _chain_hlo(q5_chain_kernels[N // 4], one_chip)
+    assert "while" not in hlo
+    gathers = [(int(m.group(1)), m.group(2)) for m in re.finditer(
+        r"= \w+\[(\d+)\]\S* gather\(.*op_name=\"([^\"]*)\"", hlo)]
+    assert {rows for rows, _ in gathers} == {N, N // 4}, gathers
+    at_n = [name for rows, name in gathers if rows == N]
+    assert len(at_n) == 4 and all(
+        re.search(r"/hop\d/(lookup|gather_cols)/", n) for n in at_n), at_n
+    assert any("/deferred/" in name for _, name in gathers), gathers
+    tables = _lookup_tables(hlo)
     assert len(tables) == 4 and all("S(1)" in t for t in tables), tables
 
 

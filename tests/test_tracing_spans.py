@@ -488,6 +488,12 @@ def test_a_join_says_how_each_build_is_probed(traced):
     assert {k: probe["counts"][k]
             for k in ("landed", "capacity_pred", "capacity_out")} == {
         "landed": "hit", "capacity_pred": 4096, "capacity_out": 4096}
+    # no hop's key is a build column, so no build column is gathered at its
+    # hop; of the four, the USING join's projection hoisted into the second
+    # hop drops `uniq.k`, and the other three are gathered after the
+    # compaction
+    assert (probe["counts"]["deferred_cols"], probe["counts"]["hop_cols"]) \
+        == (3, 0), probe["counts"]
     single = by_name(spans, "HashJoin.probe")
     assert single and {s["counts"]["mode"] for s in single} == {"two"}
 
