@@ -47,6 +47,16 @@ _CHAIN_MIN_CAPACITY = 1024
 AGG_MERGE_OPS = {"sum": "sum", "count": "sum", "min": "min", "max": "max"}
 
 
+def _sorted_and_valid(key: Col, num_rows, capacity: int):
+    """1 where the live rows of ``key`` are all valid and nondecreasing,
+    else 0 (int32): the condition of the sorted-input group-by."""
+    vals = key.values
+    live = jnp.arange(capacity, dtype=jnp.int32) < num_rows
+    ok = jnp.all(key.validity | ~live) & jnp.all(
+        jnp.where(live[1:], vals[1:] >= vals[:-1], True))
+    return ok.astype(jnp.int32)
+
+
 def _agg_fn(e) -> AggregateFunction:
     f = e.child if isinstance(e, Alias) else e
     assert isinstance(f, AggregateFunction), f
@@ -142,9 +152,10 @@ class HashAggregateExec(TpuExec):
         """One fused update-or-merge aggregation, jit-compiled per shape bucket
         (runtime/fuse.py). In merge mode the batch is in keys+state layout; in
         update mode it is raw child output. Returns (a batch in keys+state
-        layout with one row per group, the words the group sort folded its
-        keys into by what they hold, or None: what the chain predicts by).
-        ``sp``: the caller's span, for what the grouping did."""
+        layout with one row per group, the words the keys fold into by what
+        they hold, or None, and whether the key-stats probe proved the input
+        sorted: what the chain predicts by). ``sp``: the caller's span, for
+        what the grouping did."""
         from spark_rapids_tpu.columnar.encoded import (EncodedColumnVector,
                                                        densify_cols)
         from spark_rapids_tpu.expr.core import Col
@@ -157,7 +168,7 @@ class HashAggregateExec(TpuExec):
             for e in (*self.group_exprs, *self.agg_exprs,
                       *([pre] if pre is not None else []),
                       *(prep or [])))
-        n_words, need = None, 0
+        n_words, need, presorted = None, 0, False
         if batch.columns and not ctx_sensitive:
             # scan-side chain: still-encoded scan columns enter the kernel AS
             # ENCODED PAGES and expand inside this fused program (late
@@ -173,19 +184,21 @@ class HashAggregateExec(TpuExec):
             nr = jnp.asarray(batch.lazy_num_rows, jnp.int32)
             n_words, need, presorted = self._key_stats(batch, in_cols, nr,
                                                        merge)
+            # a presorted batch sorts nothing: its program folds no key
+            words = None if presorted else n_words
             key = ("agg", merge, fuse.schema_key(
                 self._partial_schema() if merge else self.child.output),
                 tuple(fuse.expr_key(e) for e in self.group_exprs),
                 tuple(fuse.expr_key(e) for e in self.agg_exprs),
                 fuse.expr_key(pre) if pre is not None else None,
                 tuple(fuse.expr_key(e) for e in prep) if prep is not None
-                else None, self.prefilter_on_projected, n_words, presorted)
+                else None, self.prefilter_on_projected, words, presorted)
 
             def build():
                 def kernel(cols, num_rows):
                     cols = densify_cols(cols)
                     ctx = EvalContext(cols, num_rows, cols[0].values.shape[0])
-                    return self._agg_kernel(ctx, merge, n_words=n_words,
+                    return self._agg_kernel(ctx, merge, n_words=words,
                                             presorted=presorted)
                 return kernel
 
@@ -205,11 +218,13 @@ class HashAggregateExec(TpuExec):
             compacted, n_groups, _need, facts = self._agg_kernel(
                 EvalContext.from_batch(batch), merge)
         if sp:
-            self._count_grouping(sp, facts, need, batch.lazy_num_rows,
-                                 batch.capacity, n_groups)
+            self._count_grouping(sp, facts, 0 if presorted else need,
+                                 batch.lazy_num_rows, batch.capacity,
+                                 n_groups)
+            sp.set(presorted=int(presorted))
         cols = [c.to_vector() for c in compacted]
         return (ColumnarBatch(cols, n_groups, self._partial_schema()),
-                n_words)
+                n_words, presorted)
 
     @staticmethod
     def _count_grouping(sp, facts, need_bits, rows, capacity, groups):
@@ -227,7 +242,8 @@ class HashAggregateExec(TpuExec):
         sp.set(**counts)
 
     def _chain_step(self, acc: ColumnarBatch, batch: ColumnarBatch,
-                    A: int, pred_P: int, n_words=None, sp=tracing.NO_SPAN):
+                    A: int, pred_P: int, n_words=None, sp=tracing.NO_SPAN,
+                    presorted: bool = False):
         """One fused update→concat→merge step of the group-by chain: aggregate
         the incoming batch, pad-concat the partial onto the accumulated
         partials, and merge-aggregate — ONE program per batch, like
@@ -250,6 +266,19 @@ class HashAggregateExec(TpuExec):
         that outgrew the prediction rejects the step like a capacity
         mispredict does (ops/sorting.fold_keys).
 
+        ``presorted``: the last unchained batch's key-stats probe proved its
+        input sorted. The update and the merge then sort nothing (the
+        sorted-input group-by of ``_agg_kernel``), and the program checks
+        what that assumes: the concat's keys, the accumulator's and then the
+        update's, are all valid and nondecreasing over the live rows. The
+        update's groups are the batch's runs of equal keys, so they come out
+        nondecreasing only where the batch was sorted, and the merge needs
+        the accumulator's last key at or below the update's first. A fourth
+        status word says so; where it does not hold the step is rejected
+        like a mispredict. Every sort breaks ties on the row index, so over
+        sorted input the sorting strategies give the same order and the same
+        sums as this one.
+
         Returns ``(accepted, merged_batch, merged_groups, update_groups,
         words the keys needed)`` or None when the shape cannot chain at all.
         """
@@ -262,6 +291,7 @@ class HashAggregateExec(TpuExec):
         import numpy as np
         if not (batch.columns and acc.columns):
             return None
+        presorted = presorted and len(self.group_exprs) == 1
         # chaining only pays when its one-off trace+compile can amortize over
         # real batches: the syncs it removes cost microseconds, the fused
         # program costs seconds to compile, and a cluster executor compiling
@@ -288,35 +318,41 @@ class HashAggregateExec(TpuExec):
                    and isinstance(c, EncodedColumnVector) else None)
             in_cols.append(enc if enc is not None else Col.from_vector(c))
         acc_cols = [Col.from_vector(c) for c in acc.columns]
+        if presorted:
+            n_words = None   # nothing is sorted, so no key is folded
         key = ("agg_chain", fuse.schema_key(self.child.output),
                fuse.schema_key(self._partial_schema()), acc_cap, bcap, Cc,
                tuple(fuse.expr_key(e) for e in self.group_exprs),
                tuple(fuse.expr_key(e) for e in self.agg_exprs),
                fuse.expr_key(pre) if pre is not None else None,
                tuple(fuse.expr_key(e) for e in prep) if prep is not None
-               else None, self.prefilter_on_projected, n_words)
+               else None, self.prefilter_on_projected, n_words) + (
+                   ("presorted",) if presorted else ())
 
         def build():
             def kernel(a_cols, b_cols, acc_n, nr):
                 b_cols = densify_cols(b_cols)
                 uctx = EvalContext(b_cols, nr, bcap)
-                # no key-stats probe: skipping the presorted strategy is
+                # no key-stats probe: the presorted verdict is the last
+                # unchained batch's, checked in the status; skipping it is
                 # value-neutral (every sort embeds the row index, so all
                 # strategies produce the same total order)
                 upd_cols, upd_n, need_u, _ = self._agg_kernel(
-                    uctx, merge=False, n_words=n_words)
+                    uctx, merge=False, n_words=n_words, presorted=presorted)
                 counts_v = jnp.stack([acc_n, upd_n.astype(jnp.int32)])
                 per_col = [[a, u] for a, u in zip(a_cols, upd_cols)]
                 with jax.named_scope("concat"):
                     cat = concat_cols(per_col, counts_v, Cc, (acc_cap, bcap))
                 mctx = EvalContext(cat, acc_n + upd_n, Cc)
                 mg_cols, mg_n, need_m, facts = self._agg_kernel(
-                    mctx, merge=True, n_words=n_words)
-                status = jnp.stack([jnp.asarray(mg_n, jnp.int32),
-                                    jnp.asarray(upd_n, jnp.int32),
-                                    jnp.maximum(need_u, need_m)
-                                    .astype(jnp.int32)])
-                return mg_cols, status, facts
+                    mctx, merge=True, n_words=n_words, presorted=presorted)
+                status = [jnp.asarray(mg_n, jnp.int32),
+                          jnp.asarray(upd_n, jnp.int32),
+                          jnp.maximum(need_u, need_m).astype(jnp.int32)]
+                if presorted:
+                    status.append(_sorted_and_valid(cat[0], acc_n + upd_n,
+                                                    Cc))
+                return mg_cols, jnp.stack(status), facts
             return kernel
 
         acc_n_t = jnp.asarray(acc.lazy_num_rows, jnp.int32)
@@ -338,8 +374,14 @@ class HashAggregateExec(TpuExec):
         # concat_batches would have picked (bucket of the TRUE total): the
         # merge's f64 reduction order is capacity-sensitive, so an equal
         # bucket is exactly the bit-identity condition
+        in_order = not presorted or bool(st[3])
         accepted = (bucket_capacity(max(A + upd_n, 1)) == Cc
-                    and need <= (held_bits(n_words) if n_words else 0))
+                    and need <= (held_bits(n_words) if n_words else 0)
+                    and in_order)
+        if not in_order:
+            upd_n = pred_P   # it counted the batch's runs, not its groups
+        if sp:
+            sp.set(accepted=int(accepted), presorted=int(presorted))
         if accepted and self.conf.stage_fusion_enabled:
             # same stage-boundary right-sizing the unchained merge applies —
             # mg_n is already a host int, so this syncs nothing extra, and
@@ -369,18 +411,20 @@ class HashAggregateExec(TpuExec):
         physically ordered by l_orderkey): then the sort vanishes entirely
         and the segment path runs over the input order (the sorted-input
         group-by; `presorted` wins over the fold). Gated to big capacities
-        (below, any sort is cheap), dense inputs (the probe would expand an
-        encoded column a second time) and key sets that hold an integer
-        whose type alone says too little: a 64-bit key, or one among
-        several. Every other key set folds by its types and dictionaries
-        with no read (n_words None), or cannot fold at all."""
-        from spark_rapids_tpu.columnar.encoded import EncodedCol
+        (below, any sort is cheap) and key sets that hold an integer whose
+        type alone says too little: a 64-bit key, or one among several.
+        Several keys take dense inputs only (the probe would expand encoded
+        columns a second time); a single key is probed over a scan's
+        still-encoded pages too, since that is the key that arrives sorted,
+        and only its own column expands (the probe returns nothing else).
+        Every other key set folds by its types and dictionaries with no read
+        (n_words None), or cannot fold at all."""
+        from spark_rapids_tpu.columnar.encoded import EncodedCol, densify_cols
         from spark_rapids_tpu.ops.sorting import (SortOrder, fold_keys,
                                                   ranged_key)
         from spark_rapids_tpu.runtime import fuse
         no = (None, 0, False)
-        if (not self.group_exprs or batch.capacity < (1 << 17)
-                or any(isinstance(c, EncodedCol) for c in in_cols)):
+        if not self.group_exprs or batch.capacity < (1 << 17):
             return no
         ranged = False
         for e in self.group_exprs:
@@ -397,6 +441,8 @@ class HashAggregateExec(TpuExec):
         if not ranged:
             return no
         single = len(self.group_exprs) == 1
+        if not single and any(isinstance(c, EncodedCol) for c in in_cols):
+            return no
         prep = self.preproject if not merge else None
         skey = ("agg_key_stats", merge, fuse.schema_key(
             self._partial_schema() if merge else self.child.output),
@@ -406,6 +452,7 @@ class HashAggregateExec(TpuExec):
 
         def build():
             def kernel(cols, num_rows):
+                cols = densify_cols(cols)
                 cap_ = cols[0].values.shape[0]
                 ctx = EvalContext(cols, num_rows, cap_)
                 if prep is not None:
@@ -416,17 +463,12 @@ class HashAggregateExec(TpuExec):
                 folded = fold_keys(keys, [SortOrder() for _ in keys],
                                    num_rows, cap_, n_words=1)
                 need = 0 if folded is None else folded.need_bits
-                is_sorted = False
-                if single:
-                    # sorted = every live row valid AND values nondecreasing
-                    # over the live prefix (all-valid means validity
-                    # boundaries cannot reorder groups, so input order ==
-                    # sorted group order)
-                    k = keys[0]
-                    vals = k.values.astype(jnp.int64)
-                    live = jnp.arange(cap_, dtype=jnp.int32) < num_rows
-                    is_sorted = jnp.all(k.validity | ~live) & jnp.all(
-                        jnp.where(live[1:], vals[1:] >= vals[:-1], True))
+                # sorted = every live row valid AND values nondecreasing
+                # over the live prefix (all-valid means validity boundaries
+                # cannot reorder groups, so input order == sorted group
+                # order)
+                is_sorted = (_sorted_and_valid(keys[0], num_rows, cap_)
+                             if single else 0)
                 return jnp.stack([jnp.asarray(need, jnp.int32),
                                   jnp.asarray(is_sorted, jnp.int32)])
             return kernel
@@ -436,11 +478,12 @@ class HashAggregateExec(TpuExec):
                 skey, "HashAggregateExec.key_stats", build, (in_cols, nr),
                 lambda: build()(in_cols, nr)))
             sp.set(rows=need, capacity=batch.capacity)
-        if bool(is_sorted) and self.conf.stage_fusion_enabled:
-            return None, 0, True
+        presorted = bool(is_sorted) and self.conf.stage_fusion_enabled
         if not need:
-            return no
-        return words_for(need), need, False
+            return None, 0, presorted
+        # the words stay with a presorted verdict: a later batch that turns
+        # out unsorted then folds its key instead of the unfolded sort
+        return words_for(need), need, presorted
 
     def _agg_kernel(self, ctx: EvalContext, merge: bool, n_words=None,
                     presorted: bool = False):
@@ -795,21 +838,26 @@ class HashAggregateExec(TpuExec):
             merge_input = self.mode == FINAL
 
             def agg_one(b, merge=merge_input):
-                nonlocal n_words
+                nonlocal n_words, sorted_in
                 with trace_range("HashAggregate.agg", self._agg_time) as sp:
-                    out, n_words = self._aggregate_batch(b, merge, sp)
+                    out, n_words, sorted_in = self._aggregate_batch(b, merge,
+                                                                    sp)
                     return out
 
             acc = None
             # group-by chain (host-side predictors): A = accumulated group
             # count, pred_P = predicted partial-group count of the next batch
             # (last observed), n_words = the words the last group sort folded
-            # its keys into. All plain values maintained WITHOUT extra syncs
-            # on chained iterations.
+            # its keys into, sorted_in = the last unchained batch's probe
+            # proved its input sorted, and its merge (if one ran) its concat,
+            # seen_rows = the rows of the batch pred_P counted (a host int, or
+            # None). All plain values maintained WITHOUT extra syncs on
+            # chained iterations.
             chain_ok = (not merge_input and bool(self.group_exprs)
                         and self.conf.groupby_chain_enabled)
             A = pred_P = 0
-            n_words = None
+            n_words = seen_rows = None
+            sorted_in = False
             for batch in self.child.execute_partition(split):
                 self._in_rows.add_lazy(batch.lazy_num_rows)
                 # acquire only once data is ready for device work — acquiring before
@@ -818,17 +866,28 @@ class HashAggregateExec(TpuExec):
                 # acquires on data arrival, RapidsShuffleIterator.scala:300)
                 acquire_semaphore(self.metrics)
                 needed = None
+                rows = batch.lazy_num_rows
+                rows = rows if isinstance(rows, int) else None
                 if acc is not None and chain_ok:
-                    def chain_step(a=acc, b=batch, A=A, P=pred_P, w=n_words):
+                    P = pred_P
+                    if sorted_in and rows and seen_rows:
+                        # a sorted stream's groups follow its rows: the last
+                        # batch's count scaled to this one's rows (a file's
+                        # batches alternate 1 Mi rows and its remainder)
+                        P = -(-pred_P * rows // seen_rows)
+
+                    def chain_step(a=acc, b=batch, A=A, P=P, w=n_words,
+                                   srt=sorted_in):
                         with trace_range("HashAggregate.chain",
                                          self._agg_time) as sp:
-                            return self._chain_step(a, b, A, P, w, sp)
+                            return self._chain_step(a, b, A, P, w, sp, srt)
                     try:
                         res = R.call_with_retry(chain_step, scope="agg.chain")
                     except R.DeviceOomError:
                         res = None   # fall back to the splittable update loop
                     if res is not None:
                         accepted, merged, mg_n, upd_n, needed = res
+                        seen_rows = rows
                         if accepted:
                             acc, A, pred_P = merged, mg_n, upd_n
                             continue
@@ -852,12 +911,16 @@ class HashAggregateExec(TpuExec):
 
                     # incremental concat+merge loop (reference aggregate.scala:388)
                     def merge_acc(a=acc, p=partial):
+                        nonlocal sorted_in
                         with trace_range("HashAggregate.concat",
                                          self._concat_time):
                             both = concat_batches([a, p])
                         with trace_range("HashAggregate.merge",
                                          self._agg_time) as sp:
-                            return self._aggregate_batch(both, True, sp)[0]
+                            out, _, merged_sorted = self._aggregate_batch(
+                                both, True, sp)
+                            sorted_in = sorted_in and merged_sorted
+                            return out
 
                     # the merge needs BOTH partials at once — unsplittable,
                     # so spill-only retry (withRetryNoSplit)
@@ -871,7 +934,8 @@ class HashAggregateExec(TpuExec):
                     # already syncs counts per merge, so this adds none on the
                     # steady path and the chain adds exactly one per step
                     A = acc.num_rows
-                    pred_P = pred_P or A
+                    if not pred_P:
+                        pred_P, seen_rows = A, rows
             if acc is None:
                 if self.group_exprs:
                     return  # grouped agg over empty input → no rows (Spark)
