@@ -16,11 +16,16 @@ which drives these primitives.
 Segment reductions are SCAN-based, never scatter-based: TPU scatters at large
 segment counts are catastrophically slow (measured: jax.ops.segment_sum with
 4M segments does not finish in minutes on v5e, while the whole sort is ~7 ms).
-Sums difference one global cumsum at segment edges (exact for ints even across
-wrap; f64 cancellation error is ~ulp(prefix) — negligible at analytic scales);
-min/max/first/last ride segmented doubling scans (ops/windowing.py) gathered at
-per-row segment ends. Results are PER-ROW (row i holds the aggregate of row i's
-whole segment), so callers compact boundary rows to get one row per group.
+Integer sums and counts difference one global cumsum at segment edges (exact
+even across wrap). Float sums take a segmented doubling scan bounded by each
+row's segment start, and carry the value at the segment end back over the
+segment the same way (_seg_total): full-width passes that fuse, with no
+gather, since a dynamic float64 gather costs the chip tens of ms a 1 Mi rows,
+and no prefix differencing, whose error is ulp(prefix), not ulp(total) (at a
+revenue prefix of 1e11, 1e-5 against totals of 1e5). min/max/first/last
+re-sort (seg_id, value) pairs so the extreme lands on a segment edge. Results
+are PER-ROW (row i holds the aggregate of row i's whole segment), so callers
+compact boundary rows to get one row per group.
 """
 
 from __future__ import annotations
@@ -29,7 +34,6 @@ import typing
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.expr.core import Col
@@ -257,76 +261,22 @@ def _edge_sum(data, ctx: SegCtx):
     return csz[ctx.seg_end + 1] - csz[ctx.seg_start]
 
 
-def _seg_scan(data, ctx: SegCtx, combine):
-    """Segmented inclusive scan reusing the PRECOMPUTED ctx.seg_start (the
-    generic windowing.segmented_scan would re-derive it per call)."""
-    from spark_rapids_tpu.ops.windowing import _doubling_scan
-    return _doubling_scan(data, lambda i, s: (i - s) >= ctx.seg_start, combine)
-
-
-_TREE_SMALL = 1 << 12   # levels this short are walked as a second group
-
-
-def _tree_walk(levels, k0: int, lo, hi, out):
-    """Consume levels k0.. of the range-sum tree against the open ranges
-    [lo, hi): one loop over the levels laid end to end, so the chip's
-    compiler sees ONE gather body instead of two per level (unrolled, the
-    40 gathers of a 1 Mi-row tree took it 19 s; this form 1 s). The adds
-    happen in the same order either way."""
-    sizes = np.array([lv.shape[0] for lv in levels], np.int32)
-    offs_d = jnp.asarray(np.concatenate([[0], np.cumsum(sizes)[:-1]])
-                         .astype(np.int32))
-    last_d = jnp.asarray(sizes - 1)
-    flat = jnp.concatenate(levels)
-
-    def body(i, st):
-        lo, hi, out = st
-        i = i.astype(jnp.int32)
-        k = i + jnp.int32(k0)
-        blk = jnp.int32(1) << k
-        off, last = offs_d[i], last_d[i]
-        # consume a 2^k block at the front if lo is 2^k-aligned-odd
-        take_lo = ((lo & blk) != 0) & (lo + blk <= hi)
-        contrib = flat[off + jnp.clip(lo >> k, 0, last)]
-        out = out + jnp.where(take_lo, contrib, jnp.zeros_like(out))
-        lo = jnp.where(take_lo, lo + blk, lo)
-        # and one at the back if hi has bit k set
-        take_hi = ((hi & blk) != 0) & (hi - blk >= lo)
-        contrib = flat[off + jnp.clip((hi - blk) >> k, 0, last)]
-        out = out + jnp.where(take_hi, contrib, jnp.zeros_like(out))
-        hi = jnp.where(take_hi, hi - blk, hi)
-        return lo, hi, out
-
-    return jax.lax.fori_loop(0, len(levels), body, (lo, hi, out))
-
-
-def _seg_sum_tree(data, ctx: SegCtx):
-    """Per-segment float total via a range-sum tree (sparse-table query).
-
-    Level k holds sums of aligned 2^k-blocks (built by pairwise halving — ~2x
-    the data in total traffic). Each row's [seg_start, seg_end] range is
-    decomposed into <= 2*log2(cap) disjoint aligned blocks and ADDED — no
-    prefix subtraction at all, so segment totals never cancel against foreign
-    segment prefixes (the flaw of cumsum edge-differencing), and the pairwise
-    build gives better-than-sequential float error. Cost: log2(cap) masked
-    gathers from geometrically shrinking levels vs log2(cap) full-width
-    combine passes for the doubling scan (~20x cheaper at 256k rows). The
-    long levels and the short ones are walked as two groups, so the gathers
-    from short levels stay gathers from a small table."""
-    levels = [data]
-    while levels[-1].shape[0] > 1:
-        x = levels[-1]
-        if x.shape[0] % 2:    # non-power-of-two capacity: zero-pad the level
-            x = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
-        levels.append(x.reshape(-1, 2).sum(axis=1))
-
-    split = next(k for k, lv in enumerate(levels)
-                 if lv.shape[0] <= _TREE_SMALL)
-    st = (ctx.seg_start, ctx.seg_end + 1, jnp.zeros_like(data))
-    for k0, group in ((0, levels[:split]), (split, levels[split:])):
-        if group:
-            st = _tree_walk(group, k0, *st)
-    return st[2]
+def _seg_total(data, ctx: SegCtx):
+    """Per-row total of a float column's segment, with no gather: an
+    inclusive sum scan bounded by ctx.seg_start, then the value at
+    ctx.seg_end carried back over the segment. Each is log2(capacity)
+    full-width passes of roll, compare and select (windowing's doubling
+    scan), which fuse; a dynamic gather of float64 is what the chip pays
+    for (on a v5e at 1 Mi rows, the scan read at seg_end by a gather took
+    35 ms, the two scans 1.5 ms). A
+    row's sum adds disjoint blocks of its own segment only, in a tree of
+    depth log2(capacity), so a total never cancels against a foreign
+    prefix, NaN and infinities stay in their segment, and integral totals
+    below 2^53 are exact. Every row of a segment holds the same value."""
+    scan = W._doubling_scan(data, lambda i, s: (i - s) >= ctx.seg_start,
+                            jnp.add)
+    return W._doubling_scan(scan, lambda i, s: (i + s) <= ctx.seg_end,
+                            lambda later, _: later, reverse=True)
 
 
 def _seg_extreme(data, ctx: SegCtx, largest: bool):
@@ -353,9 +303,9 @@ def segment_count(validity, ctx: SegCtx):
 def segment_sum(values, validity, ctx: SegCtx):
     data = jnp.where(validity, values, jnp.zeros_like(values))
     if jnp.issubdtype(data.dtype, jnp.floating):
-        # floats: range-sum tree — additions of disjoint aligned blocks only,
-        # no cancellation against foreign segment prefixes
-        s = _seg_sum_tree(data, ctx)[ctx.seg_end]
+        # floats: a segmented scan, no gather and no prefix differencing
+        # (_seg_total); a global prefix's ulp would swamp a small total
+        s = _seg_total(data, ctx)
     else:
         s = _edge_sum(data, ctx)  # ints: exact even across wrap
     return s, segment_count(validity, ctx)

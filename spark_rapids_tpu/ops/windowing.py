@@ -27,15 +27,17 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 
 
-def _doubling_scan(values, mask_fn, combine):
+def _doubling_scan(values, mask_fn, combine, reverse: bool = False):
     """Inclusive scan by log-step doubling: out[i] = combine over the allowed
-    prefix. mask_fn(idx, s) says whether out[i-s] may fold into out[i]."""
+    prefix. mask_fn(idx, s) says whether out[i-s] may fold into out[i]
+    (`reverse`: over the allowed suffix, whether out[i+s] may)."""
     cap = values.shape[0]
     idx = jnp.arange(cap, dtype=jnp.int32)
     out = values
     s = 1
     while s < cap:
-        prev = jnp.roll(out, s)   # out[i-s]; head rows are masked off below
+        # out[i-s] (out[i+s]); rows it wraps to are masked off below
+        prev = jnp.roll(out, -s if reverse else s)
         out = jnp.where(mask_fn(idx, s), combine(prev, out), out)
         s <<= 1
     return out

@@ -264,3 +264,55 @@ def test_unsorted_keys_chain_as_before(tmp_path, traced, shape, keys,
     assert _chain_counts(spans) == [(1, 0)] * 3
     assert all(s["counts"].get("presorted", 0) == 0 for s in spans)
     assert n == dispatches
+
+
+# -- float sums on the sort path ----------------------------------------------
+
+def _float_groups(rng, presorted):
+    """One batch at capacity 2^17 (the key-stats probe's gate): an int64 key
+    with about four rows a key, a float64 value with nulls, one key whose
+    values are all null and one whose values hold a NaN. Shuffled, the keys
+    hold nulls too (a null key would break the sorted verdict)."""
+    n = 120_000
+    keys = np.sort(rng.integers(1 << 33, (1 << 33) + n // 4, n))
+    vals = rng.uniform(-1e4, 1e5, n)
+    vals[np.flatnonzero(keys == keys[n // 3])[-1]] = np.nan
+    null_val = (rng.random(n) < 0.05) | (keys == keys[n // 2])
+    key_valid = np.ones(n, bool)
+    if not presorted:
+        perm = rng.permutation(n)
+        keys, vals, null_val = keys[perm], vals[perm], null_val[perm]
+        key_valid = rng.random(n) >= 0.01
+    return pa.table({"k": pa.array(keys, mask=~key_valid),
+                     "v": pa.array(vals, mask=null_val)})
+
+
+@pytest.mark.parametrize("presorted", [True, False],
+                         ids=["presorted", "unsorted"])
+def test_float_sum_and_avg_on_the_sort_path_match_pyarrow(traced, presorted):
+    """A float64 sum and avg by an int64 key through the sort-path group-by,
+    over input the probe proves sorted and over shuffled input, agree with
+    pyarrow's group_by: a group of null values sums to null, a NaN stays in
+    its own group, every other group within 1e-12."""
+    t = _float_groups(np.random.default_rng(41), presorted)
+    got = (TpuSession().create_dataframe(t).group_by("k")
+           .agg(F.sum(F.col("v")).alias("s"), F.avg(F.col("v")).alias("a"))
+           .collect())
+    (agg,) = [s for s in tracing.drain() if s["name"] == "HashAggregate.agg"]
+    assert agg["counts"]["path"] == "sort"
+    assert agg["counts"]["capacity"] >= 4096
+    assert agg["counts"].get("presorted", 0) == int(presorted)
+    want = t.group_by("k").aggregate([("v", "sum"), ("v", "mean")])
+    want = {k: (s, a) for k, s, a in zip(*(want.column(c).to_pylist()
+                                           for c in ("k", "v_sum", "v_mean")))}
+    got = {r["k"]: (r["s"], r["a"]) for r in got.to_pylist()}
+    assert got.keys() == want.keys()
+    nan = [k for k, (s, _) in want.items() if s is not None and s != s]
+    null = [k for k, (s, _) in want.items() if s is None]
+    assert len(nan) == 1 and null and (None in want) != presorted
+    for k, (s, a) in want.items():
+        if s is None or s != s:
+            assert got[k][0] is None if s is None else got[k][0] != got[k][0]
+            assert got[k][1] is None if a is None else got[k][1] != got[k][1]
+        else:
+            np.testing.assert_allclose(got[k], (s, a), rtol=1e-12, atol=0)

@@ -367,21 +367,81 @@ def test_two_level_scans_match_the_native_ones(n, dtype):
                               jax.lax.cummin(d, reverse=True))
 
 
-@pytest.mark.parametrize("cap", [256, 5000, 1 << 14])
-def test_range_sum_tree_matches_segment_totals(cap):
-    """grouping._seg_sum_tree walks the long and the short levels as two
-    loops; every row gets its own segment's total."""
+def _float_segment_sum(cap, seg_ids, valid, data):
+    """(per-row sum, per-row count) of ops/grouping.segment_sum over sorted
+    ``seg_ids``, in one program."""
+    import jax
     import jax.numpy as jnp
     from spark_rapids_tpu.ops import grouping as G
-    rng = np.random.default_rng(cap)
-    data = rng.uniform(0, 1e5, cap)
+
+    def run(v, m, sid):
+        return G.segment_sum(v, m, G.segment_structure(sid, cap))
+    s, n = jax.jit(run)(jnp.asarray(data), jnp.asarray(valid),
+                        jnp.asarray(seg_ids))
+    return np.asarray(s), np.asarray(n)
+
+
+def _segments(cap, layout, rng):
+    """(sorted seg_ids, valid): ``padded`` leaves the last tenth of the rows
+    dead on seg_id cap - 1, as sorted_groups pads a batch."""
+    valid = np.ones(cap, bool)
+    if layout == "singletons":
+        return np.arange(cap, dtype=np.int32), valid
+    if layout == "one":
+        return np.zeros(cap, np.int32), valid
     boundary = rng.random(cap) < 0.01
     boundary[0] = True
     seg_ids = np.cumsum(boundary).astype(np.int32) - 1
-    ctx = G.segment_structure(jnp.asarray(seg_ids), cap)
-    got = np.asarray(G._seg_sum_tree(jnp.asarray(data), ctx))
-    want = np.bincount(seg_ids, weights=data)[seg_ids]
+    if layout == "padded":
+        live = cap - cap // 10
+        seg_ids[live:] = cap - 1
+        valid[live:] = False
+    return seg_ids, valid
+
+
+@pytest.mark.parametrize("layout", ["random", "singletons", "one", "padded"])
+@pytest.mark.parametrize("cap", [256, 5000, 1 << 14, 1 << 20])
+def test_float_segment_sum_matches_segment_totals(cap, layout):
+    """A float column's segment sum (a segmented scan bounded by each row's
+    segment, its end carried back) gives every row its own segment's total:
+    within 1e-12 of np.bincount, and integral float64 values (TPC-H
+    quantities) summed exactly; dead padding rows add nothing."""
+    rng = np.random.default_rng(cap)
+    seg_ids, valid = _segments(cap, layout, rng)
+    live = np.where(valid, 1.0, 0.0)
+    nseg = int(seg_ids.max()) + 1
+    data = rng.uniform(0, 1e5, cap)
+    got, count = _float_segment_sum(cap, seg_ids, valid, data)
+    want = np.bincount(seg_ids, weights=data * live, minlength=nseg)[seg_ids]
     np.testing.assert_allclose(got, want, rtol=1e-12)
+    assert np.array_equal(
+        count, np.bincount(seg_ids, weights=live, minlength=nseg)[seg_ids])
+    qty = rng.integers(1, 51, cap).astype(np.float64)
+    got, _ = _float_segment_sum(cap, seg_ids, valid, qty)
+    assert np.array_equal(
+        got, np.bincount(seg_ids, weights=qty * live, minlength=nseg)[seg_ids])
+
+
+@pytest.mark.parametrize("cap", [256, 5000, 1 << 14, 1 << 20])
+def test_float_segment_sum_keeps_nan_and_inf_in_their_segment(cap):
+    """A NaN, a +inf, and a +inf beside a -inf make their own segments'
+    totals NaN, inf and NaN, and never reach a neighbour's, which stays
+    exact."""
+    rng = np.random.default_rng(cap + 1)
+    seg_ids = (np.arange(cap) // 8).astype(np.int32)
+    data = rng.integers(1, 51, cap).astype(np.float64)
+    mid = int(seg_ids[cap // 2])
+    rows = [np.flatnonzero(seg_ids == mid + d) for d in (-1, 0, 1)]
+    data[rows[0][3]] = np.nan
+    data[rows[1][0]] = np.inf
+    data[rows[2][1]], data[rows[2][6]] = np.inf, -np.inf
+    got, _ = _float_segment_sum(cap, seg_ids, np.ones(cap, bool), data)
+    assert np.isnan(got[rows[0]]).all()
+    assert np.isposinf(got[rows[1]]).all()
+    assert np.isnan(got[rows[2]]).all()
+    finite = np.abs(seg_ids - mid) > 1
+    assert np.array_equal(
+        got[finite], np.bincount(seg_ids, weights=data)[seg_ids][finite])
 
 
 # -- the fused program (PR 35) -------------------------------------------------
